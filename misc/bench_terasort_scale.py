@@ -8,7 +8,7 @@ proof predates the copier (it ran the serial LocalJobRunner shuffle);
 this is the path `ReduceTask.java:659,1080` describes.
 
 Host-only (no TPU needed). Run:  python misc/bench_terasort_scale.py
-[records] [reduces]; prints one JSON line, results belong in BASELINE.md.
+[records] [reduces]; prints one JSON line.
 """
 
 from __future__ import annotations
@@ -29,16 +29,11 @@ def main() -> int:
     #: reuse an existing teragen dir (skip the 3-min gen) and/or raise
     #: the copier RAM budget: TERASORT_GEN_DIR=..., TERASORT_RAM_MB=...
     #: TERASORT_DEVICE=1 runs the dense/gang-reduce shuffle instead of
-    #: the per-record host path (vectorized end-to-end; sorts on
-    #: whatever backend JAX has — pin TPUMR_JAX_PLATFORM=cpu for the
-    #: host-dense row)
+    #: the per-record host path (vectorized end-to-end; sorts on the
+    #: accelerator devices, or on the CPU backend under JAX_PLATFORMS=cpu)
     gen_dir = os.environ.get("TERASORT_GEN_DIR")
     ram_mb = float(os.environ.get("TERASORT_RAM_MB", 0) or 0)
     device = os.environ.get("TERASORT_DEVICE") == "1"
-    plat = os.environ.get("TPUMR_JAX_PLATFORM")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)
 
     from tpumr.cli import main as cli_main
     from tpumr.core.counters import TaskCounter

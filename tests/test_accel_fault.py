@@ -718,7 +718,14 @@ class TestEndToEndHungTaskReap:
         from tpumr.fs import FileSystem
         from tpumr.mapred.mini_cluster import MiniMRCluster
         base = JobConf()
-        base.set("mapred.task.timeout", 2000)
+        # well above a child's start-up: the clock runs from launch, and
+        # an isolated child spends seconds importing (its mapper's module
+        # pulls in jax) before its first report — at 2 s every HEALTHY
+        # child was reaped too and the job never finished. No twin: past
+        # mapred.speculative.min.runtime.s a speculative attempt would
+        # win and the hung one would be KILLED, not reaped
+        base.set("mapred.task.timeout", 9000)
+        base.set("mapred.speculative.execution", False)
         base.set("tpumr.task.isolation", "process")
         # the hang comes from the sleep example's attempt-aware mode,
         # not the fi seam: fi's max.failures ledger is per-process, and

@@ -34,12 +34,11 @@ mesh = multihost.global_mesh(conf)
 assert len(mesh.devices.flatten()) == 4, mesh
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from tpumr.parallel import collectives
 local = np.array([rank * 2 + 0.0, rank * 2 + 1.0], dtype=np.float32)
 garr = jax.make_array_from_process_local_data(
     NamedSharding(mesh, P("data")), local, (4,))
-out = jax.jit(shard_map(lambda x: collectives.psum(x, "data"),
+out = jax.jit(jax.shard_map(lambda x: collectives.psum(x, "data"),
                         mesh=mesh, in_specs=P("data"), out_specs=P()))(garr)
 total = float(np.asarray(jax.device_get(out))[0])
 assert total == 6.0, total

@@ -179,41 +179,111 @@ def test_multihost_spec_and_single_host_noop(monkeypatch):
 
 
 class TestPersistentCompilationCache:
-    def test_conf_key_lands_in_jax_config(self, tmp_path, monkeypatch):
+    """Cache placement (parallel/jaxruntime.py): JAX_COMPILATION_CACHE_DIR
+    wins and the code then sets no directory; else the operator's
+    ``tpumr.jax.cache.dir``; else one fixed directory in the checkout."""
+
+    @pytest.fixture
+    def runtime(self, monkeypatch):
+        """jaxruntime reset before and after, JAX's cache dir restored,
+        and no cache directory in the environment unless a test sets it."""
+        import jax
+
+        from tpumr.parallel import jaxruntime
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        prev = jax.config.jax_compilation_cache_dir
+        jaxruntime._reset_for_tests()
+        yield jaxruntime
+        jax.config.update("jax_compilation_cache_dir", prev)
+        jaxruntime._reset_for_tests()
+
+    def test_conf_key_lands_in_jax_config(self, runtime, tmp_path):
         import jax
 
         from tpumr.mapred.jobconf import JobConf
-        from tpumr.parallel import jaxruntime
-        jaxruntime._reset_for_tests()
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            conf = JobConf()
-            conf.set("tpumr.jax.cache.dir", str(tmp_path / "jc"))
-            got = jaxruntime.configure_persistent_cache(conf)
-            assert got == str(tmp_path / "jc")
-            assert jax.config.jax_compilation_cache_dir == got
-            # idempotent: second caller (different conf) is a no-op
-            other = JobConf()
-            other.set("tpumr.jax.cache.dir", str(tmp_path / "other"))
-            assert jaxruntime.configure_persistent_cache(other) == got
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-            jaxruntime._reset_for_tests()
+        conf = JobConf()
+        conf.set("tpumr.jax.cache.dir", str(tmp_path / "jc"))
+        got = runtime.configure_persistent_cache(conf)
+        assert got == str(tmp_path / "jc")
+        assert jax.config.jax_compilation_cache_dir == got
+        # two calls agree: the second caller (different conf) is a no-op
+        other = JobConf()
+        other.set("tpumr.jax.cache.dir", str(tmp_path / "other"))
+        assert runtime.configure_persistent_cache(other) == got
+        assert not (tmp_path / "other").exists()
 
-    def test_disabled_with_none(self, monkeypatch):
+    @pytest.mark.parametrize("off", ["none", "off", "disabled", ""])
+    def test_disabled_by_conf(self, runtime, off):
         import jax
 
         from tpumr.mapred.jobconf import JobConf
-        from tpumr.parallel import jaxruntime
-        jaxruntime._reset_for_tests()
         prev = jax.config.jax_compilation_cache_dir
-        try:
-            conf = JobConf()
-            conf.set("tpumr.jax.cache.dir", "none")
-            assert jaxruntime.configure_persistent_cache(conf) is None
-            assert jax.config.jax_compilation_cache_dir == prev
-        finally:
-            jaxruntime._reset_for_tests()
+        conf = JobConf()
+        conf.set("tpumr.jax.cache.dir", off)
+        assert runtime.configure_persistent_cache(conf) is None
+        assert jax.config.jax_compilation_cache_dir == prev
+
+    def test_environment_variable_wins_and_code_sets_no_directory(
+            self, runtime, tmp_path, monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set, JAX's own handling stands:
+        configure_persistent_cache must not update the directory (JAX
+        read the variable at import; what it holds now is untouched)."""
+        import jax
+
+        from tpumr.mapred.jobconf import JobConf
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "from-env"))
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda k, v: (updates.append(k), real_update(k, v))[1])
+        before = jax.config.jax_compilation_cache_dir
+        conf = JobConf()
+        conf.set("tpumr.jax.cache.dir", str(tmp_path / "from-conf"))
+        got = runtime.configure_persistent_cache(conf)
+        assert "jax_compilation_cache_dir" not in updates
+        assert got == before == jax.config.jax_compilation_cache_dir
+        assert not (tmp_path / "from-conf").exists()
+
+    def test_unset_gives_the_fixed_directory_in_the_checkout(
+            self, runtime, monkeypatch):
+        import os
+
+        repo_root = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        assert runtime.DEFAULT_CACHE_DIR == os.path.join(repo_root,
+                                                         ".jax_cache")
+        monkeypatch.setenv("HOME", "/nonexistent-home")  # never consulted
+        got = runtime.configure_persistent_cache(None)
+        assert got == runtime.DEFAULT_CACHE_DIR
+        assert runtime.configure_persistent_cache(None) == got
+
+    def test_environment_variable_reaches_a_fresh_process(self, tmp_path):
+        """End to end in a fresh interpreter: the variable alone places
+        the cache, whatever the conf says."""
+        import os
+        import subprocess
+        import sys
+        repo_root = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        prog = (
+            "import sys\n"
+            "sys.path.insert(0, %r)\n"
+            "from tpumr.mapred.jobconf import JobConf\n"
+            "from tpumr.parallel.jaxruntime import "
+            "configure_persistent_cache\n"
+            "conf = JobConf()\n"
+            "conf.set('tpumr.jax.cache.dir', %r)\n"
+            "print(configure_persistent_cache(conf))\n"
+        ) % (repo_root, str(tmp_path / "from-conf"))
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "from-env"))
+        out = subprocess.run([sys.executable, "-c", prog], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip() == str(tmp_path / "from-env")
+        assert not (tmp_path / "from-conf").exists()
 
     def test_cache_populates_and_hits_across_processes(self, tmp_path):
         """Two fresh processes share compiles through the cache dir —
@@ -235,11 +305,11 @@ class TestPersistentCompilationCache:
             "conf.set('tpumr.jax.cache.min.compile.secs', 0.0)\n"
             "configure_persistent_cache(conf)\n"
             "import jax, jax.numpy as jnp\n"
-            "jax.config.update('jax_platforms', 'cpu')\n"
             "f = jax.jit(lambda x: jnp.sort(x * 2 + 1, axis=0))\n"
             "f(jnp.zeros((4096, 8))).block_until_ready()\n"
         ) % (repo_root, str(tmp_path / "xc"))
         env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)  # the conf key is tested
         entries = []
         for _ in range(2):
             out = subprocess.run([sys.executable, "-c", prog], env=env,

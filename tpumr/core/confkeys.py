@@ -526,7 +526,10 @@ _ENTRIES: "tuple[ConfKey, ...]" = (
         "Bound on queued history events before new ones are dropped and "
         "counted in history_writes_dropped (must stay 0 in bench runs)."),
     _K('tpumr.jax.cache.dir', 'str', None,
-        "JAX persistent compilation cache directory."),
+        "JAX persistent compilation cache directory; 'none' disables. "
+        "Unset: <checkout>/.jax_cache. Ignored where "
+        "JAX_COMPILATION_CACHE_DIR is set in the environment, which JAX "
+        "reads itself."),
     _K('tpumr.jax.cache.min.compile.secs', 'float', 0.5,
         "Min compile time before an executable is persisted, seconds."),
     _K('tpumr.job.id', 'str', '',

@@ -273,6 +273,11 @@ def main(argv: "list[str] | None" = None) -> int:
         print("usage: python -m tpumr.mapred.child <task-file>",
               file=sys.stderr)
         return 2
+    # the tracker process owns the accelerator (one process per chip) and
+    # only CPU attempts are isolated: host-side JAX work in this child
+    # must never try to open the device, so it gets the CPU backend
+    # before anything imports jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
     return run_child(argv[0])
 
 

@@ -3,7 +3,7 @@
 Inputs already have the HBM split cache (tpu_runner.split_cache); this
 module gives kernel OUTPUTS the same residency, so a chained pipeline
 (matmul → consumer, round N → round N+1) consumes its predecessor's
-output without the device→host→device tunnel roundtrip. Extends the
+output without the device→host→device round trip. Extends the
 reference's device-binding role (pipes Application.java:162-181 pins a
 binary to a device) into dataflow: what the previous kernel left on the
 chip IS the next job's input.
@@ -122,8 +122,8 @@ def lookup(conf: Any, device: Any, fs: Any, path: str, size: int,
     8 KB read to fingerprint the file — and nothing at all until some
     job in this process has actually published an output. The FIRST hit
     per on-disk identity additionally reads the whole file and checks
-    the publisher's full-content sha1: a local sequential read is far
-    cheaper than the tunnel upload being skipped, and it closes the
+    the publisher's full-content sha1: it costs one local sequential
+    read where the read AND the upload are being skipped, and it closes the
     boundary-window aliasing hole (same size+mtime+8 KB edges, different
     middle) that probabilistic fingerprints leave open."""
     if not _published_any:

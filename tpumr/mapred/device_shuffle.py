@@ -279,26 +279,23 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     shards = None
     overflow = 0
     if n > 0:
-        import jax
-        from tpumr.parallel.jaxruntime import configure_persistent_cache
+        from tpumr.parallel.jaxruntime import (accelerator_devices,
+                                               configure_persistent_cache)
         from tpumr.parallel.mesh import make_mesh
         from tpumr.parallel.device_sort import device_partition_sort
         configure_persistent_cache(conf)
-        mesh = make_mesh(devices=jax.local_devices())
+        devices = accelerator_devices()
+        mesh = make_mesh(devices=devices)
         capacity = conf.get_int(CAPACITY_KEY, 0) or None
         shards, overflow = device_partition_sort(
             mesh, records, klen, splitters, num_ranges, capacity=capacity)
-        # liveness tick for the bench wedge watchdog: the gang sort is
-        # one long device stretch with no other transfer chokepoint
-        from tpumr.utils import progress
-        progress.tick(int(records.nbytes), "gang-sort")
         if shards is not None:  # count only records the device actually moved
             reporter.incr_counter(BackendCounter.GROUP,
                                   BackendCounter.TPU_SHUFFLE_RECORDS, n)
             reporter.incr_counter(BackendCounter.GROUP,
                                   BackendCounter.TPU_SHUFFLE_BYTES,
                                   int(records.nbytes))
-            if jax.default_backend() != "cpu":
+            if devices[0].platform != "cpu":
                 reporter.incr_counter(BackendCounter.GROUP,
                                       BackendCounter.DEVICE_SORT_ON_ACCEL)
     if shards is None:

@@ -149,9 +149,10 @@ def default_tpu_probe(device_id: int) -> None:
     to it) is sick — exactly the signal the quarantine cares about."""
     import jax
     import numpy as np
-    devices = jax.local_devices()
-    d = devices[device_id % len(devices)]
-    jax.device_put(np.ones(8, np.float32), d).block_until_ready()
+
+    from tpumr.parallel.jaxruntime import accelerator_device
+    jax.device_put(np.ones(8, np.float32),
+                   accelerator_device(device_id)).block_until_ready()
 
 
 class TpuDeviceHealth:

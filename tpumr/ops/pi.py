@@ -33,9 +33,8 @@ def _count_inside_many(seeds, n: int):
     """All of a task's same-size sample blocks in ONE dispatch:
     ``lax.map`` runs the blocks sequentially on device (same transient
     memory as one block), so a task costs one small seed-array upload +
-    one program launch instead of a scalar upload + dispatch per record
-    — on a tunneled runtime the per-record launches were the task's
-    wall-clock. Per-seed results are bit-identical to :func:`_count_inside`."""
+    one program launch instead of a scalar upload + dispatch per record.
+    Per-seed results are bit-identical to :func:`_count_inside`."""
     def one(seed):
         key = jax.random.key(seed)
         pts = jax.random.uniform(key, (n, 2), dtype=jnp.float32)

@@ -50,14 +50,13 @@ class KernelMapper:
 
     # ---------------------------------------------- two-phase device protocol
     #
-    # Remote/tunneled TPU runtimes charge a full roundtrip per host
-    # transfer of a computed array (~tens of ms on a tunneled chip),
-    # while dispatch is asynchronous and ~free. Kernels that split into
+    # Dispatch is asynchronous; a host transfer of a computed array is
+    # where the host blocks on the device. Kernels that split into
     #   launch: dispatch device work, return a pytree of jax.Arrays
     #           (plain-python leaves pass through untouched), and
     #   drain:  turn the fetched host pytree into (key, value) records
     # let the runner batch MANY tasks' fetches into ONE jax.device_get —
-    # one roundtrip per pipeline window instead of per output array
+    # one host sync per pipeline window instead of per output array
     # (TpuMapRunner single-task path + LocalJobRunner windowed prelaunch).
 
     def map_batch_launch(self, batch: Any, conf: Any, task: Any) -> Any:
