@@ -340,10 +340,30 @@ class TestMiniClusterTracing:
         assert rc == 1
 
     def test_off_by_default_and_output_bytes_unchanged(
-            self, tmp_path_factory):
-        """Tracing is opt-in: an untraced cluster writes no span files
-        and stamps no trace context; enabling it changes observability
-        only — job output bytes are identical."""
+            self, tmp_path_factory, monkeypatch):
+        """Tracing is opt-in: an untraced cluster writes no span files,
+        stamps no trace context, makes no Span and opens no profiler
+        annotation; enabling it changes observability only — job output
+        bytes are identical."""
+        import jax
+        made = {"spans": 0, "annotations": 0}
+
+        class CountedSpan(tracing.Span):
+            def __init__(self, *a, **kw):
+                # not the heartbeat spans of another module-scoped
+                # cluster whose TRACKER conf enables tracing
+                if not kw["trace_id"].startswith("daemon-"):
+                    made["spans"] += 1
+                super().__init__(*a, **kw)
+
+        class CountedAnnotation(jax.profiler.TraceAnnotation):
+            def __init__(self, *a, **kw):
+                made["annotations"] += 1
+                super().__init__(*a, **kw)
+
+        monkeypatch.setattr(tracing, "Span", CountedSpan)
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            CountedAnnotation)
         hist = str(tmp_path_factory.mktemp("untraced-hist"))
         conf = JobConf()
         conf.set("tpumr.history.dir", hist)
@@ -376,12 +396,15 @@ class TestMiniClusterTracing:
             assert t["spans"] == [] and "not traced" in t["error"]
             assert not [f for f in os.listdir(hist)
                         if f.startswith("trace-")]
+            assert made == {"spans": 0, "annotations": 0}
             # per-JOB opt-in on an untraced cluster still traces
             traced_bytes, traced_jid = run("traced", traced=True)
             assert c.master.jobs[traced_jid].trace_id == traced_jid
             time.sleep(0.3)
             spans = c.master.get_job_trace(traced_jid)["spans"]
             assert {s["role"] for s in spans} >= {"jobtracker", "task"}
+            # the in-process tasks' ambient spans went to the profiler too
+            assert made["spans"] >= len(spans) and made["annotations"] > 0
             # observability must not perturb the data plane
             assert plain_bytes == traced_bytes and plain_bytes
 

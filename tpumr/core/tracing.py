@@ -29,6 +29,18 @@ Design:
   The JobMaster merges the files on demand (``/tracejson?job=`` and the
   ``get_job_trace`` RPC) into Chrome trace-event JSON loadable by
   ``chrome://tracing`` / Perfetto.
+- **Two clocks, each for what it is good at.** ``Span.start`` is
+  wall-clock, because the files of several processes are merged on it;
+  a span's LENGTH is taken from ``time.monotonic()`` (``end = start +
+  elapsed``), so a step of the wall clock mid-span cannot make a negative
+  or a stretched span.
+- **One timeline with the device profiler.** In a process that has
+  already imported ``jax`` (the tracker and its in-process tasks), an
+  ambient :func:`span` of a traced job also enters
+  ``jax.profiler.TraceAnnotation(name, span_id=..., trace_id=...)`` for
+  its length, so a profile taken meanwhile shows ``tpu:execute`` or
+  ``dshuffle:device`` on the host line above the device's operations, on
+  the profiler's own clock. This module never imports ``jax`` itself.
 - **Critical path.** :func:`critical_path` walks the span tree backward
   from the last-finishing leaf (the classic makespan-dominating chain)
   and reports each span's contribution — the measurement substrate every
@@ -40,6 +52,7 @@ from __future__ import annotations
 import json
 import os
 import re
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -124,7 +137,7 @@ class Span:
     """One timed operation. Mutable until :meth:`Tracer.finish`."""
 
     __slots__ = ("trace_id", "span_id", "parent_span_id", "name", "role",
-                 "backend", "start", "end", "attributes")
+                 "backend", "start", "end", "attributes", "_t0")
 
     def __init__(self, trace_id: str, span_id: str, parent_span_id: str,
                  name: str, role: str, backend: str = "",
@@ -139,6 +152,19 @@ class Span:
         self.start = start
         self.end = end
         self.attributes = attributes if attributes is not None else {}
+        #: monotonic twin of ``start``: lengths never read the wall clock
+        self._t0 = time.monotonic()
+
+    def elapsed(self) -> float:
+        """Monotonic seconds since the span was made."""
+        return time.monotonic() - self._t0
+
+    def backdate(self, start: float) -> "Span":
+        """Move the start back to an earlier wall-clock reading, on both
+        clocks: for a phase that is recorded once it is over."""
+        self._t0 -= self.start - start
+        self.start = start
+        return self
 
     def set(self, **attrs: Any) -> "Span":
         self.attributes.update(attrs)
@@ -151,7 +177,9 @@ class Span:
 
     @property
     def duration(self) -> float:
-        return max(0.0, (self.end or time.time()) - self.start)
+        if self.end:
+            return max(0.0, self.end - self.start)
+        return self.elapsed()
 
     def to_dict(self) -> dict:
         return {"trace_id": self.trace_id, "span_id": self.span_id,
@@ -214,7 +242,7 @@ class Tracer:
                     start=time.time(), attributes=dict(attrs))
 
     def finish(self, span: Span) -> Span:
-        span.end = time.time()
+        span.end = span.start + span.elapsed()
         span.attributes.setdefault("host", self.hostname)
         with self._lock:
             self._finished.append(span)
@@ -355,11 +383,25 @@ def current() -> "tuple[Tracer, Span] | None":
     return getattr(_ambient, "ctx", None)
 
 
+def _profiler_annotation(s: Span) -> Any:
+    """A ``jax.profiler.TraceAnnotation`` carrying the span's name and
+    ids, in a process where ``jax`` is already imported; None elsewhere
+    (a client, the master, a child pinned off JAX never import it for a
+    span's sake). Outside a profiler session the annotation is a no-op."""
+    jax = sys.modules.get("jax")
+    # getattr twice: another thread may be halfway through ``import jax``
+    cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    if cls is None:
+        return None
+    return cls(s.name, span_id=s.span_id, trace_id=s.trace_id)
+
+
 @contextmanager
 def span(name: str, backend: str = "", role: "str | None" = None,
          **attrs: Any) -> "Iterator[Span | None]":
     """Ambient child span: records under the thread's active span, or
-    no-ops (yielding None) when tracing is inactive."""
+    no-ops (yielding None) when tracing is inactive. Where ``jax`` is
+    loaded the span is mirrored onto the profiler's host line."""
     ctx = getattr(_ambient, "ctx", None)
     if ctx is None:
         yield None
@@ -368,14 +410,19 @@ def span(name: str, backend: str = "", role: "str | None" = None,
     s = tracer.start_span(name, parent.trace_id, parent=parent,
                           role=role or parent.role, backend=backend,
                           **attrs)
+    note = _profiler_annotation(s)
     prev = ctx
     _ambient.ctx = (tracer, s)
+    if note is not None:
+        note.__enter__()
     try:
         yield s
     except BaseException as e:
         s.set(error=f"{type(e).__name__}: {e}")
         raise
     finally:
+        if note is not None:
+            note.__exit__(None, None, None)
         _ambient.ctx = prev
         tracer.finish(s)
 

@@ -41,6 +41,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from tpumr.core import tracing
 from tpumr.core.counters import BackendCounter, TaskCounter
 from tpumr.mapred.api import OutputCollector, Reporter
 from tpumr.mapred.output_formats import FileOutputCommitter
@@ -243,8 +244,21 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
                       reporter: Reporter | None = None) -> None:
     """Execute the reduce gang task: fetch every map's dense output, run
     the device partition+exchange+sort, apply the job's reducer over each
-    range's sorted stream, write R part files, one commit."""
-    reporter = reporter or Reporter()
+    range's sorted stream, write R part files, one commit.
+
+    Traced jobs get one ``dshuffle`` span around all of it, and under it
+    a span per phase, each opened where the work is done:
+    ``dshuffle:locate`` / ``dshuffle:fetch`` (the fetch function),
+    ``dshuffle:assemble``, ``dshuffle:pack`` / ``dshuffle:device`` /
+    ``dshuffle:gather`` (``device_partition_sort``) or
+    ``dshuffle:host_sort`` (the fallback), ``dshuffle:write``. What the
+    parent does not spend in a child is its self time."""
+    with tracing.span("dshuffle") as ds:
+        _device_reduce(conf, task, dense_fetch, reporter or Reporter(), ds)
+
+
+def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
+                   reporter: Reporter, ds: "tracing.Span | None") -> None:
     from tpumr.mapred.map_task import localize_task_conf
     conf = localize_task_conf(conf, task)
     from tpumr.utils.fi import maybe_fail
@@ -265,15 +279,18 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
                              f"({klen},{vlen})")
         key_parts.append(k)
         val_parts.append(v)
-    keys = np.concatenate(key_parts) if key_parts else \
-        np.zeros((0, klen), np.uint8)
-    values = np.concatenate(val_parts) if val_parts else \
-        np.zeros((0, vlen), np.uint8)
-    n = keys.shape[0]
+    with tracing.span("dshuffle:assemble") as sp:
+        keys = np.concatenate(key_parts) if key_parts else \
+            np.zeros((0, klen), np.uint8)
+        values = np.concatenate(val_parts) if val_parts else \
+            np.zeros((0, vlen), np.uint8)
+        n = keys.shape[0]
+        records = np.concatenate([keys, values], axis=1)
+        splitters = _load_splitters(conf, keys, num_ranges, klen)
+        if sp is not None:
+            sp.set(rows=n, bytes=int(records.nbytes))
     reporter.incr_counter(TaskCounter.FRAMEWORK_GROUP,
                           TaskCounter.REDUCE_INPUT_RECORDS, n)
-    records = np.concatenate([keys, values], axis=1)
-    splitters = _load_splitters(conf, keys, num_ranges, klen)
 
     # ---- exchange + sort phase (device)
     shards = None
@@ -298,7 +315,8 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
             if devices[0].platform != "cpu":
                 reporter.incr_counter(BackendCounter.GROUP,
                                       BackendCounter.DEVICE_SORT_ON_ACCEL)
-    if shards is None:
+    host_fallback = shards is None
+    if host_fallback:
         # host fallback: full numpy lexsort, then the same range split
         # (≈ the disk-spill fallback role; correctness never depends on
         # the device path)
@@ -306,15 +324,19 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
             reporter.incr_counter(BackendCounter.GROUP,
                                   BackendCounter.SHUFFLE_HOST_FALLBACKS)
         from tpumr.parallel.device_sort import key_columns
-        kcols = key_columns(keys, klen) if n else None
-        order = np.lexsort(tuple(
-            kcols[:, c] for c in range(kcols.shape[1] - 1, -1, -1))) \
-            if n else np.zeros(0, int)
-        all_sorted = records[order]
+        with tracing.span("dshuffle:host_sort", rows=n):
+            kcols = key_columns(keys, klen) if n else None
+            order = np.lexsort(tuple(
+                kcols[:, c] for c in range(kcols.shape[1] - 1, -1, -1))) \
+                if n else np.zeros(0, int)
+            all_sorted = records[order]
         n_dev = 1
         shards = [all_sorted]
     else:
         n_dev = len(shards)
+    if ds is not None:
+        ds.set(rows=n, n_dev=n_dev, overflow=overflow,
+               host_fallback=host_fallback)
     ranges_per_dev = -(-num_ranges // n_dev)
     reporter.set_status(
         f"device shuffle: {n} records over {n_dev} devices in "
@@ -328,7 +350,10 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     wd = committer.setup_task(str(task.attempt_id))
     out_fmt = new_instance(conf.get_output_format(), conf)
 
-    def write_range(range_idx: int, rows: np.ndarray) -> None:
+    def write_range(range_idx: int, rows: np.ndarray,
+                    sp: "tracing.Span | None") -> None:
+        if sp is not None:
+            sp.set(rows=int(rows.shape[0]), bytes=int(rows.nbytes))
         writer = out_fmt.get_record_writer(conf, wd, range_idx)
         try:
             if identity:
@@ -345,14 +370,20 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
         if lo_r >= hi_r:
             continue
         shard = shards[d]
-        bounds = _range_boundaries(shard[:, :klen], splitters, lo_r, hi_r)
-        cuts = [0] + bounds + [shard.shape[0]]
+        cuts: "list[int] | None" = None
         for i, r in enumerate(range(lo_r, hi_r)):
-            write_range(r, shard[cuts[i]:cuts[i + 1]])
+            with tracing.span("dshuffle:write", range=r) as sp:
+                if cuts is None:
+                    # cutting a device's shard is booked to its first range
+                    cuts = [0] + _range_boundaries(
+                        shard[:, :klen], splitters, lo_r, hi_r) \
+                        + [shard.shape[0]]
+                write_range(r, shard[cuts[i]:cuts[i + 1]], sp)
             emitted.add(r)
     for r in range(num_ranges):  # ranges on idle devices: empty parts
         if r not in emitted:
-            write_range(r, np.zeros((0, klen + vlen), np.uint8))
+            with tracing.span("dshuffle:write", range=r) as sp:
+                write_range(r, np.zeros((0, klen + vlen), np.uint8), sp)
     # commit is the CALLER's job (tracker: master-gated can_commit;
     # local runner: direct commit_task) — same contract as run_reduce_task
 
@@ -409,6 +440,10 @@ def local_dense_fetch(map_outputs: "list[tuple[str, dict] | None]"
     def fetch(map_index: int) -> tuple[np.ndarray, np.ndarray]:
         ent = map_outputs[map_index]
         assert ent is not None, f"map {map_index} output missing"
-        return read_dense_output(ent[0])
+        with tracing.span("dshuffle:fetch", map_index=map_index) as sp:
+            k, v = read_dense_output(ent[0])
+            if sp is not None:
+                sp.set(bytes=int(k.nbytes + v.nbytes))
+        return k, v
 
     return fetch

@@ -400,6 +400,10 @@ class JobInProgress:
         #: jobs only ("" / None keeps every trace check a cheap miss)
         self.trace_id: str = str(self.conf.get("tpumr.trace.id", "") or "")
         self.trace_root: Any = None
+        #: attempt id -> span id of its ``schedule`` instant, for the
+        #: attempts of a traced job that have not ended yet: what the
+        #: master's ``task:done`` is parented to (popped there)
+        self.trace_sched: dict[str, str] = {}
         # --- master restart survival (attempt-level recovery) ---
         #: the interrupted job this one was recovered from (None for a
         #: normal submission): attempt ids carrying the OLD job id are

@@ -1883,6 +1883,7 @@ class JobMaster:
                 if fin_span is not None:
                     self.tracer.finish(fin_span.set(state=jip.state))
                 jip.trace_root = None
+                jip.trace_sched.clear()
                 self.tracer.finish(root.set(state=jip.state,
                                             error=jip.error or ""))
                 self.tracer.flush()
@@ -2578,8 +2579,7 @@ class JobMaster:
             return
         s = self.tracer.start_span(name, hb_trace.get("trace_id", ""),
                                    parent=hb_trace, **attrs)
-        s.start = start_wall
-        self.tracer.finish(s)
+        self.tracer.finish(s.backdate(start_wall))
 
     def _heartbeat(self, status: dict, initial_contact: bool,
                    ask_for_new_task: bool, response_id: int,
@@ -2788,6 +2788,19 @@ class JobMaster:
                         # replayed heartbeats re-deliver terminal
                         # statuses; log each attempt's outcome once
                         jip.history_logged.add(aid)
+                        if jip.trace_root is not None:
+                            # WHEN the master learnt the attempt ended:
+                            # against the end of the tracker's
+                            # task:launch (joined on attempt_id) this is
+                            # the report lag the heartbeat imposes
+                            self.tracer.instant(
+                                "task:done", jip.trace_id,
+                                parent=jip.trace_sched.pop(aid, None)
+                                or jip.trace_root,
+                                backend="tpu" if ts.is_map
+                                and ts.run_on_tpu else "cpu",
+                                attempt_id=aid, state=ts.state,
+                                is_map=ts.is_map, tracker=name)
                         if ts.state == TaskState.FAILED \
                                 and ts.failure_class == "timeout":
                             # a tracker reaped this attempt for progress
@@ -2989,6 +3002,7 @@ class JobMaster:
                         attempt_id=str(task.attempt_id), tracker=name)
                     task.trace = {"trace_id": tjip.trace_id,
                                   "span_id": sched.span_id}
+                    tjip.trace_sched[str(task.attempt_id)] = sched.span_id
                 # the believed-running set learns launches immediately:
                 # a launched-but-never-yet-reported attempt must still
                 # be requeued if this tracker is lost, and killed if

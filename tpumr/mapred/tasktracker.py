@@ -1525,10 +1525,17 @@ class NodeRunner:
         aid = str(task.attempt_id)
         backend = ("tpu" if task.run_on_tpu else "cpu") if task.is_map \
             else "cpu"
+        # ``slots``: the size of the pool this task's semaphore guards,
+        # so a reader can turn launch seconds into slot occupancy
         launch = tracer.start_span(
             "task:launch", task.trace["trace_id"], parent=task.trace,
             backend=backend, attempt_id=aid, tracker=self.name,
-            is_map=task.is_map, slot_wait_s=round(slot_wait_s, 6))
+            is_map=task.is_map, slot_wait_s=round(slot_wait_s, 6),
+            slots=max(1, self.max_reduce_slots if not task.is_map
+                      else self.max_tpu_map_slots if task.run_on_tpu
+                      else self.max_cpu_map_slots))
+        if task.run_on_tpu and task.tpu_device_id >= 0:
+            launch.set(device_id=task.tpu_device_id)
         try:
             isolated = False
             try:
@@ -2297,13 +2304,21 @@ class NodeRunner:
     def _remote_dense_fetch_factory(self, job_id: str, task: Task):
         """Dense fetch for device-shuffled jobs: pulls each map's whole
         fixed-width output (same serving seam, array payload)."""
+        from tpumr.core import tracing
         from tpumr.mapred.device_shuffle import parse_dense_bytes
 
         locate = self._map_locator(job_id)
 
         def fetch(map_index: int):
-            out = locate(map_index).call("get_map_output_dense", job_id,
-                                         map_index)
-            return parse_dense_bytes(out["data"])
+            # apart, so that waiting for a map to finish (the 200 ms poll
+            # of the master's completion events) is not read as copying
+            with tracing.span("dshuffle:locate", map_index=map_index):
+                source = locate(map_index)
+            with tracing.span("dshuffle:fetch", map_index=map_index) as sp:
+                out = source.call("get_map_output_dense", job_id,
+                                  map_index)
+                if sp is not None:
+                    sp.set(bytes=len(out["data"]))
+                return parse_dense_bytes(out["data"])
 
         return fetch

@@ -116,7 +116,11 @@ def runner_metrics():
     per-job PROFILED factor the scheduler derives from whole-task
     runtimes (job status ``acceleration_factor``). The two disagreeing
     is signal: profiled includes staging + per-task overhead, observed
-    is pure kernel wall time."""
+    is pure kernel wall time. ``tpu_hbm_bytes_in_use`` and
+    ``tpu_hbm_peak_bytes`` are the device memory as the program sees it:
+    ``memory_stats()`` of this process's TPU slot devices at scrape, the
+    largest over the devices (0 where the backend reports none, as the
+    CPU stand-in does, or before a slot device was ever asked for)."""
     from tpumr.metrics.core import process_registry
     reg = process_registry("tpu")
     reg.histogram("tpu_stage_seconds")
@@ -131,7 +135,16 @@ def runner_metrics():
         return cpu_mean / tpu_mean if tpu_mean > 0 else 0.0
 
     reg.set_gauge("tpu_observed_acceleration", _observed)
+    reg.set_gauge("tpu_hbm_bytes_in_use", lambda: _hbm_stat("bytes_in_use"))
+    reg.set_gauge("tpu_hbm_peak_bytes",
+                  lambda: _hbm_stat("peak_bytes_in_use"))
     return reg
+
+
+def _hbm_stat(stat: str) -> int:
+    from tpumr.parallel.jaxruntime import known_accelerator_devices
+    return max((int((d.memory_stats() or {}).get(stat, 0))
+                for d in known_accelerator_devices()), default=0)
 
 #: (kernel, input signature) pairs this process has dispatched before —
 #: the trace's compile-cache attribute: a first dispatch ("cold") pays

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from tpumr.core import tracing
+
 
 def num_key_columns(klen: int) -> int:
     return -(-klen // 4)
@@ -149,6 +151,12 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
     padding removed) or ``None`` when every retry overflowed (caller falls
     back to the host path — the reference's disk-spill role,
     ReduceTask.java:1080 ShuffleRamManager budget semantics).
+
+    Under a traced task both branches record the same three spans:
+    ``dshuffle:pack`` (host: what goes to the device is laid out),
+    ``dshuffle:device`` (from the first dispatch until the host holds the
+    result: copy in, the programs, copy out) and ``dshuffle:gather``
+    (host: rows into their final order).
     """
     from tpumr.parallel.mesh import shard_over
     from tpumr.parallel.shuffle import shuffle_dense
@@ -166,63 +174,89 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
         # sorted rows down); the value payload never leaves the host.
         if n0 == 0:
             return [records.copy()], 0
-        kcols = key_columns(records, klen)
-        # pad to the next power of two with all-FF sentinel keys so the
-        # jitted argsort compiles once per size BUCKET, not per exact n
-        # (XLA recompiles per shape, and a variadic sort is the slowest
-        # compile on this path — tests/test_chip_compile.py records it).
-        # lexsort is stable, so pad rows (indices >= n0) land after real
-        # rows even on all-FF keys.
-        n_pad = 1 << max(4, (n0 - 1).bit_length())
-        if n_pad != n0:
-            padded = np.full((n_pad, kcols.shape[1]), 0xFFFFFFFF, np.uint32)
-            padded[:n0] = kcols
-            kcols = padded
-        order = np.asarray(_argsort_keys(kcols.shape[1])(kcols))
-        if n_pad != n0:
-            order = order[order < n0]
-        return [records[order]], 0
+        with tracing.span("dshuffle:pack") as sp:
+            kcols = key_columns(records, klen)
+            # pad to the next power of two with all-FF sentinel keys so
+            # the jitted argsort compiles once per size BUCKET, not per
+            # exact n (XLA recompiles per shape, and a variadic sort is
+            # the slowest compile on this path —
+            # tests/test_chip_compile.py records it). lexsort is stable,
+            # so pad rows (indices >= n0) land after real rows even on
+            # all-FF keys.
+            n_pad = 1 << max(4, (n0 - 1).bit_length())
+            if n_pad != n0:
+                padded = np.full((n_pad, kcols.shape[1]), 0xFFFFFFFF,
+                                 np.uint32)
+                padded[:n0] = kcols
+                kcols = padded
+            if sp is not None:
+                sp.set(n_pad=n_pad, bytes_in=int(kcols.nbytes))
+        with tracing.span("dshuffle:device", devices=1, retries=0,
+                          bytes_in=int(kcols.nbytes)) as sp:
+            order = np.asarray(_argsort_keys(kcols.shape[1])(kcols))
+            if sp is not None:
+                sp.set(bytes_out=int(order.nbytes))
+        with tracing.span("dshuffle:gather", rows=n0,
+                          bytes=int(records.nbytes)):
+            if n_pad != n0:
+                order = order[order < n0]
+            return [records[order]], 0
 
     # trailing validity byte + pad rows (zeros → marked invalid) so the
     # leading dim divides the mesh; pads route to device 0 and are masked
     # out on the host after the sort
-    n = -(-n0 // n_dev) * n_dev
-    ext = np.zeros((n, w + 1), dtype=np.uint8)
-    ext[:n0, :w] = records
-    ext[:n0, w] = 1
+    with tracing.span("dshuffle:pack") as sp:
+        n = -(-n0 // n_dev) * n_dev
+        ext = np.zeros((n, w + 1), dtype=np.uint8)
+        ext[:n0, :w] = records
+        ext[:n0, w] = 1
+        sharded = shard_over(mesh, ext, axis_name)
+        if sp is not None:
+            sp.set(n_pad=n, bytes_in=int(ext.nbytes))
 
-    sharded = shard_over(mesh, ext, axis_name)
-    dest = make_dest_fn(mesh, klen, splitters, ranges_per_dev,
-                        axis_name)(sharded)
+    with tracing.span("dshuffle:device", devices=n_dev,
+                      bytes_in=int(ext.nbytes)) as sp:
+        dest = make_dest_fn(mesh, klen, splitters, ranges_per_dev,
+                            axis_name)(sharded)
 
-    if capacity is None:
-        # balanced per-(src,dst) load with 2x headroom for sampling skew;
-        # the receive side is only the ACTIVE destination devices (when
-        # num_ranges < mesh size, fewer devices share the whole load —
-        # dividing by n_dev² would systematically overflow)
-        active = max(1, -(-num_ranges // ranges_per_dev))
-        capacity = max(16, int(2 * n / (n_dev * active)))
-    overflowed = 0
-    for _attempt in range(max_retries + 1):
-        res = shuffle_dense(mesh, sharded, dest, capacity=capacity,
-                            axis_name=axis_name)
-        if int(res.overflow) == 0:
-            break
-        overflowed = int(res.overflow)
-        capacity *= 2
-    else:
-        return None, overflowed
+        if capacity is None:
+            # balanced per-(src,dst) load with 2x headroom for sampling
+            # skew; the receive side is only the ACTIVE destination
+            # devices (when num_ranges < mesh size, fewer devices share
+            # the whole load — dividing by n_dev² would systematically
+            # overflow)
+            active = max(1, -(-num_ranges // ranges_per_dev))
+            capacity = max(16, int(2 * n / (n_dev * active)))
+        overflowed = 0
+        for attempt in range(max_retries + 1):
+            res = shuffle_dense(mesh, sharded, dest, capacity=capacity,
+                                axis_name=axis_name)
+            if sp is not None:
+                sp.set(retries=attempt)
+            if int(res.overflow) == 0:
+                break
+            overflowed = int(res.overflow)
+            capacity *= 2
+        else:
+            return None, overflowed
 
-    sorted_recs, sorted_valid = make_sort_fn(mesh, klen, axis_name)(
-        res.values, res.valid)
-    host_recs = np.asarray(sorted_recs)
-    host_valid = np.asarray(sorted_valid)
-    per_dev = host_recs.shape[0] // n_dev
-    shards = []
-    for d in range(n_dev):
-        lo, hi = d * per_dev, (d + 1) * per_dev
-        rows = host_recs[lo:hi]
-        # mask-filter (order-preserving): drop unfilled slots AND padding
-        mask = host_valid[lo:hi] & (rows[:, w] == 1)
-        shards.append(rows[mask][:, :w])
+        sorted_recs, sorted_valid = make_sort_fn(mesh, klen, axis_name)(
+            res.values, res.valid)
+        host_recs = np.asarray(sorted_recs)
+        host_valid = np.asarray(sorted_valid)
+        if sp is not None:
+            sp.set(bytes_out=int(host_recs.nbytes + host_valid.nbytes))
+    with tracing.span("dshuffle:gather") as sp:
+        per_dev = host_recs.shape[0] // n_dev
+        shards = []
+        for d in range(n_dev):
+            lo, hi = d * per_dev, (d + 1) * per_dev
+            rows = host_recs[lo:hi]
+            # mask-filter (order-preserving): drop unfilled slots AND
+            # padding
+            mask = host_valid[lo:hi] & (rows[:, w] == 1)
+            shards.append(rows[mask][:, :w])
+        if sp is not None:
+            sp.set(rows=sum(s.shape[0] for s in shards),
+                   bytes=sum(int(s.nbytes) for s in shards))
     return shards, overflowed

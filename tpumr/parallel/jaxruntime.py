@@ -37,6 +37,8 @@ from typing import Any
 
 _lock = threading.Lock()
 _configured = False
+#: what accelerator_devices() last answered (empty before its first call)
+_known_devices: list = []
 
 #: the fixed default: inside the checkout, shared by every daemon and
 #: script started from it
@@ -98,13 +100,13 @@ def accelerator_devices() -> list:
     element *i*. Raises when JAX has no ``tpu`` device and the CPU
     backend was not explicitly requested — that is a chip that failed
     to initialise, not a place to run TPU tasks."""
+    global _known_devices
     import jax
     devices = jax.local_devices()
     tpus = [d for d in devices if d.platform == "tpu"]
-    if tpus:
-        return tpus
-    if cpu_backend_requested():
-        return list(devices)
+    if tpus or cpu_backend_requested():
+        _known_devices = tpus or list(devices)
+        return list(_known_devices)
     raise RuntimeError(
         "no TPU device: jax.local_devices() is "
         f"{[str(d) for d in devices]} and the CPU backend was not "
@@ -112,6 +114,15 @@ def accelerator_devices() -> list:
         "backend; fix the chip, configure zero TPU slots "
         "(mapred.tasktracker.map.tpu.tasks.maximum=0), or set "
         "JAX_PLATFORMS=cpu to rehearse on CPU devices.")
+
+
+def known_accelerator_devices() -> list:
+    """The slot devices this process has ALREADY been told of by
+    :func:`accelerator_devices`; empty before that. For a caller that
+    must never be the one to initialise a JAX backend: a metrics scrape
+    in a tracker without TPU slots would otherwise reach for the chip
+    another process owns."""
+    return list(_known_devices)
 
 
 def accelerator_device(dev_id: int = -1):
