@@ -472,7 +472,8 @@ def _bare_noderunner(interval_s=0.2):
     from tpumr.metrics.core import MetricsRegistry
     nr = object.__new__(NodeRunner)
     nr._stop = threading.Event()
-    nr.heartbeat_s = interval_s
+    nr._wake = threading.Event()
+    nr.heartbeat_s = nr._heartbeat_floor_s = interval_s
     nr.tracer = None                     # tracing off (the default)
     nr.master_unreachable = False
     nr._master_failures = 0
@@ -490,15 +491,16 @@ class TestHeartbeatErrorBackoff:
         still interrupts the wait promptly."""
         nr = _bare_noderunner(interval_s=0.1)
         beats = []
-        nr._heartbeat_once = lambda: (beats.append(time.time()),
-                                      (_ for _ in ()).throw(
-                                          ConnectionError("down")))
+        nr._heartbeat_once = lambda **kw: (beats.append(time.time()),
+                                          (_ for _ in ()).throw(
+                                              ConnectionError("down")))
         t = threading.Thread(target=nr._heartbeat_loop, daemon=True)
         t.start()
         time.sleep(1.0)
         assert nr.master_unreachable, \
             "transport failure must raise the lost-master flag"
         nr._stop.set()
+        nr._wake.set()               # as NodeRunner.stop() does
         t.join(timeout=1.0)
         assert not t.is_alive(), "stop must interrupt the backoff wait"
         assert len(beats) >= 2, "must keep retrying through the outage"
@@ -516,13 +518,14 @@ class TestHeartbeatErrorBackoff:
         from tpumr.ipc.rpc import RpcError
         nr = _bare_noderunner(interval_s=0.1)
         beats = []
-        nr._heartbeat_once = lambda: (beats.append(time.time()),
-                                      (_ for _ in ()).throw(
-                                          RpcError("handler raised")))
+        nr._heartbeat_once = lambda **kw: (beats.append(time.time()),
+                                          (_ for _ in ()).throw(
+                                              RpcError("handler raised")))
         t = threading.Thread(target=nr._heartbeat_loop, daemon=True)
         t.start()
         time.sleep(0.55)
         nr._stop.set()
+        nr._wake.set()               # as NodeRunner.stop() does
         t.join(timeout=1.0)
         assert not nr.master_unreachable
         assert nr._master_failures == 0

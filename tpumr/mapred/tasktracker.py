@@ -410,6 +410,9 @@ class NodeRunner:
         #: by start() (None on a tracker with no TPU slots)
         self.tpu_devices: "dict | None" = None
         self.heartbeat_s = conf.get_int("tpumr.heartbeat.interval.ms", 1000) / 1000.0
+        #: this tracker's own configured cadence: an out-of-band beat
+        #: goes out only while the master instructs no slower one
+        self._heartbeat_floor_s = self.heartbeat_s
 
         self.lock = threading.RLock()
         self.running: dict[str, TaskStatus] = {}      # attempt -> status
@@ -452,7 +455,10 @@ class NodeRunner:
         #: aid -> (state, phase, monotonic of last ship)
         self._status_shipped: "dict[str, tuple]" = {}
         self._stop = threading.Event()
-        self._hb_count = 0
+        #: set when an attempt goes terminal (_slot_freed): the heartbeat
+        #: loop sleeps on it, so a freed slot is reported and refilled
+        #: now and not on the next tick
+        self._wake = threading.Event()
         # --- lost-master state (master restart survival) ---
         #: True while the master is unreachable at the TRANSPORT level
         #: (connect refused / reset / timeout) — in-flight tasks keep
@@ -665,6 +671,9 @@ class NodeRunner:
         self._reaper_thread = threading.Thread(
             target=self._reaper_loop, name=f"{self.name}-task-reaper",
             daemon=True)
+        self._cleanup_thread = threading.Thread(
+            target=self._cleanup_loop, name=f"{self.name}-job-cleanup",
+            daemon=True)
 
     # ------------------------------------------------------------ lifecycle
 
@@ -690,6 +699,7 @@ class NodeRunner:
         self._server.start()
         self._hb_thread.start()
         self._reaper_thread.start()
+        self._cleanup_thread.start()
         self.metrics.start()
         if self.sampler is not None:
             self.sampler.start()
@@ -832,6 +842,7 @@ class NodeRunner:
 
     def stop(self) -> None:
         self._stop.set()
+        self._wake.set()
         if self.sampler is not None:
             self.sampler.stop()
         self.metrics.stop()
@@ -977,13 +988,36 @@ class NodeRunner:
 
     # ------------------------------------------------------------ heartbeat
 
+    #: least spacing of out-of-band beats: attempts that finish together
+    #: share one beat, and a stream of instant tasks cannot spin the loop
+    _OOB_MIN_GAP_S = 0.05
+
+    def _slot_freed(self) -> None:
+        """An attempt of this tracker has just gone terminal: a slot is
+        free and a completion waits to be told, so wake the heartbeat
+        loop (≈ TaskTracker.notifyTTAboutTaskCompletion). Called AFTER
+        the terminal state is set — the loop looks for it — and, where
+        the attempt held a slot semaphore, after its release, so the
+        task the early beat brings back finds the slot open."""
+        self._wake.set()
+
+    def _completion_waiting(self) -> bool:
+        with self.lock:
+            return any(st.state in TaskState.TERMINAL
+                       for st in self.running.values())
+
     def _heartbeat_loop(self) -> None:
         import random as _random
+        oob = False
         while not self._stop.is_set():
+            # cleared BEFORE the beat snapshots the statuses: an attempt
+            # that finishes while the RPC is in flight (reported
+            # RUNNING) sets it again and gets a beat of its own
+            self._wake.clear()
             wait_s = self.heartbeat_s
             try:
                 if self.tracer is None:
-                    self._heartbeat_once()
+                    self._heartbeat_once(oob=oob)
                 else:
                     # daemon-scoped trace (trace id = the tracker, not a
                     # job): heartbeat latency is where master contention
@@ -994,7 +1028,7 @@ class NodeRunner:
                     # beat's time went, master-side included.
                     with self.tracer.span("heartbeat",
                                           f"daemon-{self.name}") as hb:
-                        self._heartbeat_once(hb_span=hb)
+                        self._heartbeat_once(hb_span=hb, oob=oob)
             except (ConnectionError, OSError):
                 # LOST MASTER: transport-level failure (crashed,
                 # restarting, partitioned). In-flight tasks keep
@@ -1015,7 +1049,35 @@ class NodeRunner:
                 # answered (a raise inside the handler, an auth refusal)
                 # — keep the normal cadence, no lost-master backoff
                 pass
-            self._stop.wait(wait_s)
+            oob = self._await_next_beat(wait_s)
+
+    def _await_next_beat(self, wait_s: float) -> bool:
+        """Sleep until the next beat is due; True when that beat is an
+        out-of-band one (≈ mapreduce.tasktracker.outofband.heartbeat,
+        with no switch: it depends only on what the loop observes). The
+        timer runs from the beat just sent, whichever kind it was, so an
+        idle tracker still beats every ``wait_s``. A wake-up becomes an
+        early beat only while (a) the master instructs no slower cadence
+        than this tracker's own (a master that stretches it — adaptive
+        rate, brownout — is shedding load and is obeyed), (b) the master
+        is reachable (a wake must not defeat the lost-master backoff),
+        and a completion is in fact waiting; (c) early beats keep
+        ``_OOB_MIN_GAP_S`` from the beat before. So a tracker sends no
+        more early beats than it finishes tasks."""
+        sent = time.monotonic()
+        while True:
+            left = sent + wait_s - time.monotonic()
+            if left <= 0 or not self._wake.wait(left) \
+                    or self._stop.is_set():
+                return False
+            # clear, THEN look: a completion between the two sets it again
+            self._wake.clear()
+            if not self.master_unreachable \
+                    and self.heartbeat_s <= self._heartbeat_floor_s \
+                    and self._completion_waiting():
+                self._stop.wait(max(0.0, sent + self._OOB_MIN_GAP_S
+                                    - time.monotonic()))
+                return True
 
     def _metrics_piggyback(self) -> dict:
         """The compact metrics snapshot that rides every heartbeat:
@@ -1061,8 +1123,12 @@ class NodeRunner:
             out.append(sd)
         return out
 
-    def _heartbeat_once(self, hb_span: Any = None) -> None:
+    def _heartbeat_once(self, hb_span: Any = None,
+                        oob: bool = False) -> None:
         full = self._status_dict()
+        # the completions this beat tells: dropped once it is delivered
+        sent_terminal = {sd["attempt_id"] for sd in full["task_statuses"]
+                         if sd["state"] in TaskState.TERMINAL}
         now = time.monotonic()
         metrics = None
         if now - self._piggyback_last >= self._piggyback_interval_s:
@@ -1102,6 +1168,10 @@ class NodeRunner:
             self._hb_encoder.reset()
             raise
         self._hb_encoder.delivered()
+        if oob:
+            self._mreg.incr("heartbeats_out_of_band")
+            if hb_span is not None:
+                hb_span.set(oob=True, freed=len(sent_terminal))
         # re-contact: the lost-master state clears the moment a beat
         # lands (the master that answered has adopted our full status)
         self.master_unreachable = False
@@ -1139,9 +1209,6 @@ class NodeRunner:
             # that finished while the RPC was in flight was reported as
             # RUNNING, so it must survive until the next heartbeat or the
             # master never learns it completed.
-            sent_terminal = {sd["attempt_id"]
-                             for sd in full.get("task_statuses", [])
-                             if sd["state"] in TaskState.TERMINAL}
             for aid in sent_terminal:
                 self.running.pop(aid, None)
                 self._status_shipped.pop(aid, None)
@@ -1153,9 +1220,18 @@ class NodeRunner:
                 self._umb_ticks.pop(aid, None)
         for action in resp["actions"]:
             self._apply_action(action)
-        self._hb_count += 1
-        if self._hb_count % 20 == 0:
-            self._cleanup_finished_jobs()
+
+    def _cleanup_loop(self) -> None:
+        """The sweep of finished jobs, every 20 heartbeat intervals on a
+        thread of its own: it asks the master about every known job and
+        removes their scratch trees, 0.6 to 2 s beside busy map threads,
+        and the beat's thread has to stay free to tell a completion the
+        moment it happens."""
+        while not self._stop.wait(20 * self.heartbeat_s):
+            try:
+                self._cleanup_finished_jobs()
+            except Exception:  # noqa: BLE001 — retried next sweep
+                pass
 
     def _cleanup_finished_jobs(self) -> None:
         """Drop map outputs + cached confs of terminal jobs (≈ the
@@ -1303,6 +1379,7 @@ class NodeRunner:
                 for aid in list(self.running_tasks):
                     self._kill_requested.add(aid)
             self._stop.set()
+            self._wake.set()
 
     # ------------------------------------------------------------ execution
 
@@ -1512,6 +1589,10 @@ class NodeRunner:
                                   time.monotonic() - wait_t0)
         finally:
             sem.release()  # ≈ addFreeSlots on done/kill (:3401-3402)
+            # the status is terminal, the launch span ended and the slot
+            # is open: NOW tell the master, so that what it sends back
+            # does not queue behind the attempt that just ended
+            self._slot_freed()
 
     def _run_task_traced(self, tracer: Any, job_id: str, task: Task,
                          status: TaskStatus, reporter: Reporter,
@@ -1851,6 +1932,8 @@ class NodeRunner:
             st.finish_time = time.time()
             st.state = TaskState.FAILED
             task = self.running_tasks.get(aid)
+        # a hung in-process thread may never reach _run_task's release
+        self._slot_freed()
         self._mreg.incr("tasks_reaped_timeout")
         if task is not None and task.trace is not None:
             try:
@@ -2045,6 +2128,9 @@ class NodeRunner:
                 st.diagnostics = final.get("diagnostics", "")
                 st.finish_time = time.time()
                 st.state = final.get("state", TaskState.SUCCEEDED)
+                # the child still has to exit (its babysitter holds the
+                # slot ~0.1 s more); the master can hear of it already
+                self._slot_freed()
                 if out_path and index:
                     # size-aware shuffle: isolated children report their
                     # output size exactly like in-process attempts do
@@ -2079,6 +2165,7 @@ class NodeRunner:
                 st.failure_class = str(failure_class or "")
                 st.state = (state if state in TaskState.TERMINAL
                             else TaskState.FAILED)
+                self._slot_freed()
 
     # ------------------------------------------------- fetch failures
 

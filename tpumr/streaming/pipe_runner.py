@@ -123,7 +123,10 @@ class _StreamProcess:
         self.proc.stdin.write(f"{value}\n".encode("utf-8"))
 
     def finish(self, what: str) -> None:
-        self.proc.stdin.close()
+        try:
+            self.proc.stdin.close()
+        except BrokenPipeError:
+            pass    # the child is gone already: its rc below says why
         self._out_thread.join()
         self._err_thread.join()
         rc = self.proc.wait()
