@@ -154,25 +154,31 @@ def test_one_device_argsort(one_chip, record_property):
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_device_shuffle_program(meshes, record_property, n_dev, program):
     """The device shuffle's three programs over a mesh of described
-    chips: destination from the sampled splitters, the all_to_all
-    exchange, the per-device lexsort + row gather."""
+    chips: destination from the sampled splitters (a runtime argument),
+    the all_to_all exchange, the per-device lexsort + row gather; every
+    shape from ``bucket_rows``, as ``device_partition_sort`` runs them."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from tpumr.parallel.device_sort import make_dest_fn, make_sort_fn
+    from tpumr.parallel.device_sort import (bucket_rows, make_dest_fn,
+                                            make_sort_fn, num_key_columns)
     from tpumr.parallel.shuffle import make_shuffle
     mesh = meshes[n_dev]
     rows = NamedSharding(mesh, P("data"))
     w = ROW_W + 1                       # rows carry a validity byte
-    # device_partition_sort's own sizing: 2x headroom per (src, dst)
-    # bucket, so each device sorts twice the rows it sent
-    n = SORT_ROWS // 2 * n_dev
-    capacity = max(16, int(2 * n / (n_dev * n_dev)))
+    # device_partition_sort's own sizing, all from the shape bucket: a
+    # job of a few rows under SORT_ROWS // 2 a device is padded up to it,
+    # and 2x headroom per (src, dst) bucket of the exchange makes each
+    # device sort twice the rows it sent
+    n = bucket_rows(SORT_ROWS // 2 * n_dev - 37, n_dev)
+    assert n == SORT_ROWS // 2 * n_dev
+    capacity = max(16, 2 * (n // n_dev) // n_dev)
     if program == "dest":
-        splitters = np.random.default_rng(0).integers(
-            0x20, 0x7F, size=(3, KLEN), dtype=np.uint8)
+        # the splitters are an argument: [r-1, cols] uint32, replicated
         compiled, mem, secs = _compile(
-            make_dest_fn(mesh, KLEN, splitters, ranges_per_dev=1),
-            _shape((n, w), np.uint8, rows))
+            make_dest_fn(mesh, KLEN, 1, n_dev),
+            _shape((n, w), np.uint8, rows),
+            _shape((3, num_key_columns(KLEN)), np.uint32,
+                   NamedSharding(mesh, P())))
     elif program == "exchange":
         compiled, mem, secs = _compile(
             make_shuffle(mesh, capacity),

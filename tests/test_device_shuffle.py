@@ -93,7 +93,174 @@ class TestDeviceSortPrimitives:
         assert shards is None and overflow > 0
 
 
+def _seeded_case(case: str, n_dev: int):
+    """(records, splitters, num_ranges) of one named input, from a seed."""
+    rng = np.random.default_rng([20261001, n_dev])
+    klen, vlen, num_ranges = 10, 14, n_dev
+    n = 3000
+    if case == "not_divisible":
+        n = 3000 + n_dev - 1
+    if case == "empty":
+        n = 0
+    keys = rng.integers(0x20, 0x7F, size=(n, klen), dtype=np.uint8)
+    if case == "duplicate_heavy":
+        keys = keys[rng.integers(0, 5, size=n)]     # five distinct keys
+    if case == "fewer_ranges":
+        num_ranges = n_dev - 2
+    samp = rng.integers(0x20, 0x7F, size=(64, klen), dtype=np.uint8) \
+        if n == 0 else keys[rng.integers(0, n, size=64)]
+    samp = samp[np.lexsort(tuple(samp[:, c]
+                                 for c in range(klen - 1, -1, -1)))]
+    splitters = samp[[round(i * 64 / num_ranges)
+                      for i in range(1, num_ranges)]]
+    if case == "equal_to_a_splitter":
+        keys[::7] = splitters[rng.integers(0, len(splitters),
+                                           size=len(keys[::7]))]
+    values = rng.integers(0, 256, size=(n, vlen), dtype=np.uint8)
+    return np.concatenate([keys, values], axis=1), splitters, num_ranges
+
+
+MESH_CASES = ["uniform", "duplicate_heavy", "equal_to_a_splitter",
+              "fewer_ranges", "not_divisible", "empty"]
+
+
+@pytest.mark.parametrize("case", MESH_CASES)
+@pytest.mark.parametrize("n_dev", [4, 8])
+def test_mesh_partition_sort_agrees_with_numpy_lexsort(n_dev, case):
+    """The mesh branch against a plain ``numpy.lexsort`` of the same
+    seeded rows: the shards in device order are the sorted input, each
+    device holds exactly its ranges (a key equal to a cut stays in the
+    lower one), and the bucket's padding is gone."""
+    from tpumr.parallel.device_sort import (bucket_rows, compute_dest,
+                                            device_partition_sort,
+                                            key_columns)
+    from tpumr.parallel.mesh import make_mesh
+    records, splitters, num_ranges = _seeded_case(case, n_dev)
+    klen, n = 10, records.shape[0]
+    stats = {}
+    shards, overflow = device_partition_sort(
+        make_mesh(n_dev), records, klen, splitters, num_ranges, stats=stats)
+    assert shards is not None and len(shards) == n_dev
+    # five distinct keys may fill a bucket: then a retry, never a loss
+    assert overflow == 0 or case == "duplicate_heavy"
+    assert stats == {"pad_rows": bucket_rows(n, n_dev) - n,
+                     "retries": 1 if overflow else 0}
+    kcols = key_columns(records[:, :klen], klen)
+    order = np.lexsort(tuple(kcols[:, c] for c in range(2, -1, -1)))
+    want = records[order]
+    got = np.concatenate(shards)
+    assert got.shape == want.shape and got.dtype == np.uint8
+    # row for row: the exchange keeps a source's order, the devices'
+    # shares are dealt in input order and both sorts are stable, so
+    # equal keys come out in the order they went in, as numpy's do
+    assert (got == want).all()
+    ranges_per_dev = -(-num_ranges // n_dev)
+    for d, shard in enumerate(shards):
+        if shard.shape[0]:
+            dest = compute_dest(key_columns(shard[:, :klen], klen),
+                                key_columns(splitters, klen))
+            assert set(np.unique(dest // ranges_per_dev)) == {d}
+    active = -(-num_ranges // ranges_per_dev)
+    assert all(s.shape[0] == 0 for s in shards[active:])
+
+
+def test_mesh_programs_compile_once_per_bucket_not_per_input():
+    """Three inputs in a row on one mesh: other splitters and another row
+    count inside the same bucket add nothing to the jitted functions'
+    caches; a row count in the next bucket adds one entry to each."""
+    from tpumr.parallel.device_sort import (bucket_rows,
+                                            device_partition_sort,
+                                            make_dest_fn, make_sort_fn)
+    from tpumr.parallel.mesh import make_mesh
+    from tpumr.parallel.shuffle import make_shuffle
+    n_dev, klen, num_ranges = 4, 10, 4
+    width = 27      # no other test's rows: the caches below are shared
+    mesh = make_mesh(n_dev)
+    first, second, third = 5000, 4700, 5300
+    assert bucket_rows(first, n_dev) == bucket_rows(second, n_dev) == 5120
+    assert bucket_rows(third, n_dev) == 6144
+    rng = np.random.default_rng(11)
+
+    def one(n):
+        records = rng.integers(0x20, 0x7F, size=(n, width), dtype=np.uint8)
+        samp = records[rng.integers(0, n, size=3), :klen]
+        splitters = samp[np.lexsort(tuple(
+            samp[:, c] for c in range(klen - 1, -1, -1)))]
+        shards, _ = device_partition_sort(mesh, records, klen, splitters,
+                                          num_ranges)
+        got = np.concatenate(shards)
+        assert got.shape[0] == n
+        keys = [bytes(k) for k in got[:, :klen]]
+        assert keys == sorted(keys)
+        return splitters
+
+    def compiled():
+        """Executables held by the three jitted functions, the exchange's
+        at the capacity of each of the two buckets."""
+        return [make_dest_fn(mesh, klen, 1, 4, "data")._cache_size(),
+                make_sort_fn(mesh, klen, "data")._cache_size()] + [
+            make_shuffle(mesh, 2 * (rows // n_dev) // 4, "data",
+                         with_keys=False)._cache_size()
+            for rows in (5120, 6144)]
+
+    before = compiled()
+    a = one(first)
+    after_first = compiled()
+    assert [x - y for x, y in zip(after_first, before)] == [1, 1, 1, 0]
+    b = one(second)
+    assert not (a == b).all()               # other splitters, other rows
+    assert compiled() == after_first        # and nothing compiled
+    one(third)
+    assert [x - y for x, y in zip(compiled(), after_first)] == [1, 1, 0, 1]
+
+
+def test_skewed_input_retries_then_gives_up_with_the_retries_counted():
+    """Every row for one range with a capacity far too small: the
+    exchange overflows, is retried twice with doubled capacity, and the
+    caller is handed None (it sorts on the host)."""
+    from tpumr.parallel.device_sort import device_partition_sort
+    from tpumr.parallel.mesh import make_mesh
+    rng = np.random.default_rng(9)
+    records = rng.integers(0, 256, size=(2048, 12), dtype=np.uint8)
+    stats = {}
+    shards, overflow = device_partition_sort(
+        make_mesh(4), records, 10, np.zeros((0, 10), np.uint8), 1,
+        capacity=16, stats=stats)
+    assert shards is None and overflow > 0
+    assert stats["retries"] == 2 and stats["pad_rows"] == 0
+    # one doubling is enough here: a retry, then a result
+    stats = {}
+    shards, overflow = device_partition_sort(
+        make_mesh(4), records, 10, np.zeros((0, 10), np.uint8), 1,
+        capacity=256, stats=stats)
+    assert overflow > 0 and stats["retries"] == 1
+    assert sum(s.shape[0] for s in shards) == 2048
+
+
 class TestDeviceShuffleLocalJob:
+    def test_an_overflowing_job_counts_its_retries_and_sorts_on_the_host(
+            self):
+        from tpumr.examples.terasort import make_terasort_conf
+        from tpumr.mapred.device_shuffle import CAPACITY_KEY
+        fs = get_filesystem("mem:///")
+        _teragen("mem:///dso/gen", 4000, maps=2)
+        conf = make_terasort_conf("mem:///dso/gen", "mem:///dso/out", 4,
+                                  device_shuffle=True)
+        conf.set(CAPACITY_KEY, 2)
+        result = run_job(conf)
+        assert result.successful
+
+        def counted(name):
+            return result.counters.value(BackendCounter.GROUP, name)
+
+        assert counted(BackendCounter.TPU_SHUFFLE_RETRIES) == 2
+        assert counted(BackendCounter.SHUFFLE_HOST_FALLBACKS) == 1
+        assert counted(BackendCounter.TPU_SHUFFLE_DEVICES) == 0
+        assert counted(BackendCounter.TPU_SHUFFLE_RECORDS) == 0
+        keys = [k for k, _ in _read_parts(fs, "/dso/out")[0]]
+        assert len(keys) == 4000 and keys == sorted(keys)
+
+
     def test_terasort_device_shuffle_local(self):
         """Terasort through LocalJobRunner with the device reduce: output
         part files globally sorted, same multiset, R parts kept."""
@@ -114,6 +281,13 @@ class TestDeviceShuffleLocalJob:
         shuffled = result.counters.value(BackendCounter.GROUP,
                                        BackendCounter.TPU_SHUFFLE_RECORDS)
         assert shuffled == 900
+        assert result.counters.value(
+            BackendCounter.GROUP, BackendCounter.TPU_SHUFFLE_DEVICES) == 8
+        assert result.counters.value(
+            BackendCounter.GROUP, BackendCounter.TPU_SHUFFLE_RETRIES) == 0
+        assert result.counters.value(
+            BackendCounter.GROUP,
+            BackendCounter.TPU_SHUFFLE_PAD_ROWS) == 8 * 128 - 900
 
     def test_device_shuffle_with_real_reducer(self):
         """A non-identity reducer still runs (grouped over the device-sorted

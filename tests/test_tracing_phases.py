@@ -129,6 +129,39 @@ def test_phase_rows_and_bytes_match_the_jobs_counters(sorted_job):
     assert sum(w["bytes"] for w in writes) == moved
 
 
+def test_the_mesh_device_call_has_a_child_span_per_step(sorted_job):
+    """Under ``dshuffle:device`` on a mesh: copy in, destination, one
+    exchange per attempt, sort, copy out; in that order, each closed when
+    its result is ready, so that they add up to the device call."""
+    spans, counters = sorted_job["spans"], sorted_job["counters"]
+    device = next(s for s in spans if s["name"] == "dshuffle:device")
+    kids = _children(spans, device)
+    assert [k["name"] for k in kids] == [
+        "dshuffle:put", "dshuffle:dest", "dshuffle:exchange",
+        "dshuffle:sort", "dshuffle:get"]
+    for a, b in zip(kids, kids[1:]):
+        assert a["end"] <= b["start"] + 1e-4, (a["name"], b["name"])
+    # starts are wall-clock, lengths monotonic: equal to a few us only
+    assert device["start"] - 1e-4 <= kids[0]["start"]
+    assert kids[-1]["end"] <= device["end"] + 1e-4
+    covered = sum(k["end"] - k["start"] for k in kids)
+    assert covered >= 0.9 * (device["end"] - device["start"])
+    put, _dest, exchange, _sort, get = (k["attributes"] for k in kids)
+    assert put["bytes"] == device["attributes"]["bytes_in"]
+    assert get["bytes"] == device["attributes"]["bytes_out"]
+    assert exchange["attempt"] == 0 and exchange["overflow"] == 0
+    # 6000 rows on eight devices: 768 a device, so 2 x 768 / 4 ranges...
+    pad = counters.value(BackendCounter.GROUP,
+                         BackendCounter.TPU_SHUFFLE_PAD_ROWS)
+    assert pad == 8 * 768 - ROWS
+    assert exchange["capacity"] == 2 * 768 // RANGES
+    assert put["bytes"] == 8 * 768 * 101
+    assert counters.value(BackendCounter.GROUP,
+                          BackendCounter.TPU_SHUFFLE_DEVICES) == 8
+    assert counters.value(BackendCounter.GROUP,
+                          BackendCounter.TPU_SHUFFLE_RETRIES) == 0
+
+
 def test_every_finished_attempt_has_one_task_done_after_its_launch(
         sorted_job):
     spans = sorted_job["spans"]

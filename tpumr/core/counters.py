@@ -71,6 +71,12 @@ class BackendCounter:
     #: path ran
     DEVICE_SORT_ON_ACCEL = "DEVICE_SORT_ON_ACCEL"
     SHUFFLE_HOST_FALLBACKS = "SHUFFLE_HOST_FALLBACKS"
+    #: what the gang reduce's device call did: the mesh size the exchange
+    #: and sort ran over (1: the one-device argsort), the overflow
+    #: retries of the exchange, the rows its shape bucket added
+    TPU_SHUFFLE_DEVICES = "TPU_SHUFFLE_DEVICES"
+    TPU_SHUFFLE_RETRIES = "TPU_SHUFFLE_RETRIES"
+    TPU_SHUFFLE_PAD_ROWS = "TPU_SHUFFLE_PAD_ROWS"
     GROUP = "tpumr.BackendCounter"
 
 

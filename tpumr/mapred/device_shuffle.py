@@ -249,8 +249,10 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     Traced jobs get one ``dshuffle`` span around all of it, and under it
     a span per phase, each opened where the work is done:
     ``dshuffle:locate`` / ``dshuffle:fetch`` (the fetch function),
-    ``dshuffle:assemble``, ``dshuffle:pack`` / ``dshuffle:device`` /
-    ``dshuffle:gather`` (``device_partition_sort``) or
+    ``dshuffle:assemble``, ``dshuffle:pack`` / ``dshuffle:device`` (on a
+    mesh with a child per step: ``:put``, ``:dest``, ``:exchange``,
+    ``:sort``, ``:get``) / ``dshuffle:gather``
+    (``device_partition_sort``) or
     ``dshuffle:host_sort`` (the fallback), ``dshuffle:write``. What the
     parent does not spend in a child is its self time."""
     with tracing.span("dshuffle") as ds:
@@ -304,9 +306,20 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
         devices = accelerator_devices()
         mesh = make_mesh(devices=devices)
         capacity = conf.get_int(CAPACITY_KEY, 0) or None
+        stats: dict = {}
         shards, overflow = device_partition_sort(
-            mesh, records, klen, splitters, num_ranges, capacity=capacity)
+            mesh, records, klen, splitters, num_ranges, capacity=capacity,
+            stats=stats)
+        reporter.incr_counter(BackendCounter.GROUP,
+                              BackendCounter.TPU_SHUFFLE_RETRIES,
+                              stats.get("retries", 0))
+        reporter.incr_counter(BackendCounter.GROUP,
+                              BackendCounter.TPU_SHUFFLE_PAD_ROWS,
+                              stats.get("pad_rows", 0))
         if shards is not None:  # count only records the device actually moved
+            reporter.incr_counter(BackendCounter.GROUP,
+                                  BackendCounter.TPU_SHUFFLE_DEVICES,
+                                  len(shards))
             reporter.incr_counter(BackendCounter.GROUP,
                                   BackendCounter.TPU_SHUFFLE_RECORDS, n)
             reporter.incr_counter(BackendCounter.GROUP,

@@ -12,6 +12,12 @@ becomes three XLA programs over a mesh:
    moves every record to the device that owns its range;
 3. ``sort_local_shards`` — each device lexsorts what it received.
 
+The mesh path compiles once per SHAPE BUCKET, never per job: the sampled
+splitters are a runtime argument of ``compute_dest``'s program, and row
+counts are padded up to ``bucket_rows`` (each device's share rounded up to
+4, 5, 6 or 7 x 2^k), from which the exchange's capacity and the sort's
+shape follow.
+
 Keys are fixed-width byte strings (the device-sortable case called out in
 SURVEY.md §7: terasort's 10-byte keys); they are packed into big-endian
 uint32 columns so lexicographic byte order == multi-column numeric order,
@@ -76,52 +82,80 @@ def compute_dest(key_cols, splitter_cols):
     return dest
 
 
-@functools.lru_cache(maxsize=32)
-def _make_dest_fn(mesh: Mesh, klen: int, splitters_key: bytes,
-                  ranges_per_dev: int, axis_name: str):
-    splitters = np.frombuffer(splitters_key, dtype=np.uint8).reshape(-1, klen)
-    splitter_cols = key_columns(splitters, klen) if len(splitters) else \
-        np.zeros((0, num_key_columns(klen)), np.uint32)
+def bucket_rows(n: int, n_dev: int) -> int:
+    """The row count the mesh branch pads ``n`` rows to, from ``n`` and the
+    mesh size alone: each device's share ``ceil(n / n_dev)`` rounded up to
+    the next of 4, 5, 6, 7 x 2^k (its top three bits kept; at least 64),
+    times ``n_dev``. Every shape the three programs compile for follows
+    from it, so inputs whose shares fall between the same two edges run
+    the same executables. Padding is under 25 % of ``n`` (plus the
+    64-row floor a device): four buckets an octave, where the one-device
+    branch's power of two would copy up to 2x the rows in and out."""
+    local = -(-n // n_dev)
+    if local <= 64:
+        return 64 * n_dev
+    step = 1 << ((local - 1).bit_length() - 3)
+    return -(-local // step) * step * n_dev
 
-    @partial(jax.shard_map, mesh=mesh, in_specs=P(axis_name),
+
+def splitter_columns(splitters: np.ndarray, klen: int,
+                     num_ranges: int) -> np.ndarray:
+    """``[r-1, klen]`` uint8 cut points → the ``[r-1, cols]`` uint32 array
+    the destination program takes at run time. A SHORT cut list
+    (write_partition_file dedups duplicate samples) is filled up to
+    ``num_ranges - 1`` rows of all-FF words, which no key exceeds: a
+    missing splitter acts as +inf, and the shape stays the job's."""
+    ncols = num_key_columns(klen)
+    cols = key_columns(np.asarray(splitters, np.uint8).reshape(-1, klen),
+                       klen) if len(splitters) else \
+        np.zeros((0, ncols), np.uint32)
+    fill = num_ranges - 1 - cols.shape[0]
+    if fill > 0:
+        cols = np.concatenate(
+            [cols, np.full((fill, ncols), 0xFFFFFFFF, np.uint32)])
+    return cols
+
+
+@functools.lru_cache(maxsize=32)
+def make_dest_fn(mesh: Mesh, klen: int, ranges_per_dev: int, active: int,
+                 axis_name: str = "data"):
+    """Jitted SPMD map ``(rows, splitter_cols)`` → destination *device*
+    (range // ranges_per_dev). ``rows`` are sharded and end in a validity
+    byte; ``splitter_cols`` (``splitter_columns``) is replicated and a
+    RUNTIME argument, so one executable serves every job of the same
+    shapes. Padding rows (validity 0) carry no record: they are dealt
+    round-robin over the ``active`` receiving devices, so that no bucket
+    of the exchange fills with them."""
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(axis_name), P()),
              out_specs=P(axis_name))
-    def _dest(records):
+    def _dest(records, splitter_cols):
         cols = key_columns(records, klen)
-        rng = compute_dest(cols, jnp.asarray(splitter_cols))
-        return rng // ranges_per_dev
+        dev = compute_dest(cols, splitter_cols) // ranges_per_dev
+        spread = jnp.arange(records.shape[0], dtype=jnp.int32) % active
+        return jnp.where(records[:, -1] == 1, dev, spread)
 
     return jax.jit(_dest)
 
 
-def make_dest_fn(mesh: Mesh, klen: int, splitters: np.ndarray,
-                 ranges_per_dev: int, axis_name: str = "data"):
-    """Jitted SPMD map records→destination *device* (range // ranges_per_dev).
-    ``splitters`` is [r-1, klen] uint8 (may be empty for r == 1)."""
-    return _make_dest_fn(mesh, klen, splitters.astype(np.uint8).tobytes(),
-                         ranges_per_dev, axis_name)
-
-
 @functools.lru_cache(maxsize=32)
-def _make_sort_fn(mesh: Mesh, klen: int, axis_name: str):
+def make_sort_fn(mesh: Mesh, klen: int, axis_name: str = "data"):
+    """Jitted SPMD per-device sort of received rows by their leading
+    ``klen`` key bytes. Slots the exchange left unfilled and padding rows
+    (trailing validity byte 0) sort to the end of each device's shard;
+    the returned mask marks the live rows, a prefix of the shard."""
     @partial(jax.shard_map, mesh=mesh, in_specs=(P(axis_name), P(axis_name)),
              out_specs=(P(axis_name), P(axis_name)))
     def _sort(records, valid):
+        live = valid & (records[:, -1] == 1)
         cols = key_columns(records, klen)
         # lexsort: LAST key is primary → (least-significant col … col0,
-        # invalid-last) so each device's shard comes back valid-records-
-        # first in ascending key order
+        # dead-last) so each device's shard comes back live-rows-first
+        # in ascending key order
         keys = tuple(cols[:, c] for c in range(cols.shape[1] - 1, -1, -1))
-        order = jnp.lexsort(keys + (~valid,))
-        return jnp.take(records, order, axis=0), jnp.take(valid, order)
+        order = jnp.lexsort(keys + (~live,))
+        return jnp.take(records, order, axis=0), jnp.take(live, order)
 
     return jax.jit(_sort)
-
-
-def make_sort_fn(mesh: Mesh, klen: int, axis_name: str = "data"):
-    """Jitted SPMD per-device sort of received records by their leading
-    ``klen`` key bytes; invalid (padding) slots sort to the end of each
-    device's shard."""
-    return _make_sort_fn(mesh, klen, axis_name)
 
 
 @functools.lru_cache(maxsize=8)
@@ -136,27 +170,44 @@ def _argsort_keys(ncols: int):
     return _argsort
 
 
+def _ready(sp, out):
+    """``out``, waited for where a span is open to time it: an untraced
+    job dispatches the next program without waiting."""
+    return jax.block_until_ready(out) if sp is not None else out
+
+
 def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
                           splitters: np.ndarray, num_ranges: int,
                           capacity: int | None = None,
                           max_retries: int = 2,
-                          axis_name: str = "data"):
+                          axis_name: str = "data",
+                          stats: "dict | None" = None):
     """Full device path: records [N, w] uint8 (first ``klen`` bytes = the
-    sort key) → per-device key-sorted rows. ``records`` is padded internally
-    to a mesh-size multiple; a trailing validity byte distinguishes real
-    rows from padding after the exchange.
+    sort key) → per-device key-sorted rows. On a mesh ``records`` is dealt
+    evenly over the devices and padded to ``bucket_rows(N, n_dev)``; a
+    trailing validity byte distinguishes real rows from padding. The
+    exchange's per-(src, dst) ``capacity`` (twice the bucket's balanced
+    load unless given) and the sort's shape follow from the bucket, and
+    the splitters are a runtime argument, so a second input in the same
+    bucket compiles nothing. An overflow is retried with doubled capacity
+    (a second and third bucket of the exchange and the sort).
 
     Returns ``(shards, total_capacity_overflowed)`` where ``shards`` is a
     list of ``n_dev`` numpy arrays (device d's received rows, key-sorted,
     padding removed) or ``None`` when every retry overflowed (caller falls
     back to the host path — the reference's disk-spill role,
-    ReduceTask.java:1080 ShuffleRamManager budget semantics).
+    ReduceTask.java:1080 ShuffleRamManager budget semantics). The mesh
+    branch notes ``pad_rows`` (what the bucket added) and ``retries``
+    (overflow retries made) in ``stats``.
 
     Under a traced task both branches record the same three spans:
     ``dshuffle:pack`` (host: what goes to the device is laid out),
     ``dshuffle:device`` (from the first dispatch until the host holds the
     result: copy in, the programs, copy out) and ``dshuffle:gather``
-    (host: rows into their final order).
+    (host: rows into their final order). On a mesh ``dshuffle:device``
+    has a child per step, each closed when its result is ready:
+    ``dshuffle:put``, ``dshuffle:dest``, ``dshuffle:exchange`` (one per
+    attempt), ``dshuffle:sort``, ``dshuffle:get``.
     """
     from tpumr.parallel.mesh import shard_over
     from tpumr.parallel.shuffle import shuffle_dense
@@ -202,60 +253,79 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
                 order = order[order < n0]
             return [records[order]], 0
 
-    # trailing validity byte + pad rows (zeros → marked invalid) so the
-    # leading dim divides the mesh; pads route to device 0 and are masked
-    # out on the host after the sort
+    # each device's share of the rows, then padding up to the bucket
+    # (zeros with validity byte 0): every shape below follows from the
+    # bucket, so another input in it compiles nothing
     with tracing.span("dshuffle:pack") as sp:
-        n = -(-n0 // n_dev) * n_dev
-        ext = np.zeros((n, w + 1), dtype=np.uint8)
-        ext[:n0, :w] = records
-        ext[:n0, w] = 1
-        sharded = shard_over(mesh, ext, axis_name)
+        n = bucket_rows(n0, n_dev)
+        local = n // n_dev
+        ext = np.zeros((n_dev, local, w + 1), dtype=np.uint8)
+        for d, share in enumerate(np.array_split(records, n_dev)):
+            ext[d, :len(share), :w] = share
+            ext[d, :len(share), w] = 1
+        ext = ext.reshape(n, w + 1)
         if sp is not None:
             sp.set(n_pad=n, bytes_in=int(ext.nbytes))
+    if stats is not None:
+        stats.update(pad_rows=n - n0, retries=0)
 
     with tracing.span("dshuffle:device", devices=n_dev,
-                      bytes_in=int(ext.nbytes)) as sp:
-        dest = make_dest_fn(mesh, klen, splitters, ranges_per_dev,
-                            axis_name)(sharded)
+                      bytes_in=int(ext.nbytes)) as dev_sp:
+        with tracing.span("dshuffle:put", bytes=int(ext.nbytes)) as sp:
+            sharded = _ready(sp, shard_over(mesh, ext, axis_name))
+        # the receive side is only the ACTIVE destination devices (when
+        # num_ranges < mesh size, fewer devices share the whole load)
+        active = max(1, -(-num_ranges // ranges_per_dev))
+        with tracing.span("dshuffle:dest") as sp:
+            dest = _ready(sp, make_dest_fn(
+                mesh, klen, ranges_per_dev, active, axis_name)(
+                    sharded, splitter_columns(splitters, klen, num_ranges)))
 
         if capacity is None:
-            # balanced per-(src,dst) load with 2x headroom for sampling
-            # skew; the receive side is only the ACTIVE destination
-            # devices (when num_ranges < mesh size, fewer devices share
-            # the whole load — dividing by n_dev² would systematically
-            # overflow)
-            active = max(1, -(-num_ranges // ranges_per_dev))
-            capacity = max(16, int(2 * n / (n_dev * active)))
+            # balanced per-(src,dst) load of the BUCKET with 2x headroom
+            # for sampling skew (dividing by n_dev² where fewer devices
+            # receive would systematically overflow)
+            capacity = max(16, 2 * local // active)
         overflowed = 0
         for attempt in range(max_retries + 1):
-            res = shuffle_dense(mesh, sharded, dest, capacity=capacity,
-                                axis_name=axis_name)
-            if sp is not None:
-                sp.set(retries=attempt)
-            if int(res.overflow) == 0:
+            with tracing.span("dshuffle:exchange", capacity=capacity,
+                              attempt=attempt) as sp:
+                res = shuffle_dense(mesh, sharded, dest, capacity=capacity,
+                                    axis_name=axis_name)
+                lost = int(res.overflow)    # waits for the exchange
+                if sp is not None:
+                    sp.set(overflow=lost)
+            if dev_sp is not None:
+                dev_sp.set(retries=attempt)
+            if stats is not None:
+                stats["retries"] = attempt
+            if lost == 0:
                 break
-            overflowed = int(res.overflow)
+            overflowed = lost
             capacity *= 2
         else:
             return None, overflowed
 
-        sorted_recs, sorted_valid = make_sort_fn(mesh, klen, axis_name)(
-            res.values, res.valid)
-        host_recs = np.asarray(sorted_recs)
-        host_valid = np.asarray(sorted_valid)
-        if sp is not None:
-            sp.set(bytes_out=int(host_recs.nbytes + host_valid.nbytes))
+        with tracing.span("dshuffle:sort") as sp:
+            sorted_recs, live = _ready(sp, make_sort_fn(
+                mesh, klen, axis_name)(res.values, res.valid))
+        with tracing.span("dshuffle:get") as sp:
+            host_recs = np.asarray(sorted_recs)
+            host_live = np.asarray(live)
+            bytes_out = int(host_recs.nbytes + host_live.nbytes)
+            if sp is not None:
+                sp.set(bytes=bytes_out)
+        if dev_sp is not None:
+            dev_sp.set(bytes_out=bytes_out)
     with tracing.span("dshuffle:gather") as sp:
         per_dev = host_recs.shape[0] // n_dev
         shards = []
         for d in range(n_dev):
-            lo, hi = d * per_dev, (d + 1) * per_dev
-            rows = host_recs[lo:hi]
-            # mask-filter (order-preserving): drop unfilled slots AND
-            # padding
-            mask = host_valid[lo:hi] & (rows[:, w] == 1)
-            shards.append(rows[mask][:, :w])
+            lo = d * per_dev
+            # the sort put the live rows first: unfilled slots and
+            # padding are cut off, the validity byte dropped
+            cnt = int(np.count_nonzero(host_live[lo:lo + per_dev]))
+            shards.append(np.ascontiguousarray(host_recs[lo:lo + cnt, :w]))
         if sp is not None:
             sp.set(rows=sum(s.shape[0] for s in shards),
                    bytes=sum(int(s.nbytes) for s in shards))

@@ -14,6 +14,7 @@ disk-spill fallback role).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from functools import partial
 from typing import Any
@@ -69,9 +70,6 @@ def _bucket_local(values, dest, n_dev: int, capacity: int, keys=None):
             .set(skeys)[:, :capacity]
         out.append(kbuf)
     return out
-
-
-import functools
 
 
 @functools.lru_cache(maxsize=64)
