@@ -476,3 +476,254 @@ def test_device_partition_sort_single_device_mesh():
     assert keys == sorted(keys)
     # permutation fidelity: exact multiset of rows survives
     assert sorted(map(bytes, out)) == sorted(map(bytes, records))
+
+
+# ------------------------------------------------- the copy phase (PR 27)
+
+
+def _rows_of(records):
+    return np.frombuffer(b"".join(k + v for k, v in records),
+                         np.uint8).reshape(len(records), -1)
+
+
+def _numpy_sorted(rows: np.ndarray, klen: int = 10) -> np.ndarray:
+    """The reference: rows in ascending key order, by numpy alone."""
+    return rows[np.lexsort(tuple(rows[:, c]
+                                 for c in range(klen - 1, -1, -1)))]
+
+
+@pytest.mark.parametrize("trackers", [1, 2])
+def test_gang_reduce_reads_its_own_trackers_maps_and_calls_for_the_rest(
+        trackers, monkeypatch):
+    """A map that the gang reduce's tracker serves is read from its file;
+    a map of another tracker comes through that tracker's RPC. One slot a
+    tracker and eight maps, so with two trackers each runs some."""
+    from tpumr.examples.terasort import make_terasort_conf
+    rows, maps = 1600, 8
+    gen, out = f"mem:///dsl{trackers}/gen", f"mem:///dsl{trackers}/out"
+    fs = get_filesystem("mem:///")
+    _teragen(gen, rows, maps=maps)
+    served: list = []       # (serving tracker, map) of every dense RPC
+    reduced: list = []      # the tracker that ran the gang reduce
+    own: set = set()        # the maps that tracker held when asked for
+    with MiniMRCluster(num_trackers=trackers, cpu_slots=1,
+                       tpu_slots=0) as c:
+        for t in c.trackers:
+            def serve(job_id, m, t=t, real=t.get_map_output_dense):
+                served.append((t.name, m))
+                return real(job_id, m)
+
+            def factory(job_id, task, reporter, t=t,
+                        real=t._remote_dense_fetch_factory):
+                reduced.append(t)
+                fetch = real(job_id, task, reporter)
+
+                def watched(m):
+                    k, v = fetch(m)
+                    if t._map_output_entry(job_id, m) is not None:
+                        own.add(m)
+                    return k, v
+
+                return watched
+
+            monkeypatch.setattr(t, "get_map_output_dense", serve)
+            monkeypatch.setattr(t, "_remote_dense_fetch_factory", factory)
+        conf = make_terasort_conf(gen, out, 4, device_shuffle=True)
+        for k, v in c.create_job_conf():
+            conf.set_if_unset(k, v)
+        result = JobClient(conf).run_job(conf)
+        assert result.successful
+        reducer, = reduced
+    assert _rows_of(_read_parts(fs, out)[0]).tobytes() == _numpy_sorted(
+        _rows_of(_read_parts(fs, gen)[0])).tobytes()
+    counted = result.counters.value(BackendCounter.GROUP,
+                                    BackendCounter.TPU_SHUFFLE_LOCAL_MAPS)
+    assert counted == len(own) == maps - len(served)
+    # what was called for is what the reduce's tracker does not hold,
+    # once each and from the other tracker
+    assert sorted(m for _t, m in served) == sorted(set(range(maps)) - own)
+    assert all(name != reducer.name for name, _m in served)
+    if trackers == 1:
+        assert counted == maps and not served
+    else:
+        assert 0 < counted < maps
+    assert result.counters.value(BackendCounter.GROUP,
+                                 BackendCounter.TPU_SHUFFLE_RECORDS) == rows
+
+
+LANDINGS = {
+    "grows-as-maps-grow": [3, 0, 50, 7, 400, 1],
+    "equal-maps": [64, 64, 64, 64],
+    "empty-maps-first": [0, 0, 9, 30],
+    "one-map": [25],
+    "nothing": [0, 0],
+}
+
+
+@pytest.mark.parametrize("arrival", ["in-order", "reversed"])
+@pytest.mark.parametrize("case", LANDINGS)
+def test_rows_and_key_words_land_map_by_map_as_the_whole_would(case,
+                                                               arrival):
+    from tpumr.mapred.device_shuffle import RowLanding
+    from tpumr.parallel.device_sort import key_columns
+    klen, vlen = 10, 7
+    rng = np.random.default_rng(len(case))
+    sizes = LANDINGS[case][::-1 if arrival == "reversed" else 1]
+    parts = [(rng.integers(0, 256, (n, klen), dtype=np.uint8),
+              rng.integers(0, 256, (n, vlen), dtype=np.uint8))
+             for n in sizes]
+    landing = RowLanding(klen, vlen, len(parts))
+    grown = 0
+    for k, v in parts:
+        grown += landing.land_rows(k, v)
+        landing.land_key_words(k)
+    whole = np.concatenate([np.concatenate([k, v], axis=1)
+                            for k, v in parts])
+    assert landing.rows.dtype == np.uint8
+    assert np.array_equal(landing.rows, whole)
+    if sum(sizes) == 0:
+        assert landing.key_words is None and grown == 0
+        return
+    assert landing.key_words.dtype == np.uint32
+    assert np.array_equal(landing.key_words, key_columns(whole, klen))
+    # unknown in advance, so at least the first rows grow it; a run of
+    # equal maps fits what the first one predicted
+    assert grown >= 1
+    assert grown == 1 or case != "equal-maps"
+    assert grown >= 2 or case != "grows-as-maps-grow"
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("n", [16, 1000, 5000])
+def test_handed_over_key_words_change_nothing_in_the_shards(n, n_dev):
+    """One device: the sort sends the words it is handed and the shards
+    are those it makes from its own; a mesh has no use for them."""
+    from tpumr.parallel.device_sort import (device_partition_sort,
+                                            key_columns)
+    from tpumr.parallel.mesh import make_mesh
+    rng = np.random.default_rng(n)
+    klen = 10
+    records = rng.integers(0, 256, size=(n, klen + 6), dtype=np.uint8)
+    records[:, 2:klen] = 7      # two bytes decide: some keys come twice
+    splitters = np.zeros((3, klen), np.uint8)
+    splitters[:, 0] = [64, 128, 192]
+    mesh = make_mesh(n_dev)
+    plain, lost = device_partition_sort(mesh, records, klen, splitters, 4)
+    handed, lost_too = device_partition_sort(
+        mesh, records, klen, splitters, 4,
+        key_words=key_columns(records, klen))
+    assert lost == lost_too == 0 and len(plain) == len(handed) == n_dev
+    for a, b in zip(plain, handed):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert np.array_equal(np.concatenate(handed),
+                          _numpy_sorted(records, klen))
+
+
+def test_key_words_of_other_rows_are_refused_on_one_device():
+    from tpumr.parallel.device_sort import (device_partition_sort,
+                                            key_columns)
+    from tpumr.parallel.mesh import make_mesh
+    records = np.zeros((40, 12), np.uint8)
+    with pytest.raises(ValueError, match="key words of 39 rows"):
+        device_partition_sort(make_mesh(1), records, 10,
+                              np.zeros((0, 10), np.uint8), 1,
+                              key_words=key_columns(records[1:], 10))
+
+
+def test_a_one_device_reduce_hands_over_the_key_words_it_made_per_map(
+        monkeypatch):
+    """The one-chip host's path (one device stands in): a span of
+    assemble and of pack a map, and ``device_partition_sort`` is handed
+    the key words of exactly the rows it is handed."""
+    import jax
+
+    from tpumr.core import tracing
+    from tpumr.examples.terasort import make_terasort_conf
+    from tpumr.parallel import device_sort, jaxruntime
+    monkeypatch.setattr(jaxruntime, "accelerator_devices",
+                        lambda: jax.devices()[:1])
+    seen = {}
+    real = device_sort.device_partition_sort
+
+    def spy(mesh, records, klen, *args, key_words=None, **kwargs):
+        seen.update(n_dev=mesh.size, records=records, key_words=key_words)
+        return real(mesh, records, klen, *args, key_words=key_words,
+                    **kwargs)
+
+    monkeypatch.setattr(device_sort, "device_partition_sort", spy)
+    fs = get_filesystem("mem:///")
+    _teragen("mem:///ds1/gen", 700, maps=3)
+    conf = make_terasort_conf("mem:///ds1/gen", "mem:///ds1/out", 4,
+                              device_shuffle=True)
+    tracer = tracing.Tracer("task")
+    with tracing.activate(tracer, tracer.start_span("task:run", "job_ds1")):
+        result = run_job(conf)
+    assert result.successful
+    assert seen["n_dev"] == 1 and seen["records"].shape == (700, 100)
+    assert np.array_equal(seen["key_words"],
+                          device_sort.key_columns(seen["records"], 10))
+    assert result.counters.value(BackendCounter.GROUP,
+                                 BackendCounter.TPU_SHUFFLE_DEVICES) == 1
+    gen = _rows_of(_read_parts(fs, "/ds1/gen")[0])
+    assert _rows_of(_read_parts(fs, "/ds1/out")[0]).tobytes() \
+        == _numpy_sorted(gen).tobytes()
+    names = [s.name for s in tracer.pending()
+             if s.name.startswith("dshuffle:")]
+    assert names[:10] == ["dshuffle:fetch", "dshuffle:assemble",
+                          "dshuffle:pack"] * 3 + ["dshuffle:assemble"]
+    assert names[10:13] == ["dshuffle:pack", "dshuffle:device",
+                            "dshuffle:gather"]
+    packs = [s.attributes for s in tracer.pending()
+             if s.name == "dshuffle:pack"]
+    assert [p.get("map_index") for p in packs] == [0, 1, 2, None]
+    assert sum(p.get("rows", 0) for p in packs) == 700
+    assert packs[-1]["n_pad"] == 1024
+
+
+class _Here:
+    """A located map whose serving address is the given one."""
+
+    def __init__(self, addr):
+        self.addr = addr
+
+    def call(self, *a):
+        raise AssertionError("a map of this tracker went through RPC")
+
+
+@pytest.mark.parametrize("fault, error", [
+    ("no-entry", KeyError), ("not-dense", ValueError),
+    ("file-gone", FileNotFoundError)])
+def test_a_local_entry_withdrawn_before_the_read_fails_as_the_rpc_does(
+        fault, error, tmp_path, monkeypatch):
+    """The gang reduce's own read and the RPC handler raise the same for
+    the same fault, so the attempt fails and is retried as before."""
+    from tpumr.mapred.api import Reporter
+    from tpumr.mapred.device_shuffle import DenseMapOutputBuffer
+    conf = JobConf()
+    conf.set_device_shuffle(4, 2)
+    buf = DenseMapOutputBuffer(conf, str(tmp_path), Reporter())
+    buf.collect(b"abcd", b"xy")
+    path, index = buf.flush()
+    with MiniMRCluster(num_trackers=1, cpu_slots=1, tpu_slots=0) as c:
+        t, = c.trackers
+        monkeypatch.setattr(t, "_map_locator",
+                            lambda job_id: lambda m: _Here(t.shuffle_addr))
+        reporter = Reporter()
+        fetch = t._remote_dense_fetch_factory("job_x", None, reporter)
+        t.map_outputs[("job_x", 0)] = (path, index)
+        k, v = fetch(0)
+        assert k.tobytes() == b"abcd" and v.tobytes() == b"xy"
+        if fault == "no-entry":
+            del t.map_outputs[("job_x", 0)]
+        elif fault == "not-dense":
+            t.map_outputs[("job_x", 0)] = (path, {"partitions": []})
+        else:
+            import os
+            os.unlink(path)
+        with pytest.raises(error) as local:
+            fetch(0)
+        with pytest.raises(error) as remote:
+            t.get_map_output_dense("job_x", 0)
+        assert str(local.value) == str(remote.value)
+        assert reporter.counters.value(
+            BackendCounter.GROUP, BackendCounter.TPU_SHUFFLE_LOCAL_MAPS) == 1

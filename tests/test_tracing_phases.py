@@ -82,7 +82,10 @@ def test_the_phases_are_the_children_in_order_and_do_not_overlap(sorted_job):
     assert sorted({k["name"] for k in kids}) == sorted(PHASES)
     # each phase where the work is done, in the order the work is done
     order = [k["name"] for k in kids]
-    assert order == ["dshuffle:locate", "dshuffle:fetch"] * MAPS + [
+    # a map's rows land when it arrives (on a mesh its key words are the
+    # devices' to make); one more assemble closes the copy phase
+    assert order == ["dshuffle:locate", "dshuffle:fetch",
+                     "dshuffle:assemble"] * MAPS + [
         "dshuffle:assemble", "dshuffle:pack", "dshuffle:device",
         "dshuffle:gather"] + ["dshuffle:write"] * RANGES
     for a, b in zip(kids, kids[1:]):
@@ -107,12 +110,19 @@ def test_phase_rows_and_bytes_match_the_jobs_counters(sorted_job):
     def attrs(name):
         return [s["attributes"] for s in spans if s["name"] == name]
 
-    assert attrs("dshuffle:assemble") == [
-        dict(attrs("dshuffle:assemble")[0], rows=rows_in, bytes=moved)]
+    *landed, whole = attrs("dshuffle:assemble")
+    assert [a["map_index"] for a in landed] == list(range(MAPS))
+    assert sum(a["rows"] for a in landed) == whole["rows"] == rows_in
+    assert sum(a["bytes"] for a in landed) == whole["bytes"] == moved
+    assert "map_index" not in whole
     fetches = attrs("dshuffle:fetch")
     assert sorted(f["map_index"] for f in fetches) == list(range(MAPS))
-    # what came over the wire: the rows and one 12-byte header a map
+    # what was read: the rows and one 12-byte header a map; one tracker,
+    # so every map's file is its own and none came over the wire
     assert sum(f["bytes"] for f in fetches) == moved + 12 * MAPS
+    assert all(f["local"] is True for f in fetches)
+    assert counters.value(BackendCounter.GROUP,
+                          BackendCounter.TPU_SHUFFLE_LOCAL_MAPS) == MAPS
     assert sorted(a["map_index"] for a in attrs("dshuffle:locate")) \
         == list(range(MAPS))
     pack, = attrs("dshuffle:pack")

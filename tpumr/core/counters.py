@@ -77,6 +77,9 @@ class BackendCounter:
     TPU_SHUFFLE_DEVICES = "TPU_SHUFFLE_DEVICES"
     TPU_SHUFFLE_RETRIES = "TPU_SHUFFLE_RETRIES"
     TPU_SHUFFLE_PAD_ROWS = "TPU_SHUFFLE_PAD_ROWS"
+    #: maps whose dense output the gang reduce read from the disk of its
+    #: own tracker, not through the RPC of the tracker that serves it
+    TPU_SHUFFLE_LOCAL_MAPS = "TPU_SHUFFLE_LOCAL_MAPS"
     GROUP = "tpumr.BackendCounter"
 
 

@@ -189,6 +189,80 @@ def read_dense_output(path: str) -> tuple[np.ndarray, np.ndarray]:
 DenseFetchFn = Callable[[int], tuple[np.ndarray, np.ndarray]]
 
 
+class RowLanding:
+    """Where the copy phase puts what it fetched: the job's rows
+    ``[n, klen + vlen]`` (key first) and, where asked for, their key
+    words ``[n, cols]`` (``key_columns``), each map's share written once
+    when that map arrives, in the order of arrival. How many rows the job
+    has is known only after the last map, so the buffers are sized from
+    the maps seen so far (their mean for every map to come, and an eighth
+    more) and grown, by a copy of what has landed, when a map does not
+    fit."""
+
+    def __init__(self, klen: int, vlen: int, num_maps: int) -> None:
+        from tpumr.parallel.device_sort import num_key_columns
+        self.klen = klen
+        self._num_maps = num_maps
+        self._seen = 0
+        self._rows = np.empty((0, klen + vlen), np.uint8)
+        self._n = 0
+        self._words = np.empty((0, num_key_columns(klen)), np.uint32)
+        self._n_words = 0
+
+    def land_rows(self, keys: np.ndarray, values: np.ndarray) -> bool:
+        """Append one map's rows; True where the buffer had to grow."""
+        self._seen += 1
+        end = self._n + keys.shape[0]
+        grown = end > self._rows.shape[0]
+        if grown:
+            mean = -(-end // self._seen)
+            to_come = max(0, self._num_maps - self._seen)
+            self._rows = _regrown(self._rows, self._n,
+                                  end + to_come * (mean + mean // 8))
+        self._rows[self._n:end, :self.klen] = keys
+        self._rows[self._n:end, self.klen:] = values
+        self._n = end
+        return grown
+
+    def land_key_words(self, keys: np.ndarray) -> None:
+        """The key words of one map's keys, after its rows have landed."""
+        from tpumr.parallel.device_sort import key_columns
+        end = self._n_words + keys.shape[0]
+        if end > self._words.shape[0]:
+            self._words = _regrown(self._words, self._n_words,
+                                   max(end, self._rows.shape[0]))
+        self._words[self._n_words:end] = key_columns(keys, self.klen)
+        self._n_words = end
+
+    @property
+    def rows(self) -> np.ndarray:
+        """What has landed, a view of the buffer."""
+        return self._rows[:self._n]
+
+    @property
+    def key_words(self) -> "np.ndarray | None":
+        """The key words that have landed; None where none were asked
+        for."""
+        return self._words[:self._n_words] if self._n_words else None
+
+
+def _regrown(buf: np.ndarray, used: int, capacity: int) -> np.ndarray:
+    """A buffer of ``capacity`` rows that starts with ``buf[:used]``."""
+    out = np.empty((capacity,) + buf.shape[1:], buf.dtype)
+    out[:used] = buf[:used]
+    return out
+
+
+def _shuffle_mesh(conf: Any) -> Any:
+    """The mesh over this process's slot devices that the exchange and
+    sort run on."""
+    from tpumr.parallel.jaxruntime import (accelerator_devices,
+                                           configure_persistent_cache)
+    from tpumr.parallel.mesh import make_mesh
+    configure_persistent_cache(conf)
+    return make_mesh(devices=accelerator_devices())
+
+
 def _load_splitters(conf: Any, keys: np.ndarray, num_ranges: int,
                     klen: int) -> np.ndarray:
     """Range cut points [r-1, klen] u8: the job's TotalOrderPartitioner
@@ -248,10 +322,12 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
 
     Traced jobs get one ``dshuffle`` span around all of it, and under it
     a span per phase, each opened where the work is done:
-    ``dshuffle:locate`` / ``dshuffle:fetch`` (the fetch function),
-    ``dshuffle:assemble``, ``dshuffle:pack`` / ``dshuffle:device`` (on a
-    mesh with a child per step: ``:put``, ``:dest``, ``:exchange``,
-    ``:sort``, ``:get``) / ``dshuffle:gather``
+    ``dshuffle:locate`` / ``dshuffle:fetch`` (the fetch function), then
+    for that map ``dshuffle:assemble`` (its rows landed) and, where one
+    device sorts, ``dshuffle:pack`` (its key words); after the last map
+    one more ``dshuffle:assemble`` (the splitters), ``dshuffle:pack`` /
+    ``dshuffle:device`` (on a mesh with a child per step: ``:put``,
+    ``:dest``, ``:exchange``, ``:sort``, ``:get``) / ``dshuffle:gather``
     (``device_partition_sort``) or
     ``dshuffle:host_sort`` (the fallback), ``dshuffle:write``. What the
     parent does not spend in a child is its self time."""
@@ -270,27 +346,37 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     vlen = conf.get_int(VALUE_BYTES_KEY, 0)
     num_ranges = conf.get_int(RANGES_KEY, 1)
 
-    # ---- copy phase (host, ≈ ReduceCopier.fetchOutputs)
+    # ---- copy phase (host, ≈ ReduceCopier.fetchOutputs): a map's rows
+    # are landed when that map arrives, so that what follows the last
+    # map is one map's landing and not the whole job's
     t0 = time.monotonic()
-    key_parts, val_parts = [], []
+    landing = RowLanding(klen, vlen, task.num_maps)
+    mesh = None
     for m in range(task.num_maps):
         k, v = dense_fetch(m)
         if k.shape[1] != klen or v.shape[1] != vlen:
             raise ValueError(f"map {m} dense output widths "
                              f"({k.shape[1]},{v.shape[1]}) != conf "
                              f"({klen},{vlen})")
-        key_parts.append(k)
-        val_parts.append(v)
-    with tracing.span("dshuffle:assemble") as sp:
-        keys = np.concatenate(key_parts) if key_parts else \
-            np.zeros((0, klen), np.uint8)
-        values = np.concatenate(val_parts) if val_parts else \
-            np.zeros((0, vlen), np.uint8)
-        n = keys.shape[0]
-        records = np.concatenate([keys, values], axis=1)
-        splitters = _load_splitters(conf, keys, num_ranges, klen)
-        if sp is not None:
-            sp.set(rows=n, bytes=int(records.nbytes))
+        if mesh is None and k.shape[0]:
+            mesh = _shuffle_mesh(conf)
+        with tracing.span("dshuffle:assemble", map_index=m,
+                          rows=int(k.shape[0]),
+                          bytes=int(k.nbytes + v.nbytes)) as sp:
+            grown = landing.land_rows(k, v)
+            if sp is not None:
+                sp.set(grown=grown)
+        if mesh is not None and mesh.size == 1:
+            # the one-device sort sends the key words, not the rows
+            with tracing.span("dshuffle:pack", map_index=m,
+                              rows=int(k.shape[0])):
+                landing.land_key_words(k)
+    records = landing.rows
+    n = records.shape[0]
+    with tracing.span("dshuffle:assemble", rows=n,
+                      bytes=int(records.nbytes)):
+        splitters = _load_splitters(conf, records[:, :klen], num_ranges,
+                                    klen)
     reporter.incr_counter(TaskCounter.FRAMEWORK_GROUP,
                           TaskCounter.REDUCE_INPUT_RECORDS, n)
 
@@ -298,18 +384,12 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     shards = None
     overflow = 0
     if n > 0:
-        from tpumr.parallel.jaxruntime import (accelerator_devices,
-                                               configure_persistent_cache)
-        from tpumr.parallel.mesh import make_mesh
         from tpumr.parallel.device_sort import device_partition_sort
-        configure_persistent_cache(conf)
-        devices = accelerator_devices()
-        mesh = make_mesh(devices=devices)
         capacity = conf.get_int(CAPACITY_KEY, 0) or None
         stats: dict = {}
         shards, overflow = device_partition_sort(
             mesh, records, klen, splitters, num_ranges, capacity=capacity,
-            stats=stats)
+            stats=stats, key_words=landing.key_words)
         reporter.incr_counter(BackendCounter.GROUP,
                               BackendCounter.TPU_SHUFFLE_RETRIES,
                               stats.get("retries", 0))
@@ -325,7 +405,7 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
             reporter.incr_counter(BackendCounter.GROUP,
                                   BackendCounter.TPU_SHUFFLE_BYTES,
                                   int(records.nbytes))
-            if devices[0].platform != "cpu":
+            if mesh.devices.flat[0].platform != "cpu":
                 reporter.incr_counter(BackendCounter.GROUP,
                                       BackendCounter.DEVICE_SORT_ON_ACCEL)
     host_fallback = shards is None
@@ -338,7 +418,7 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
                                   BackendCounter.SHUFFLE_HOST_FALLBACKS)
         from tpumr.parallel.device_sort import key_columns
         with tracing.span("dshuffle:host_sort", rows=n):
-            kcols = key_columns(keys, klen) if n else None
+            kcols = key_columns(records, klen) if n else None
             order = np.lexsort(tuple(
                 kcols[:, c] for c in range(kcols.shape[1] - 1, -1, -1))) \
                 if n else np.zeros(0, int)

@@ -868,6 +868,12 @@ class NodeRunner:
     def shuffle_port(self) -> int:
         return self._server.port
 
+    @property
+    def shuffle_addr(self) -> str:
+        """Where this tracker serves map outputs, as its status and the
+        completion events of its maps give it."""
+        return f"{self.bind_host}:{self.shuffle_port}"
+
     # ------------------------------------------------------------ status
 
     def _slot_utilization(self) -> dict:
@@ -962,7 +968,7 @@ class NodeRunner:
                 "fetch_failures": list(self._fetch_failures),
                 "tracker_name": self.name,
                 "host": self.host,
-                "shuffle_addr": f"{self.bind_host}:{self.shuffle_port}",
+                "shuffle_addr": self.shuffle_addr,
                 "shuffle_port": self.shuffle_port,
                 "max_cpu_map_slots": self.max_cpu_map_slots,
                 "max_tpu_map_slots": tpu_slots,
@@ -1722,7 +1728,8 @@ class NodeRunner:
                     from tpumr.mapred.device_shuffle import run_device_reduce
                     run_device_reduce(
                         conf, task,
-                        self._remote_dense_fetch_factory(job_id, task),
+                        self._remote_dense_fetch_factory(job_id, task,
+                                                         reporter),
                         reporter)
                 else:
                     fetch = self._remote_fetch_factory(job_id, task)
@@ -2329,6 +2336,12 @@ class NodeRunner:
         MapOutputServlet role; the exchange itself happens on the mesh).
         Ships the self-describing file verbatim — no parse/reserialize."""
         self._check_scope(job_id)
+        return {"data": self._dense_output_bytes(job_id, map_index)}
+
+    def _dense_output_bytes(self, job_id: str, map_index: int) -> bytes:
+        """The dense file this tracker serves for one map, whole; what a
+        lookup or a read that fails raises is the same for the RPC and
+        for the gang reduce's own read."""
         ent = self._map_output_entry(job_id, map_index)
         if ent is None:
             raise KeyError(f"no map output for {job_id} map {map_index}")
@@ -2337,7 +2350,7 @@ class NodeRunner:
             raise ValueError(f"map output for {job_id} map {map_index} is "
                              "not dense — fetch with get_map_output")
         with open(path, "rb") as f:
-            return {"data": f.read()}
+            return f.read()
 
     def _map_locator(self, job_id: str):
         """Resolve a map's serving tracker from the master's completion
@@ -2388,10 +2401,15 @@ class NodeRunner:
             self.report_fetch_failure(reduce_attempt, map_attempt))
         return src
 
-    def _remote_dense_fetch_factory(self, job_id: str, task: Task):
-        """Dense fetch for device-shuffled jobs: pulls each map's whole
-        fixed-width output (same serving seam, array payload)."""
+    def _remote_dense_fetch_factory(self, job_id: str, task: Task,
+                                    reporter: Any):
+        """Dense fetch for device-shuffled jobs: each map's whole
+        fixed-width output. A map that THIS tracker serves (the address
+        in its completion event is this tracker's own) is read from its
+        file; any other is pulled from the tracker that serves it (same
+        serving seam, array payload)."""
         from tpumr.core import tracing
+        from tpumr.core.counters import BackendCounter
         from tpumr.mapred.device_shuffle import parse_dense_bytes
 
         locate = self._map_locator(job_id)
@@ -2401,11 +2419,22 @@ class NodeRunner:
             # of the master's completion events) is not read as copying
             with tracing.span("dshuffle:locate", map_index=map_index):
                 source = locate(map_index)
-            with tracing.span("dshuffle:fetch", map_index=map_index) as sp:
-                out = source.call("get_map_output_dense", job_id,
-                                  map_index)
+            # by address, not by process: a mini cluster runs several
+            # trackers in one
+            local = source.addr == self.shuffle_addr
+            with tracing.span("dshuffle:fetch", map_index=map_index,
+                              local=local) as sp:
+                if local:
+                    data = self._dense_output_bytes(job_id, map_index)
+                else:
+                    data = source.call("get_map_output_dense", job_id,
+                                       map_index)["data"]
+                # by 0 too: the counter is in every gang reduce's rollup
+                reporter.incr_counter(BackendCounter.GROUP,
+                                      BackendCounter.TPU_SHUFFLE_LOCAL_MAPS,
+                                      int(local))
                 if sp is not None:
-                    sp.set(bytes=len(out["data"]))
-                return parse_dense_bytes(out["data"])
+                    sp.set(bytes=len(data))
+                return parse_dense_bytes(data)
 
         return fetch

@@ -181,7 +181,8 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
                           capacity: int | None = None,
                           max_retries: int = 2,
                           axis_name: str = "data",
-                          stats: "dict | None" = None):
+                          stats: "dict | None" = None,
+                          key_words: "np.ndarray | None" = None):
     """Full device path: records [N, w] uint8 (first ``klen`` bytes = the
     sort key) → per-device key-sorted rows. On a mesh ``records`` is dealt
     evenly over the devices and padded to ``bucket_rows(N, n_dev)``; a
@@ -198,7 +199,11 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
     back to the host path — the reference's disk-spill role,
     ReduceTask.java:1080 ShuffleRamManager budget semantics). The mesh
     branch notes ``pad_rows`` (what the bucket added) and ``retries``
-    (overflow retries made) in ``stats``.
+    (overflow retries made) in ``stats``. ``key_words`` hands over
+    ``key_columns(records, klen)`` where the caller has made it already:
+    the one-device branch then sends those and computes none; the mesh
+    branch, whose devices make their own from the rows, has no use for
+    them.
 
     Under a traced task both branches record the same three spans:
     ``dshuffle:pack`` (host: what goes to the device is laid out),
@@ -225,8 +230,12 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
         # sorted rows down); the value payload never leaves the host.
         if n0 == 0:
             return [records.copy()], 0
+        if key_words is not None and key_words.shape[0] != n0:
+            raise ValueError(f"key words of {key_words.shape[0]} rows "
+                             f"handed over with {n0} rows")
         with tracing.span("dshuffle:pack") as sp:
-            kcols = key_columns(records, klen)
+            kcols = key_columns(records, klen) if key_words is None \
+                else key_words
             # pad to the next power of two with all-FF sentinel keys so
             # the jitted argsort compiles once per size BUCKET, not per
             # exact n (XLA recompiles per shape, and a variadic sort is
@@ -236,9 +245,9 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
             # all-FF keys.
             n_pad = 1 << max(4, (n0 - 1).bit_length())
             if n_pad != n0:
-                padded = np.full((n_pad, kcols.shape[1]), 0xFFFFFFFF,
-                                 np.uint32)
+                padded = np.empty((n_pad, kcols.shape[1]), np.uint32)
                 padded[:n0] = kcols
+                padded[n0:] = 0xFFFFFFFF
                 kcols = padded
             if sp is not None:
                 sp.set(n_pad=n_pad, bytes_in=int(kcols.nbytes))
