@@ -1,8 +1,8 @@
 """DFS observability: namenode op/lock attribution, audit log, the
 SpaceSaving hot-block pipeline (DN sketch → heartbeat → NN fold →
 /hotblocks), datanode read-path metrics, the uniform prom surfaces on
-NN + DN, the NN flight-recorder incident e2e, and the bench_dfs row
-contract."""
+NN + DN, the NN flight-recorder incident e2e, and the ``simulate -dfs``
+row contract."""
 
 import json
 import logging
@@ -391,7 +391,7 @@ class TestBenchRowContract:
         validate_exposition(open(prom).read())
 
     def test_merged_op_hist_matches_families(self, tmp_path):
-        # the merge bench_dfs relies on: merging typed per-op hists
+        # the merge a DFS rung's row relies on: merging typed per-op hists
         # reproduces the union's count
         a = Histogram("nn_op_seconds")
         b = Histogram("nn_op_seconds")

@@ -293,6 +293,13 @@ def _master(extra=None):
     return JobMaster(conf).start()
 
 
+def _poll(cond, timeout_s=10.0):
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.02)
+
+
 class TestSimFleetEndToEnd:
     def test_fleet_drives_real_wire_heartbeats_and_jobs_complete(self):
         master = _master()
@@ -413,6 +420,40 @@ class TestSimFleetEndToEnd:
                 "trackers_adopted"] == 1
         finally:
             t.close()
+            master.stop()
+
+    def test_launch_a_dead_tracker_never_read_is_handed_out_again(self):
+        """A tracker killed between send and receive: the master folded
+        its beat and launched a map in a response nobody read, so no
+        status of that attempt ever arrives. Losing the tracker must
+        re-queue the map all the same, or the job never ends (what the
+        churn_storm mix hit under load: PR 28)."""
+        master = _master()
+        host, port = master.address
+        dead = SimTracker("dead", host, port, cpu_slots=1, reduce_slots=0)
+        live = SimTracker("live", host, port, cpu_slots=1, reduce_slots=0,
+                          task_time_mean_s=0.01)
+        driver = ScaleDriver(host, port)
+        try:
+            dead.heartbeat_once()            # registers; nothing to run
+            (job_id,) = driver.submit(1, 1, 0)
+            assert dead.heartbeat_begin()    # on the wire ...
+            jip = master.jobs[job_id]
+            _poll(lambda: jip.maps[0].state == "running")
+            dead.crash()                     # ... and never read
+            assert not jip.maps[0].attempts  # launched, never reported
+            master._evict_tracker("dead")
+            assert jip.maps[0].state == "pending"
+
+            def done():
+                live.heartbeat_once()
+                return jip.state == "SUCCEEDED"
+            _poll(done)
+            assert jip.maps[0].successful_attempt.endswith("_1")
+        finally:
+            dead.close()
+            live.close()
+            driver.close()
             master.stop()
 
 
@@ -944,27 +985,228 @@ class TestLabeledFamilies:
         assert 'tpumr_beats{source="jt",kind="sim"} 3' in text
 
 
-# ------------------------------------------------------------ bench
+# ------------------------------------------------------------ batching
 
 
-class TestBenchScale:
-    def test_run_bench_rows_carry_required_series(self):
-        import bench_scale
-        # generous SLO: this test gates the ROW CONTRACT, not latency —
-        # a loaded CI runner must not flake it on a wall-clock p99
-        report = bench_scale.run_bench(fleets=[2, 3], interval_s=0.05,
-                                       slo_s=30.0, wait_timeout_s=60)
-        assert len(report["rows"]) == 2
-        for row in report["rows"]:
-            for key in ("heartbeat_p50_s", "heartbeat_p99_s",
-                        "heartbeat_lag_p99_s", "lock_wait_p99_s",
-                        "lock_wait_share", "lock_wait_trackers_p99_s",
-                        "lock_wait_scheduler_p99_s",
-                        "assign_p99_s", "rpc_inflight_peak",
-                        "interval_instructed_ms",
-                        "completed", "trackers"):
-                assert key in row, key
-            assert row["completed"], row
-        assert report["max_sustainable_trackers"] == 3
-        assert report["slo_series"] == ["heartbeat_p99_s",
-                                        "heartbeat_lag_p99_s"]
+def _history_master(tmp_path):
+    return _master({"tpumr.history.dir": str(tmp_path / "history")})
+
+
+class TestHeartbeatBatch:
+    def test_resent_batch_replays_not_refolds(self, tmp_path):
+        """A resent batch must not double-fold any member — each
+        member rides the per-tracker replay cache exactly like a lone
+        resent heartbeat."""
+        master = _history_master(tmp_path)
+        try:
+            host, port = master.address
+            tr = SimTracker("batcher_00", host, port)
+            args = tr.heartbeat_build()
+            assert args is not None
+            tr.heartbeat_apply(master.heartbeat_batch([list(args)])[0])
+            # second beat (initial contact is over — the replay cache
+            # is armed now), delivered twice with the same response_id
+            args = tr.heartbeat_build()
+            first = master.heartbeat_batch([list(args)])
+            again = master.heartbeat_batch([list(args)])
+            assert first[0]["response_id"] == again[0]["response_id"]
+            assert first[0]["actions"] == again[0]["actions"]
+            snap = master.metrics.snapshot()["jobtracker"]
+            assert snap["heartbeat_batches"] == 3
+            replay = snap.get(
+                "heartbeat_phase_seconds|phase=replay", {})
+            assert replay.get("count") == 1, \
+                "second delivery must take the replay path"
+            tr.heartbeat_abort()
+            tr.close()
+        finally:
+            master.stop()
+
+    def test_member_failures_are_isolated(self, tmp_path):
+        master = _history_master(tmp_path)
+        try:
+            host, port = master.address
+            tr = SimTracker("batcher_01", host, port)
+            args = tr.heartbeat_build()
+            out = master.heartbeat_batch(
+                [["not-a-status", True, False, 0], list(args)])
+            assert "error" in out[0]
+            assert "response_id" in out[1], \
+                "a bad member must not poison the rest of the batch"
+            tr.heartbeat_abort()
+            tr.close()
+        finally:
+            master.stop()
+
+    def test_batched_fleet_drives_a_workload(self, tmp_path):
+        master = _history_master(tmp_path)
+        fleet = None
+        driver = None
+        try:
+            host, port = master.address
+            fleet = SimFleet(host, port, 6, interval_s=0.05,
+                             batch=4).start()
+            driver = ScaleDriver(host, port)
+            res = driver.run_workload(n_jobs=2, maps_per_job=4,
+                                      reduces_per_job=1, timeout_s=30)
+            assert len(res["succeeded"]) == 2, res
+            snap = master.metrics.snapshot()["jobtracker"]
+            assert snap.get("heartbeat_batches", 0) > 0
+            assert fleet.registry.snapshot().get("hb_errors", 0) == 0
+        finally:
+            if fleet is not None:
+                fleet.stop()
+            if driver is not None:
+                driver.close()
+            master.stop()
+
+
+# ------------------------------------------------------------ simulate
+
+
+def _tpumr(capsys, *argv):
+    """``tpumr ARGV`` in-process: (rc, stdout, stderr)."""
+    from tpumr.cli import main
+    capsys.readouterr()
+    rc = main(list(argv))
+    out = capsys.readouterr()
+    return rc, out.out, out.err
+
+
+def _simulate(capsys, *argv):
+    return _tpumr(capsys, "simulate", *argv)
+
+
+class TestSimulateRow:
+    def test_ramp_row_carries_required_series(self, capsys, monkeypatch):
+        """The ROW CONTRACT of the tracker ramp an operator runs by
+        hand — never a latency: a loaded CI runner must not flake it on
+        a wall-clock p99."""
+        from tpumr.metrics.core import MetricsSystem
+        snaps = []
+        real_snapshot = MetricsSystem.snapshot
+
+        def spy(self):
+            snaps.append(real_snapshot(self))
+            return snaps[-1]
+
+        monkeypatch.setattr(MetricsSystem, "snapshot", spy)
+        rc, out, _ = _simulate(
+            capsys, "-trackers", "3", "-jobs", "1", "-maps", "6",
+            "-reduces", "1", "-interval", "50", "-task-ms", "50",
+            "-timeout", "60")
+        row = json.loads(out)
+        for key in ("heartbeat_p50_s", "heartbeat_p99_s",
+                    "heartbeat_lag_p99_s", "lock_wait_p99_s",
+                    "lock_wait_trackers_p99_s",
+                    "lock_wait_scheduler_p99_s", "assign_p99_s",
+                    "completion_event_lag_p99", "rpc_inflight_peak",
+                    "interval_instructed_ms", "client_rtt_p99_s",
+                    "client_lag_p99_s", "trackers"):
+            assert key in row, key
+        assert rc == 0 and row["trackers"] == 3
+        assert row["jobs_succeeded"] == 1
+        assert not row["jobs_failed"] and not row["jobs_unfinished"]
+        assert row["tasks_completed"] >= 7 and row["hb_errors"] == 0
+        assert row["interval_instructed_ms"] == 50
+        # every lock class's wait row reads a series that is live in
+        # the snapshot the row was cut from (its count, not its p99:
+        # how a histogram interpolates is not this contract)
+        jts = [s["jobtracker"] for s in snaps if "jobtracker" in s]
+        for key, lock in (("lock_wait_p99_s", "global"),
+                          ("lock_wait_trackers_p99_s", "trackers"),
+                          ("lock_wait_scheduler_p99_s", "scheduler")):
+            name = f"jt_lock_wait_seconds|lock={lock}"
+            assert any(jt[name]["count"] > 0
+                       and jt[name]["p99"] == row[key]
+                       for jt in jts if name in jt), lock
+
+
+class TestSimulateCli:
+    def test_dfs_rung_prints_its_verdict(self, capsys):
+        # generous SLO: the verdict's SHAPE is under test, not the box
+        rc, out, _ = _tpumr(
+            capsys, "-D", "tpumr.dfs.bench.op.slo.ms=60000",
+            "-D", "tpumr.dfs.bench.read.slo.ms=60000",
+            "simulate", "-dfs", "2", "-seconds", "1.5", "-files", "3")
+        row = json.loads(out)
+        assert row["clients"] == 2 and row["completed"]
+        assert row["slo"] == {"op_slo_s": 60.0, "read_slo_s": 60.0,
+                              "pass": True}
+        assert rc == 0
+        for key in ("nn_op_p99_s", "read_rtt_p99_s", "lag_p99_s",
+                    "lock_wait_p99_by_lock", "editlog_sync_p99_s",
+                    "read_mb_s", "hot_top1_share"):
+            assert key in row, key
+
+    def test_live_master_row_has_the_fleet_side_only(self, capsys):
+        """``-jt HOST:PORT``: the fleet joins a master it did not
+        start, whose own series are read off its /metrics instead."""
+        master = _master()
+        try:
+            host, port = master.address
+            rc, out, _ = _tpumr(
+                capsys, "-jt", f"{host}:{port}", "simulate",
+                "-trackers", "2", "-jobs", "1", "-maps", "4",
+                "-reduces", "0", "-interval", "50", "-task-ms", "50",
+                "-timeout", "60")
+            row = json.loads(out)
+            assert rc == 0 and row["jobs_succeeded"] == 1
+            assert row["heartbeats"] > 0 and row["hb_errors"] == 0
+            assert "client_rtt_p99_s" in row
+            assert "heartbeat_p99_s" not in row
+            # the beats really went to THAT master
+            jt = master.metrics.snapshot()["jobtracker"]
+            assert jt["heartbeat_seconds"]["count"] >= row["heartbeats"]
+        finally:
+            master.stop()
+
+    def test_scenario_list_names_every_builtin(self, capsys):
+        from tpumr.scale import BUILTIN_SCENARIOS
+        rc, out, _ = _tpumr(capsys, "scenario", "-list")
+        rows = out.splitlines()
+        assert rc == 0
+        assert [r.split()[0] for r in rows] == sorted(BUILTIN_SCENARIOS)
+        assert all("[builtin]" in r and "jobs=" in r and "chaos=" in r
+                   for r in rows)
+
+    @pytest.mark.parametrize("name", ["no_such_mix", "shard_kill"])
+    def test_unknown_scenario_is_refused_with_the_builtins(
+            self, capsys, name):
+        from tpumr.scale import BUILTIN_SCENARIOS
+        rc, out, err = _simulate(capsys, "-scenario", name)
+        assert rc == 2 and not out
+        assert f"unknown scenario {name!r}" in err
+        for builtin in BUILTIN_SCENARIOS:
+            assert builtin in err, builtin
+
+    def test_scenario_report_holds_the_replay_plan(self, capsys,
+                                                   tmp_path):
+        """The report, not the exit code: the code follows the mix's
+        own per-class SLO verdicts, which a loaded box may miss."""
+        from tpumr.scale import BUILTIN_SCENARIOS, plan
+        report = tmp_path / "report.json"
+        rc, out, _ = _simulate(
+            capsys, "-scenario", "steady_mix", "-seed", "4242",
+            "-report", str(report), "-incidents", str(tmp_path / "a"))
+        rep = json.loads(report.read_text())
+        assert rep["scenario"] == "steady_mix" and rep["seed"] == 4242
+        assert rep["plan"] == plan(
+            dict(BUILTIN_SCENARIOS["steady_mix"], seed=4242))
+        jobs = rep["jobs"]
+        n = jobs["submitted"]
+        assert jobs["succeeded"] == n > 0
+        assert rc == (0 if rep["pass"] else 1)
+        # with -report, stdout is the short verdict summary
+        assert f"{n}/{n} jobs" in out and str(report) in out
+        for cls_name in ("interactive", "batch", "pipeline"):
+            assert f"class {cls_name}: " in out
+
+    @pytest.mark.parametrize("argv", [
+        ["stray"],                      # positional, not -name value
+        ["-trackers"],                  # a flag without its value
+        ["-trackers", "3", "-jobs"],
+    ])
+    def test_bad_arguments_are_refused(self, capsys, argv):
+        with pytest.raises(SystemExit, match="unexpected argument"):
+            _simulate(capsys, *argv)

@@ -5,6 +5,8 @@ clocks — runtimes injected via TaskStatus timestamps."""
 
 import time
 
+import pytest
+
 from tpumr.mapred.ids import JobID
 from tpumr.mapred.job_in_progress import JobInProgress
 from tpumr.mapred.jobconf import JobConf
@@ -224,6 +226,43 @@ def test_lost_tracker_requeues_completed_maps():
                 if e.get("status") != "OBSOLETE"]
     assert any(e["attempt_id"] == aid and e.get("status") == "OBSOLETE"
                for e in job.completion_events)
+
+
+@pytest.mark.parametrize("is_map", [True, False], ids=["map", "reduce"])
+def test_lost_tracker_requeues_attempt_it_never_reported(is_map):
+    """An attempt launched in a response its tracker did not live to
+    read has no status on the master yet. The tracker's loss must hand
+    the task out again all the same (found by the churn_storm mix: a
+    tracker killed between send and receive left a map `running` for
+    good, and the job never finished)."""
+    job = make_job(n_maps=1, n_reduces=1, kernel=False)
+    task = job.obtain_new_map_task("h", run_on_tpu=False) if is_map \
+        else job.obtain_new_reduce_task("h")
+    pending = job.pending_map_count if is_map else job.pending_reduce_count
+    tip = (job.maps if is_map else job.reduces)[0]
+    aid = str(task.attempt_id)
+    assert pending() == 0 and aid not in tip.attempts
+    job.requeue_lost_attempts([aid])
+    assert pending() == 1 and tip.state == "pending"
+    # settled KILLED: it burns no attempt of the task's budget, and a
+    # late status of the dead attempt cannot resurrect it
+    assert tip.attempts[aid].state == TaskState.KILLED
+    assert tip.failures == 0
+    job.update_task_status(TaskStatus(
+        attempt_id=task.attempt_id, is_map=is_map,
+        state=TaskState.SUCCEEDED), "h:0")
+    assert pending() == 1 and tip.state == "pending"
+    again = job.obtain_new_map_task("h2", run_on_tpu=False) if is_map \
+        else job.obtain_new_reduce_task("h2")
+    assert again.attempt_id.attempt == 1
+    # the master hands a lost tracker's attempts of EVERY job to each
+    # job: another job's must not pass for this one's
+    other = make_job(n_maps=1, n_reduces=1, kernel=False, job_num=2)
+    other.obtain_new_map_task("h3", run_on_tpu=False)
+    other.obtain_new_reduce_task("h3")
+    other.requeue_lost_attempts([aid])
+    for tip in (other.maps[0], other.reduces[0]):
+        assert tip.state == "running" and not tip.attempts
 
 
 def test_per_job_minimize_mode_override():

@@ -971,8 +971,19 @@ def cmd_simulate(conf, argv: list[str]) -> int:
                                             {}).get("p99", 0.0),
                 "heartbeat_lag_p99_s": jt_m.get("heartbeat_lag_seconds",
                                                 {}).get("p99", 0.0),
-                "lock_wait_p99_s": jt_m.get("jt_lock_wait_seconds",
-                                            {}).get("p99", 0.0),
+                # one wait series per decomposed master lock class;
+                # the unsuffixed row is the GLOBAL lock's
+                "lock_wait_p99_s": jt_m.get(
+                    "jt_lock_wait_seconds|lock=global",
+                    {}).get("p99", 0.0),
+                "lock_wait_trackers_p99_s": jt_m.get(
+                    "jt_lock_wait_seconds|lock=trackers",
+                    {}).get("p99", 0.0),
+                "lock_wait_scheduler_p99_s": jt_m.get(
+                    "jt_lock_wait_seconds|lock=scheduler",
+                    {}).get("p99", 0.0),
+                "interval_instructed_ms": jt_m.get(
+                    "heartbeat_interval_instructed_ms", 0),
                 "assign_p99_s": snap.get("scheduler", {}).get(
                     "assign_seconds", {}).get("p99", 0.0),
                 "completion_event_lag_p99": jt_m.get(
@@ -992,15 +1003,15 @@ def cmd_simulate(conf, argv: list[str]) -> int:
 def _simulate_dfs(conf, a: "dict[str, str]") -> int:
     """``simulate -dfs N`` — one DFS saturation rung: a fresh
     in-process MiniDFSCluster under a fleet of N real DFSClients on a
-    fixed op cadence (``tpumr/scale/simdfs.py``), reported as the same
-    joined row ``bench_dfs.py`` commits — NameNode op/lock/editlog
-    attribution plus client-side round trips and hot-block skew.
+    fixed op cadence (``tpumr/scale/simdfs.py``), reported as one
+    joined row — NameNode op/lock/editlog attribution plus client-side
+    round trips and hot-block skew.
     ``-seconds S`` measurement window, ``-interval MS`` per-client op
     cadence, ``-datanodes N``, ``-files N`` working-set size,
     ``-hot-p P`` hot-file read probability, ``-prom PATH`` scrapes the
     live NameNode /metrics/prom into PATH. The row is judged against
-    the bench_dfs dual SLO (``tpumr.dfs.bench.op.slo.ms`` /
-    ``.read.slo.ms``); exit 1 when it fails."""
+    the dual SLO (``tpumr.dfs.bench.op.slo.ms`` / ``.read.slo.ms``);
+    exit 1 when it fails."""
     from tpumr.core import confkeys
     from tpumr.scale.simdfs import run_dfs_step
     row = run_dfs_step(

@@ -404,10 +404,6 @@ _ENTRIES: "tuple[ConfKey, ...]" = (
         "ChainReducer: post-reduce mapper chain."),
     _K('tpumr.chain.reducer', 'str', None,
         "ChainReducer: the wrapped reducer."),
-    _K('tpumr.cluster.id.suffix', 'str', '',
-        "Suffix appended to the master's start-time cluster id (shard "
-        "workers set s<k> so same-millisecond shard boots can't mint "
-        "colliding job ids)."),
     _K('tpumr.cpu.batch.map', 'bool', True,
         "Vectorized CPU batch path for kernel maps."),
     _K('tpumr.datajoin.mappers', 'str', None,
@@ -439,21 +435,11 @@ _ENTRIES: "tuple[ConfKey, ...]" = (
         "Comma list of device-cache tags this job's tasks want warm "
         "(empty = derived from the job's known side inputs)."),
     _K('tpumr.dfs.bench.op.slo.ms', 'int', 100,
-        "bench_dfs: NameNode op-latency p99 SLO (merged nn_op_seconds) "
-        "a rung must hold to count as sustainable, ms."),
+        "simulate -dfs: NameNode op-latency p99 SLO (merged "
+        "nn_op_seconds) a rung must hold to pass, ms."),
     _K('tpumr.dfs.bench.read.slo.ms', 'int', 250,
-        "bench_dfs: client-side end-to-end read round-trip p99 SLO a "
-        "rung must hold to count as sustainable, ms."),
-    _K('tpumr.dfs.bench.recovery.client.slo.s', 'float', 15.0,
-        "bench_dfs --recovery-only: nn-kill -> first client op success "
-        "SLO, seconds (clients riding tdfs.client.nn.retries across "
-        "the outage)."),
-    _K('tpumr.dfs.bench.recovery.replication.slo.s', 'float', 30.0,
-        "bench_dfs --recovery-only: dn-kill -> replication-restored "
-        "SLO, seconds (includes the datanode expiry window)."),
-    _K('tpumr.dfs.bench.recovery.safemode.slo.s', 'float', 10.0,
-        "bench_dfs --recovery-only: nn-kill -> safemode-exit SLO, "
-        "seconds (editlog replay + enough block reports)."),
+        "simulate -dfs: client-side end-to-end read round-trip p99 SLO "
+        "a rung must hold to pass, ms."),
     _K('tpumr.distcp.preserve', 'bool', False,
         "distcp: preserve file attributes."),
     _K('tpumr.distcp.update', 'bool', False,
@@ -557,15 +543,6 @@ _ENTRIES: "tuple[ConfKey, ...]" = (
         "New-API mapper class bridge key."),
     _K('tpumr.mapreduce.partitioner.class', 'class', None,
         "New-API partitioner class bridge key."),
-    _K('tpumr.master.shards', 'int', 0,
-        "Shard worker processes the master partitions its tracker fleet "
-        "across (0 = classic single-process master). Trackers hash to a "
-        "shard by crc32(name); each shard owns its trackers' full "
-        "heartbeat fast path and the jobs routed to it."),
-    _K('tpumr.master.shards.poll.ms', 'int', 250,
-        "Coordinator period for pulling per-shard metrics snapshots and "
-        "folding them into the merged /metrics and flight-recorder "
-        "view, ms."),
     _K('tpumr.matmul.b', 'str', None,
         "Matmul op: serialized B operand."),
     _K('tpumr.matmul.bf16', 'bool', True,
@@ -644,7 +621,7 @@ _ENTRIES: "tuple[ConfKey, ...]" = (
         "next to the job history)."),
     _K('tpumr.prof.incident.slo.ms', 'int', 250,
         "Windowed heartbeat p99 (handling or lag) above this arms the "
-        "flight recorder — the bench_scale dual-p99 SLO, live."),
+        "flight recorder (the master's dual-p99 SLO)."),
     _K('tpumr.prof.trie.max.nodes', 'int', 20000,
         "Profiler stack-trie node budget; overflow folds into (other) "
         "so profiler memory stays bounded."),

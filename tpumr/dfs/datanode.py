@@ -259,8 +259,7 @@ class DataNode:
                                      1 << 40))
         self.heartbeat_s = float(conf.get("tdfs.datanode.heartbeat.s", 1.0))
         # block read/write path metrics — byte + latency distributions
-        # and a live concurrent-reader gauge, the series the bench_dfs
-        # read-throughput SLO is judged against
+        # and a live concurrent-reader gauge
         from tpumr.metrics import MetricsSystem
         from tpumr.metrics.histogram import BYTES
         self.metrics = MetricsSystem("datanode")

@@ -1787,8 +1787,8 @@ class NameNode:
         self.ns = FSNamesystem(name_dir, conf)
         self.dn_expiry_s = float(conf.get("tdfs.datanode.expiry.s", 10))
         # metrics live on the daemon whether or not HTTP is enabled —
-        # the lock/editlog/op histograms must exist for bench_dfs and
-        # the flight recorder even on a headless NN
+        # the lock/editlog/op histograms must exist for the flight
+        # recorder and ``simulate -dfs`` even on a headless NN
         from tpumr.metrics import MetricsSystem
         self.metrics = MetricsSystem("namenode")
         self._mreg = self.metrics.new_registry("namenode")

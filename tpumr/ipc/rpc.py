@@ -679,7 +679,7 @@ class RpcServer:
 
     def inflight_peak(self, reset: bool = False) -> int:
         """High-water mark of concurrently dispatched requests since
-        the last ``reset=True`` read (the bench_scale per-row peak)."""
+        the last ``reset=True`` read."""
         with self._inflight_lock:
             peak = self._inflight_peak
             if reset:

@@ -1331,6 +1331,15 @@ class JobInProgress:
                 self._preempt_requested.discard(aid)
                 self._kill_marked.discard(aid)
                 st = tip.attempts.get(aid)
+                if st is None and attempt.task.job == self.job_id \
+                        and attempt.attempt < tip.next_attempt:
+                    # THIS job launched it (the caller passes a
+                    # tracker's attempts of every job to each), in a
+                    # response the tracker did not live to read: no
+                    # status ever arrived, yet the task sits `running`
+                    # on it — lost like any other running attempt
+                    st = tip.attempts[aid] = TaskStatus(
+                        attempt_id=attempt, is_map=tip.is_map)
                 if st is not None and st.state == TaskState.RUNNING:
                     # honor a pending -fail-task even when the tracker
                     # died before delivering the kill: the operator asked

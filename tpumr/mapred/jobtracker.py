@@ -23,9 +23,9 @@ Structural divergence (by design, SURVEY.md §3.2): no global synchronized
 heartbeat monitor around O(jobs×tasks) recomputation — job profiling uses
 O(1) running sums and the master lock only guards registries.
 
-Lock decomposition (PR 8 — the reference's single synchronized monitor
-is exactly the ~200-tracker wall bench_scale.json measured): the
-heartbeat fast path touches the GLOBAL lock briefly or not at all.
+Lock decomposition (in place of the reference's single synchronized
+monitor): the heartbeat fast path touches the GLOBAL lock briefly or not
+at all.
 
 - ``self.lock`` (rank ``global``) guards only the job table, commit
   grants, and admin swaps; the job table itself is insert-only, so
@@ -135,7 +135,7 @@ class _TrackerInfo:
         #: hit the replay cache, never double-assign. Different
         #: trackers' beats never touch each other's lock — this is the
         #: bottom rank of the master's lock order, held across the
-        #: fold/assign phases while the shard lock is not.
+        #: fold/assign phases while the registry's stripe lock is not.
         from tpumr.metrics.locks import (RANK_TRACKER_BEAT,
                                          InstrumentedRLock)
         self.hb_lock = InstrumentedRLock(name="tracker-beat",
@@ -276,11 +276,8 @@ class JobMaster:
         self._devcache_index_cache: "tuple[float, dict]" = (-1.0, {})
         # start-time-in-ms identifier ≈ JobTracker's trackerIdentifier —
         # must differ across restarts or recovered job ids collide with
-        # the original's history file. The suffix keeps N shard masters
-        # booted in the same millisecond from minting colliding job ids
-        # (the cluster component of a JobID is a free string).
-        self.cluster_id = (str(int(time.time() * 1000))
-                           + str(conf.get("tpumr.cluster.id.suffix") or ""))
+        # the original's history file
+        self.cluster_id = str(int(time.time() * 1000))
         self.expiry_s = conf.get_int("tpumr.tracker.expiry.ms", 10_000) / 1000.0
         self.blacklist_faults = conf.get_int("tpumr.tracker.max.faults", 4)
         sched_cls = conf.get_class("mapred.jobtracker.taskScheduler",
@@ -2525,10 +2522,9 @@ class JobMaster:
             self._hb_seconds.observe(time.monotonic() - t0)
 
     def heartbeat_batch(self, beats: list) -> list:
-        """Many co-located trackers' beats in ONE RPC (satellite of the
-        sharded-master work: the syscall + dispatch overhead of a
-        round-trip per tracker was the measured single-process wall,
-        not the fold itself). Each member is ``[status,
+        """Many co-located trackers' beats in ONE RPC, sparing the
+        syscall + dispatch overhead of a round-trip per tracker (only
+        ``SimFleet(batch=...)`` sends it). Each member is ``[status,
         initial_contact, ask_for_new_task, response_id]`` and is folded
         through the normal :meth:`heartbeat` path — the per-tracker
         replay cache, hb_lock, delta decode, and deferred phase all
@@ -2549,26 +2545,6 @@ class JobMaster:
             except Exception as e:  # noqa: BLE001 — member-isolated
                 out.append({"error": f"{type(e).__name__}: {e}"})
         return out
-
-    def shard_snapshot(self) -> dict:
-        """One coordinator poll's worth of this shard's state: the full
-        typed metrics snapshot (the coordinator folds counter deltas
-        reset-safely, so a respawned shard's counters restarting at zero
-        don't go negative), per-class latency histograms, and this
-        shard's own CPU shares from the always-on profiler — the
-        per-shard ``cpu_share`` columns the scale bench commits come
-        straight from here. Handler-pool method like any slow RPC."""
-        return {
-            "cluster_id": self.cluster_id,
-            "trackers": len(self.trackers),
-            "metrics": self.metrics.typed_snapshot(),
-            "class_hists": {f"{kind}|{cls}": h.typed()
-                            for (kind, cls), h
-                            in list(self._class_hists.items())},
-            "rpc_inflight_peak": self._server.inflight_peak(),
-            "cpu_shares": (self.sampler.subsystem_shares()
-                           if self.sampler is not None else None),
-        }
 
     def _phase_span(self, hb_trace: "dict | None", name: str,
                     start_wall: float, **attrs: Any) -> None:

@@ -1,11 +1,11 @@
 """Flight recorder: automatic postmortem bundles when the master
 breaches its own latency SLO.
 
-bench_scale.json enshrines a dual-p99 SLO (heartbeat handling and
-per-tracker lag under 250 ms) that CI gates on — but a breach in a LIVE
-cluster evaporates before anyone can attach a profiler: by the time an
-operator reads the page, the convoy that caused it is gone. The
-recorder closes that gap. A watchdog thread on the master derives a
+The master's SLO is a dual p99 (heartbeat handling and per-tracker lag
+under ``tpumr.prof.incident.slo.ms``) — but a breach in a LIVE cluster
+evaporates before anyone can attach a profiler: by the time an operator
+reads the page, the convoy that caused it is gone. The recorder closes
+that gap. A watchdog thread on the master derives a
 WINDOWED p99 each tick from the cumulative ``heartbeat_seconds`` /
 ``heartbeat_lag_seconds`` histograms (``typed()`` state diffed with
 ``typed_delta`` — the same mechanism the heartbeat cluster merge uses),
@@ -444,10 +444,9 @@ class NNFlightRecorder(FlightRecorder):
     @classmethod
     def from_conf(cls, conf: Any, namenode: Any,
                   sampler: Any) -> "NNFlightRecorder | None":
-        """None unless ``tpumr.nn.incident.slo.ms`` > 0 (off by default —
-        unlike the master there is no committed-bench SLO to re-derive
-        yet; bench_dfs.py declares one explicitly). The incident dir
-        falls back to the name dir, which always exists."""
+        """None unless ``tpumr.nn.incident.slo.ms`` > 0 (off by
+        default). The incident dir falls back to the name dir, which
+        always exists."""
         from tpumr.core import confkeys
         slo_ms = confkeys.get_int(conf, "tpumr.nn.incident.slo.ms")
         if slo_ms <= 0:
@@ -516,59 +515,6 @@ class NNFlightRecorder(FlightRecorder):
                           "datanodes": len(nn.ns.datanodes)},
             "spans": [],
         }
-
-
-class ShardFlightRecorder(FlightRecorder):
-    """The sharded-master coordinator's SLO watchdog. The base tick
-    windows the COORDINATOR-MERGED ``heartbeat_seconds`` /
-    ``heartbeat_lag_seconds`` (folded from every shard's deltas) and
-    the merged per-class hists, so cluster-wide breach judgement is
-    unchanged; on top of that it windows each shard's own heartbeat
-    distributions, so a breach driven by ONE hot or dying shard shows
-    up as ``heartbeat_seconds|shard=k`` in the bundle's reason — the
-    incident names the breaching shard instead of blaming the whole
-    master. No sampler of its own: the coordinator does no fold work
-    worth profiling; per-shard CPU shares ride in the ``shards``
-    section instead."""
-
-    @classmethod
-    def from_conf(cls, conf: Any,
-                  coordinator: Any) -> "ShardFlightRecorder | None":
-        from tpumr.core import confkeys
-        if not (confkeys.get_boolean(conf, "tpumr.prof.enabled")
-                or confkeys.get_boolean(conf, "tpumr.brownout.enabled")):
-            return None
-        d = conf.get("tpumr.prof.incident.dir") \
-            or conf.get("tpumr.history.dir")
-        if not d:
-            return None
-        return cls(
-            coordinator, None,
-            slo_ms=confkeys.get_int(conf, "tpumr.prof.incident.slo.ms"),
-            cooldown_ms=confkeys.get_int(
-                conf, "tpumr.prof.incident.cooldown.ms"),
-            incident_dir=os.path.join(str(d), "incidents"),
-            conf=conf)
-
-    def _windowed_p99s(self) -> "list[tuple[str, float]]":
-        rows = super()._windowed_p99s()
-        hists = getattr(self.master, "_shard_hists", None) or {}
-        for (k, name), hist in sorted(hists.items()):
-            metric = f"{name}|shard={k}"
-            cur = hist.typed()
-            delta = typed_delta(cur, self._prev.get(metric))
-            self._prev[metric] = cur
-            if delta and delta.get("count"):
-                rows.append((metric, typed_p99(delta)))
-        return rows
-
-    def bundle(self, breaches: "list[tuple]") -> dict:
-        doc = super().bundle(breaches)
-        doc["role"] = "coordinator"
-        stats = self.master.shard_stats() \
-            if hasattr(self.master, "shard_stats") else {}
-        doc["shards"] = stats
-        return doc
 
 
 def validate_incident(doc: Any) -> "list[str]":

@@ -10,8 +10,7 @@ hold time (how long the winner keeps everyone else out) land in
 histograms (``jt_lock_wait_seconds{lock=...}`` /
 ``jt_lock_hold_seconds{lock=...}``). Wait p99 climbing while hold p99
 stays flat = more contenders; both climbing = the work under the lock
-grew. These are the first series the control-plane scale-out refactor
-is judged against (ROADMAP, bench_scale.py).
+grew. ``tpumr simulate`` prints both p99s for a simulated fleet.
 
 Since the lock decomposition (PR 8) the master runs on a fixed set of
 lock classes with a fixed acquisition order, ascending by rank (the
@@ -61,10 +60,6 @@ from typing import Any
 RANK_TRACKER_BEAT = 5    # one tracker's heartbeat processing
 RANK_SCHEDULER = 10      # scheduler passes (before_heartbeat / assign)
 RANK_PIPELINE = 15       # DAG engine state (PipelineInProgress tables)
-RANK_COORDINATOR = 18    # sharded-master coordinator tables (job→shard
-#                          routing, shard records, merged snapshots) —
-#                          its own process; every blocking edge (shard
-#                          RPC, Popen, wait) runs OUTSIDE it by rule
 RANK_GLOBAL = 20         # job table, commit grants, admin swaps
 RANK_NAMESPACE = 25      # the NameNode's structural/global lock (DFS
 #                          control plane; its own process — co-held
@@ -81,9 +76,8 @@ RANK_TRACKERS = 30       # tracker registry stripes
 RANK_JOB = 40            # one JobInProgress's task bookkeeping
 
 _ORDER_NAMES = "tracker-beat(5) -> scheduler(10) -> pipeline(15) " \
-               "-> coordinator(18) -> global(20) -> namespace(25) " \
-               "-> namespace-stripe(26) -> namespace-blocks(27) " \
-               "-> trackers(30) -> job(40)"
+               "-> global(20) -> namespace(25) -> namespace-stripe(26) " \
+               "-> namespace-blocks(27) -> trackers(30) -> job(40)"
 
 #: debug-mode ordering assertion: on under ``__debug__`` (plain
 #: ``python``), off under ``python -O`` or TPUMR_LOCK_ORDER_CHECK=0

@@ -1,10 +1,8 @@
 """Control-plane scale harness — simulated trackers, real wire protocol.
 
-The ROADMAP's scale-out item demands measurement before refactoring:
-the JobTracker is one process absorbing every heartbeat, completion-
-event poll, and fetch-failure report, and nobody ever measured where it
-saturates (the reference inherited Hadoop 1.0.3's JobTracker with the
-same blind spot). This package supplies the load side:
+The JobTracker is one process absorbing every heartbeat, completion-
+event poll, and fetch-failure report. This package loads it (and a
+NameNode) by hand, behind ``tpumr simulate``:
 
 - :mod:`tpumr.scale.simtracker` — ``SimTracker``/``SimFleet``: N
   lightweight fake trackers speaking the REAL heartbeat protocol over
@@ -18,7 +16,7 @@ same blind spot). This package supplies the load side:
 - :mod:`tpumr.scale.simdfs` — ``SimDFSClient``/``SimDFSFleet``: the
   storage twin — N real ``DFSClient`` instances generating a skewed
   read-dominant op mix against a live NameNode + DataNodes, the load
-  side of ``bench_dfs.py`` and ``tpumr simulate -dfs``.
+  side of ``tpumr simulate -dfs``.
 - :mod:`tpumr.scale.driver` — ``ScaleDriver``: submits synthetic
   multi-job workloads over the client RPC surface and waits for them.
 - :mod:`tpumr.scale.scenario` — the scenario lab: named,
@@ -29,9 +27,9 @@ same blind spot). This package supplies the load side:
 
 The read side is the master's own saturation series (heartbeat
 latency/lag/phases, ``jt_lock_wait_seconds``, ``rpc_inflight``,
-completion-event lag) — see ``bench_scale.py`` at the repo root, which
-ramps fleet sizes and writes the ``bench_scale.json`` baseline every
-control-plane refactor must beat, and ``tpumr simulate`` in the CLI.
+completion-event lag), which ``tpumr simulate`` prints when it hosts
+the master itself. These are host-clock numbers of simulated trackers:
+the system's speed is what ``bench/run.py`` measures on the chip.
 """
 
 from tpumr.scale.driver import ScaleDriver

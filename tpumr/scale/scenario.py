@@ -66,7 +66,7 @@ class ScenarioError(ValueError):
 
 _PRIORITIES = ("VERY_HIGH", "HIGH", "NORMAL", "LOW", "VERY_LOW")
 _CHAOS_KINDS = ("tracker_crash", "tracker_partition",
-                "master_restart", "shard_kill", "fi",
+                "master_restart", "fi",
                 "dn_crash", "dn_partition", "nn_restart",
                 "block_corrupt")
 #: the storage chaos kinds — only valid when the spec has a [dfs] table
@@ -80,7 +80,7 @@ _FLEET_DEFAULTS = {"trackers": 8, "interval_ms": 100, "cpu_slots": 2,
                    "fetch_failure_rate": 0.0, "batch": 0}
 _MASTER_DEFAULTS = {"expiry_ms": 60_000, "beats_per_second": 0,
                     "interval_max_ms": 0, "brownout": False,
-                    "shards": 0, "conf": {}}
+                    "conf": {}}
 _CLASS_DEFAULTS = {"jobs": 1, "maps": 2, "reduces": 0, "start_ms": 0,
                    "period_ms": 500, "jitter_ms": 0, "rounds": 1,
                    "priority": "NORMAL", "slo_assign_ms": None,
@@ -99,11 +99,6 @@ _CHAOS_DEFAULTS = {
     "tracker_partition": {"count": 1, "targets": None,
                           "duration_ms": 2500},
     "master_restart": {},
-    # SIGKILL one shard worker of a sharded master (master.shards > 0);
-    # the coordinator's monitor respawns it on its pinned port and the
-    # shard's trackers re-join via the adoption protocol. shard=None
-    # draws the victim from the seeded stream
-    "shard_kill": {"shard": None},
     "fi": {"point": None, "probability": 0.0, "max_failures": 0,
            "ms": None},
     # hard-kill datanode(s) mid-whatever; rejoin_ms=None means they
@@ -183,7 +178,6 @@ def validate_spec(spec: Any) -> dict:
                   "fleet")
     if int(out["fleet"]["trackers"]) < 1:
         raise ScenarioError("fleet.trackers must be >= 1")
-    _non_negative(out["master"], ("shards",), "master")
     classes = spec.get("classes")
     if not isinstance(classes, list) or not classes:
         raise ScenarioError("classes must be a non-empty list "
@@ -252,24 +246,6 @@ def validate_spec(spec: Any) -> dict:
                 raise ScenarioError(
                     f"chaos[{i}].file_index must be in "
                     f"[0, {n_files})")
-        if kind == "shard_kill":
-            n_shards = int(out["master"]["shards"])
-            if n_shards < 1:
-                raise ScenarioError(
-                    f"chaos[{i}].shard_kill needs master.shards >= 1 "
-                    "(only a sharded master has shard workers to kill)")
-            if row["shard"] is not None and (
-                    not isinstance(row["shard"], int)
-                    or not 0 <= row["shard"] < n_shards):
-                raise ScenarioError(
-                    f"chaos[{i}].shard must be a shard index in "
-                    f"[0, {n_shards})")
-        if kind == "master_restart" \
-                and int(out["master"]["shards"]) > 0:
-            raise ScenarioError(
-                f"chaos[{i}].master_restart is the single-process "
-                "master's chaos kind — use shard_kill against a "
-                "sharded master")
         if kind == "fi":
             if not row["point"] or "tpumr" in str(row["point"]):
                 raise ScenarioError(
@@ -321,12 +297,6 @@ def plan(spec: dict) -> "list[dict]":
                     if ev["rejoin_ms"] is not None else None)
             else:
                 row["duration_s"] = ev["duration_ms"] / 1000.0
-        elif ev["kind"] == "shard_kill":
-            shard = ev["shard"]
-            if shard is None:
-                shard = rng.randrange(
-                    int(spec["master"]["shards"]))
-            row["shard"] = int(shard)
         elif ev["kind"] == "fi":
             row.update(point=str(ev["point"]),
                        probability=float(ev["probability"]),
@@ -511,27 +481,6 @@ BUILTIN_SCENARIOS: "dict[str, dict]" = {
         ],
         "timeout_s": 90,
     },
-    # the sharded master's failover mix: a 2-shard master under a
-    # batched fleet, one shard SIGKILLed mid-mix — the coordinator
-    # respawns it on its pinned port, its trackers re-join via the
-    # adoption path, the sibling shard never notices, and every job
-    # (old ids polled throughout) still completes
-    "shard_kill": {
-        "name": "shard_kill",
-        "fleet": {"trackers": 12, "task_mean_ms": 300, "batch": 4},
-        "master": {"shards": 2, "expiry_ms": 60_000},
-        "classes": [
-            {"name": "interactive", "jobs": 6, "maps": 2, "reduces": 0,
-             "period_ms": 1200, "jitter_ms": 300, "priority": "HIGH",
-             "slo_assign_ms": 4000, "slo_complete_ms": 20_000},
-            {"name": "batch", "jobs": 2, "maps": 16, "reduces": 2,
-             "period_ms": 2000, "slo_complete_ms": 60_000},
-        ],
-        "chaos": [
-            {"kind": "shard_kill", "at_ms": 3000},
-        ],
-        "timeout_s": 90,
-    },
     # a mid-mix master kill/restart with journal recovery: the fleet
     # keeps beating, the driver keeps polling old job ids, every job
     # still completes
@@ -671,8 +620,6 @@ class ScenarioRunner:
                      int(mast["interval_max_ms"]))
         if mast["brownout"]:
             conf.set("tpumr.brownout.enabled", True)
-        if mast["shards"]:
-            conf.set("tpumr.master.shards", int(mast["shards"]))
         if fleet["batch"]:
             # the fleet's coalescing twin of the master's batch RPC —
             # one knob in the conf so the run() fleet reads it back
@@ -830,8 +777,7 @@ class ScenarioRunner:
         fleet_spec = spec["fleet"]
         interval_s = fleet_spec["interval_ms"] / 1000.0
         from tpumr.core import confkeys
-        from tpumr.mapred.shardmaster import make_master
-        master = make_master(conf).start()
+        master = JobMaster(conf).start()
         host, port = master.address
         masters = [master]
         fleet = SimFleet(
@@ -842,8 +788,6 @@ class ScenarioRunner:
             task_time_mean_s=fleet_spec["task_mean_ms"] / 1000.0,
             fetch_failure_rate=fleet_spec["fetch_failure_rate"],
             batch=confkeys.get_int(conf, "tpumr.heartbeat.batch"),
-            shard_map=(master.shard_map()
-                       if hasattr(master, "shard_map") else None),
             fi_conf=conf).start()
         driver = ScaleDriver(host, port)
         cluster = dfs_fleet = None
@@ -928,19 +872,6 @@ class ScenarioRunner:
                     chaos_log.append({
                         "t_s": round(time.monotonic() - t0, 3),
                         "kind": "master_restart"})
-                elif ev["kind"] == "shard_kill":
-                    t_kill = time.monotonic()
-                    info = masters[-1].kill_shard(ev["shard"])
-                    respawned = masters[-1].wait_shard_ready(
-                        ev["shard"], 30.0)
-                    chaos_log.append({
-                        "t_s": round(time.monotonic() - t0, 3),
-                        "kind": "shard_kill",
-                        "shard": int(ev["shard"]),
-                        "pid": info.get("pid"),
-                        "respawned": bool(respawned),
-                        "respawn_s": round(
-                            time.monotonic() - t_kill, 3)})
                 elif ev["kind"] == "fi":
                     self._apply_fi(conf, ev)
                     chaos_log.append({
@@ -1186,8 +1117,6 @@ class ScenarioRunner:
                 "attempts_adopted": int(
                     jt.get("attempts_adopted", 0)),
                 "master_restarts": len(masters) - 1,
-                "shards_killed": int(jt.get("shards_killed", 0)),
-                "shard_restarts": int(jt.get("shard_restarts", 0)),
                 "datanodes_killed": sum(
                     len(r.get("targets", ())) for r in chaos_log
                     if r["kind"] == "dn_crash"),

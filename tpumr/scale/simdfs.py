@@ -6,7 +6,7 @@ drives a real NameNode + DataNodes with real ``DFSClient`` instances —
 every namespace op is a genuine RPC through the instrumented
 ``NameNode._op`` seam, every block read moves real bytes off a real
 DataNode (and into its SpaceSaving hot-block sketch). Nothing is
-mocked, so what bench_dfs measures is the actual serving stack.
+mocked, so what a rung measures is the actual serving stack.
 
 The workload is the mix a MapReduce cluster's storage layer sees:
 
@@ -25,8 +25,8 @@ The workload is the mix a MapReduce cluster's storage layer sees:
 fixed-rate heap (same skeleton as ``SimFleet``): each client has a due
 time every ``interval_s``; the due-vs-actual gap is the client-side
 scheduling lag, and per-op round trips are the client-side latency
-view that bench_dfs compares against the NameNode's own
-``nn_op_seconds`` attribution.
+view a rung's row sets beside the NameNode's own ``nn_op_seconds``
+attribution.
 """
 
 from __future__ import annotations
@@ -128,7 +128,7 @@ class SimDFSClient:
         self.home = f"{home}/{name}"
         # the directory the listing op sweeps: the working set's own
         # parent (NOT a hardcoded root — the scenario lab seeds under a
-        # different tree than bench_dfs)
+        # different tree than a ``simulate -dfs`` rung)
         self._data_root = (self.files[0].rsplit("/", 1)[0] or "/") \
             if self.files else "/"
         self._made_home = False
@@ -365,8 +365,7 @@ def run_dfs_step(n_clients: int, *, conf: Any = None,
     fleet of ``n_clients`` real DFSClients on a fixed op cadence for
     ``measure_s``, then one joined snapshot of both sides — the
     NameNode's own op/lock/editlog attribution and the fleet's
-    client-side round trips. Shared by ``bench_dfs.py`` (the ramp) and
-    ``tpumr simulate -dfs`` (one rung, operator-driven).
+    client-side round trips. ``tpumr simulate -dfs`` runs one rung.
 
     ``prom_out`` additionally scrapes the NameNode's live
     ``/metrics/prom`` at the end of the window and writes the body
@@ -487,139 +486,3 @@ def run_dfs_step(n_clients: int, *, conf: Any = None,
             with open(prom_out, "wb") as f:
                 f.write(body)
         return row
-
-
-# ------------------------------------------------------ recovery steps
-
-
-def _recovery_conf() -> "tuple[Any, dict]":
-    """One conf + the registered recovery SLOs for the timed kill
-    steps. Fast monitor/expiry cadences: the rows measure the
-    detection + repair MACHINERY, not production timer defaults."""
-    from tpumr.core import confkeys
-    from tpumr.mapred.jobconf import JobConf
-    conf = JobConf()
-    conf.set("tdfs.http.port", -1)
-    conf.set("dfs.replication", 2)
-    conf.set("tdfs.replication.interval.s", 0.2)
-    conf.set("tdfs.datanode.expiry.s", 1.5)
-    # clients ride a NameNode outage on transport retries; safemode
-    # refusals are application-retried by the probe
-    conf.set("tdfs.client.nn.retries", 60)
-    conf.set("tdfs.client.nn.backoff.ms", 100.0)
-    slos = {
-        "safemode": confkeys.get_float(
-            conf, "tpumr.dfs.bench.recovery.safemode.slo.s"),
-        "client": confkeys.get_float(
-            conf, "tpumr.dfs.bench.recovery.client.slo.s"),
-        "replication": confkeys.get_float(
-            conf, "tpumr.dfs.bench.recovery.replication.slo.s"),
-    }
-    return conf, slos
-
-
-def run_nn_kill_recovery(*, num_datanodes: int = 3, n_files: int = 8,
-                         file_bytes: int = 1 << 18,
-                         outage_s: float = 0.3) -> "list[dict]":
-    """SIGKILL the NameNode mid-traffic and time the two recovery
-    headlines from the moment of the kill: safemode exit (editlog
-    replay + enough block reports) and the first client op that
-    SUCCEEDS again (a probe riding transport retries across the
-    outage and application-retrying safemode refusals — the HDFS
-    SafeModeException loop). Returns the two bench rows with SLO
-    verdicts (``bench_dfs.py --recovery-only``)."""
-    from tpumr.dfs.mini_cluster import MiniDFSCluster
-
-    conf, slos = _recovery_conf()
-    base = {"kind": "", "datanodes": num_datanodes, "files": n_files,
-            "outage_s": outage_s}
-    with MiniDFSCluster(num_datanodes, conf=conf) as c:
-        files = seed_files(c.nn_host, c.nn_port, conf,
-                           n_files=n_files, file_bytes=file_bytes)
-        result: dict = {}
-
-        def probe() -> None:
-            cli = c.client()
-            try:
-                deadline = time.monotonic() + 25.0
-                while time.monotonic() < deadline:
-                    try:
-                        with cli.open(files[0]) as f:
-                            f.read(1024)
-                        result["t"] = time.monotonic()
-                        return
-                    except Exception as e:  # noqa: BLE001
-                        if "safe mode" not in str(e).lower():
-                            result["error"] = str(e)
-                            return
-                        time.sleep(0.1)
-                result["error"] = "probe timed out"
-            finally:
-                close_client(cli)
-
-        t_kill = time.monotonic()
-        c.kill_namenode()
-        t = threading.Thread(target=probe, daemon=True)
-        t.start()
-        time.sleep(outage_s)
-        c.restart_killed_namenode()
-        sm_deadline = time.monotonic() + 30.0
-        while c.namenode.ns.safemode \
-                and time.monotonic() < sm_deadline:
-            time.sleep(0.02)
-        safemode_s = time.monotonic() - t_kill
-        t.join(timeout=30.0)
-        rows = [dict(base, kind="nn_kill_safemode_exit",
-                     recovery_s=round(safemode_s, 3),
-                     slo_s=slos["safemode"],
-                     ok=(not c.namenode.ns.safemode
-                         and safemode_s <= slos["safemode"]))]
-        if "t" in result:
-            client_s = result["t"] - t_kill
-            rows.append(dict(base,
-                             kind="nn_kill_first_client_success",
-                             recovery_s=round(client_s, 3),
-                             slo_s=slos["client"],
-                             ok=client_s <= slos["client"]))
-        else:
-            rows.append(dict(base,
-                             kind="nn_kill_first_client_success",
-                             error=result.get("error", "no result")))
-        return rows
-
-
-def run_dn_kill_recovery(*, num_datanodes: int = 4, n_files: int = 8,
-                         file_bytes: int = 1 << 18) -> dict:
-    """Hard-kill one datanode holding seeded replicas and time the
-    NameNode's expiry + re-replication loop restoring EVERY block to
-    its replication target on the survivors. Returns the bench row
-    with its SLO verdict."""
-    from tpumr.dfs.mini_cluster import MiniDFSCluster
-
-    conf, slos = _recovery_conf()
-    with MiniDFSCluster(num_datanodes, conf=conf) as c:
-        seed_files(c.nn_host, c.nn_port, conf,
-                   n_files=n_files, file_bytes=file_bytes)
-        ns = c.namenode.ns
-        dead = c.datanodes[0].addr
-        n_blocks = len(ns.block_locations)
-        t_kill = time.monotonic()
-        c.kill_datanode(0)
-
-        def restored() -> bool:
-            for locs in ns.block_locations.values():
-                if dead in locs or len(locs) < 2:
-                    return False
-            return True
-
-        deadline = time.monotonic() + slos["replication"] + 10.0
-        while not restored() and time.monotonic() < deadline:
-            time.sleep(0.05)
-        recovery_s = time.monotonic() - t_kill
-        return {"kind": "dn_kill_replication_restored",
-                "datanodes": num_datanodes, "files": n_files,
-                "blocks": n_blocks,
-                "recovery_s": round(recovery_s, 3),
-                "slo_s": slos["replication"],
-                "ok": (restored()
-                       and recovery_s <= slos["replication"])}

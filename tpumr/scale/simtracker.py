@@ -100,9 +100,6 @@ class SimTracker:
                                                 task_time_mean_s))
         self._rng = rng or random.Random(hash(name) & 0xFFFFFFFF)
         self._fetch_failure_rate = float(fetch_failure_rate)
-        #: where this tracker's beats go — under a sharded master the
-        #: fleet points each tracker at the shard that owns its name
-        self.endpoint = (master_host, int(master_port))
         self.master = RpcClient(master_host, master_port, secret=secret,
                                 timeout=rpc_timeout_s)
         if handshake:
@@ -515,7 +512,6 @@ class SimFleet:
                  interval_s: float = 0.2, workers: "int | None" = None,
                  name_prefix: str = "sim", seed: int = 0,
                  batch: int = 0,
-                 shard_map: "list[tuple[str, int]] | None" = None,
                  stagger_s: "float | None" = None,
                  **tracker_kwargs: Any) -> None:
         self.master_host, self.master_port = master_host, master_port
@@ -536,11 +532,6 @@ class SimFleet:
         #: per-tracker pipelined path) — the client twin of the
         #: master's ``tpumr.heartbeat.batch`` knob
         self.batch = int(batch)
-        #: sharded master: each tracker beats the shard that owns its
-        #: name (the same crc32 mapping the coordinator serves from
-        #: ``get_shard_map``); None = one unsharded master
-        self.shard_map = ([(str(h), int(p)) for h, p in shard_map]
-                          if shard_map else None)
         self._tracker_kwargs = tracker_kwargs
         self.trackers: "list[SimTracker]" = []
         self._heap: "list[tuple[float, int]]" = []
@@ -558,20 +549,12 @@ class SimFleet:
         self._rtt = self.registry.histogram("hb_rtt_seconds")
         self._lag = self.registry.histogram("hb_lag_seconds")
 
-    def _endpoint(self, name: str) -> "tuple[str, int]":
-        if not self.shard_map:
-            return self.master_host, self.master_port
-        from tpumr.mapred.shardmaster import tracker_shard
-        return self.shard_map[tracker_shard(name,
-                                            len(self.shard_map))]
-
     def start(self) -> "SimFleet":
         rng = random.Random(self._seed)
         for i in range(self.n):
-            name = f"{self._prefix}_{i:04d}"
-            host, port = self._endpoint(name)
             self.trackers.append(SimTracker(
-                name, host, port, secret=self.secret, index=i,
+                f"{self._prefix}_{i:04d}", self.master_host,
+                self.master_port, secret=self.secret, index=i,
                 rng=random.Random(rng.randrange(1 << 30)),
                 **self._tracker_kwargs))
         now = time.monotonic()
@@ -598,14 +581,12 @@ class SimFleet:
     BATCH = 16
 
     def _worker(self) -> None:
-        #: per-worker, per-endpoint batch clients: the pipelined
+        #: this worker's own ``heartbeat_batch`` client: the pipelined
         #: RpcClient surface is single-threaded by contract
-        clients: "dict[tuple[str, int], RpcClient]" = {}
-        # a drain splits across shard endpoints (the heap orders by due
-        # time, not owner), so scale it by the shard count or each
-        # endpoint's RPC would only carry ~batch/shards members
-        cap = max(self.BATCH, self.batch * (len(self.shard_map)
-                                            if self.shard_map else 1))
+        client = (RpcClient(self.master_host, self.master_port,
+                            secret=self.secret)
+                  if self.batch > 1 else None)
+        cap = max(self.BATCH, self.batch)
         try:
             while not self._stop.is_set():
                 batch: "list[tuple[float, int]]" = []
@@ -623,7 +604,7 @@ class SimFleet:
                     else:
                         return
                 if self.batch > 1:
-                    self._beat_batched(batch, clients)
+                    self._beat_batched(batch, client)
                 else:
                     self._beat_pipelined(batch)
                 # fixed-rate schedule AGAINST THE INSTRUCTED CADENCE
@@ -648,7 +629,7 @@ class SimFleet:
                             heapq.heappush(self._heap, (nxt, idx))
                     self._cv.notify()
         finally:
-            for client in clients.values():
+            if client is not None:
                 try:
                     client.close()
                 except Exception:  # noqa: BLE001 — teardown
@@ -678,69 +659,55 @@ class SimFleet:
                 self.registry.incr("hb_errors")
 
     def _beat_batched(self, batch: "list[tuple[float, int]]",
-                      clients: "dict[tuple[str, int], RpcClient]") \
-            -> None:
+                      client: RpcClient) -> None:
         """Coalesce this wakeup's due beats into ONE ``heartbeat_batch``
-        RPC per endpoint (per shard, under a sharded master): build all
-        members first, send every endpoint's batch back-to-back
-        (pipelined across endpoints), then collect and apply responses
-        member-by-member. One syscall round-trip now carries up to
-        ``batch`` beats — the client half of the batching win."""
+        RPC: build all members first, send, then apply the responses
+        member-by-member. One syscall round-trip carries up to
+        ``batch`` beats."""
         now = time.monotonic()
-        by_ep: "dict[tuple[str, int], list[SimTracker]]" = {}
+        built: "list[tuple[SimTracker, tuple]]" = []
         for due, idx in batch:
             self._lag.observe(max(0.0, now - due))
-            tracker = self.trackers[idx]
-            if tracker.stopped or now < tracker.paused_until:
+            tr = self.trackers[idx]
+            if tr.stopped or now < tr.paused_until:
                 continue
-            by_ep.setdefault(tracker.endpoint, []).append(tracker)
-        sends = []
-        for ep, members in by_ep.items():
-            built: "list[tuple[SimTracker, tuple]]" = []
-            for tr in members:
-                try:
-                    args = tr.heartbeat_build()
-                except Exception:  # noqa: BLE001 — event-poll hiccup
-                    self.registry.incr("hb_errors")
-                    continue
-                if args is not None:
-                    built.append((tr, args))
-            if not built:
-                continue
-            client = clients.get(ep)
-            if client is None:
-                client = clients[ep] = RpcClient(
-                    ep[0], ep[1], secret=self.secret)
-            t0 = time.monotonic()
             try:
-                client.call_begin("heartbeat_batch",
-                                  [list(a) for _, a in built])
-            except Exception:  # noqa: BLE001 — master down/overload
-                for tr, _ in built:
-                    tr.heartbeat_abort()
+                args = tr.heartbeat_build()
+            except Exception:  # noqa: BLE001 — event-poll hiccup
                 self.registry.incr("hb_errors")
                 continue
+            if args is not None:
+                built.append((tr, args))
+        if not built:
+            return
+        t0 = time.monotonic()
+        try:
+            client.call_begin("heartbeat_batch",
+                              [list(a) for _, a in built])
+        except Exception:  # noqa: BLE001 — master down/overload
             for tr, _ in built:
-                tr.crash_seam_fired()
-            sends.append((client, built, t0))
-        for client, built, t0 in sends:
-            try:
-                resps = client.call_finish()
-            except Exception:  # noqa: BLE001 — master down/overload
-                for tr, _ in built:
-                    if not tr.crashed:
-                        tr.heartbeat_abort()
-                self.registry.incr("hb_errors")
+                tr.heartbeat_abort()
+            self.registry.incr("hb_errors")
+            return
+        for tr, _ in built:
+            tr.crash_seam_fired()
+        try:
+            resps = client.call_finish()
+        except Exception:  # noqa: BLE001 — master down/overload
+            for tr, _ in built:
+                if not tr.crashed:
+                    tr.heartbeat_abort()
+            self.registry.incr("hb_errors")
+            return
+        self._rtt.observe(time.monotonic() - t0)
+        self.registry.incr("hb_batches")
+        for (tr, _), resp in zip(built, resps or []):
+            if tr.crashed or tr.stopped:
                 continue
-            self._rtt.observe(time.monotonic() - t0)
-            self.registry.incr("hb_batches")
-            for (tr, _), resp in zip(built, resps or []):
-                if tr.crashed or tr.stopped:
-                    continue
-                try:
-                    tr.heartbeat_apply(resp)
-                except Exception:  # noqa: BLE001 — member error
-                    self.registry.incr("hb_errors")
+            try:
+                tr.heartbeat_apply(resp)
+            except Exception:  # noqa: BLE001 — member error
+                self.registry.incr("hb_errors")
 
     def stop(self) -> None:
         self._stop.set()
@@ -775,13 +742,12 @@ class SimFleet:
         self.trackers_respawned += 1
         rng = random.Random(
             f"{self._seed}:respawn:{idx}:{self.trackers_respawned}")
-        name = f"{self._prefix}_{idx:04d}"
-        host, port = self._endpoint(name)
         deadline = time.monotonic() + 15.0
         while True:
             try:
                 tracker = SimTracker(
-                    name, host, port, secret=self.secret, index=idx,
+                    f"{self._prefix}_{idx:04d}", self.master_host,
+                    self.master_port, secret=self.secret, index=idx,
                     rng=rng, **self._tracker_kwargs)
                 break
             except OSError:

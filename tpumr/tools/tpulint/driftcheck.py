@@ -118,8 +118,8 @@ def _metric_known(token: str, metrics: set[str]) -> bool:
 
 
 def _root_modules(root: str) -> "list[Module]":
-    """Top-level repo scripts (bench_scale.py & friends) — their row
-    keys and identifiers are legitimately named in OPERATIONS.md."""
+    """Top-level repo scripts (chip_smoke.py) — their row keys and
+    identifiers are legitimately named in OPERATIONS.md."""
     import glob
 
     from tpumr.tools.tpulint.core import Pragmas
