@@ -195,6 +195,38 @@ def test_device_shuffle_program(meshes, record_property, n_dev, program):
     _record(record_property, mem, secs)
 
 
+@pytest.mark.parametrize("program", ["count", "piece"])
+def test_mesh_fetch_program(meshes, record_property, program):
+    """What brings the mesh sort's rows back, over four described chips:
+    the count of each device's live rows, and the piece of its shard from
+    a start given at run time, as flat 32-bit words."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpumr.parallel.device_sort import (make_count_fn, make_piece_fn,
+                                            piece_rows)
+    n_dev = 4
+    mesh = meshes[n_dev]
+    rows = NamedSharding(mesh, P("data"))
+    local = SORT_ROWS // 2              # as test_device_shuffle_program
+    per_dev = n_dev * max(16, 2 * local // n_dev)
+    m = n_dev * per_dev
+    if program == "count":
+        _c, mem, secs = _compile(make_count_fn(mesh),
+                                 _shape((m,), np.bool_, rows))
+        assert mem.output_size_in_bytes >= 4
+    else:
+        piece = piece_rows(local, per_dev)
+        assert piece == local // 8
+        _c, mem, secs = _compile(
+            make_piece_fn(mesh, ROW_W, piece),
+            _shape((m, ROW_W + 1), np.uint8, rows),
+            _shape((), np.int32, NamedSharding(mesh, P())))
+        # the rows' bytes a device and no padding a row: flat words are
+        # laid out in tiles of 1024, [p, 25] words would take 32 a row
+        assert 0 <= mem.output_size_in_bytes - piece * ROW_W < 4096
+    _record(record_property, mem, secs)
+
+
 def test_kmeans_distributed_step_has_the_psum(meshes, record_property):
     from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -133,7 +133,7 @@ def test_mesh_partition_sort_agrees_with_numpy_lexsort(n_dev, case):
     lower one), and the bucket's padding is gone."""
     from tpumr.parallel.device_sort import (bucket_rows, compute_dest,
                                             device_partition_sort,
-                                            key_columns)
+                                            key_columns, piece_rows)
     from tpumr.parallel.mesh import make_mesh
     records, splitters, num_ranges = _seeded_case(case, n_dev)
     klen, n = 10, records.shape[0]
@@ -143,8 +143,17 @@ def test_mesh_partition_sort_agrees_with_numpy_lexsort(n_dev, case):
     assert shards is not None and len(shards) == n_dev
     # five distinct keys may fill a bucket: then a retry, never a loss
     assert overflow == 0 or case == "duplicate_heavy"
+    back = stats.pop("bytes_back")
     assert stats == {"pad_rows": bucket_rows(n, n_dev) - n,
                      "retries": 1 if overflow else 0}
+    local = bucket_rows(n, n_dev) // n_dev
+    active = max(1, -(-num_ranges // -(-num_ranges // n_dev)))
+    capacity = max(16, 2 * local // active) * (2 if overflow else 1)
+    piece = piece_rows(local, n_dev * capacity)
+    assert records.nbytes <= back <= _fetch_bound(shards, piece)
+    if case == "uniform":       # the rows, not the slots reserved for them
+        slots = n_dev * n_dev * capacity * (records.shape[1] + 1)
+        assert back < 0.6 * slots
     kcols = key_columns(records[:, :klen], klen)
     order = np.lexsort(tuple(kcols[:, c] for c in range(2, -1, -1)))
     want = records[order]
@@ -164,13 +173,97 @@ def test_mesh_partition_sort_agrees_with_numpy_lexsort(n_dev, case):
     assert all(s.shape[0] == 0 for s in shards[active:])
 
 
+def _fetch_bound(shards, piece: int) -> int:
+    """The most the mesh sort may copy back for these shards: whole
+    pieces of whole 32-bit words, and a count a device."""
+    w = shards[0].shape[1]
+    return sum(-(-s.shape[0] // piece) * piece * -(-w // 4) * 4
+               for s in shards) + 4 * len(shards)
+
+
+#: rows per device of a four-device sort of 1793 to 2048 rows: a device's
+#: padded share is 512 rows, a piece 64, and a device has 1024 slots
+#: (2048 where ``capacity`` says so)
+FETCH_CASES = {
+    "no_row_on_a_device": ([700, 0, 650, 600], None),
+    "exactly_one_piece": ([64, 700, 600, 600], None),
+    "a_multiple_of_the_piece": ([192, 640, 576, 512], None),
+    "one_row_more": ([193, 641, 577, 513], None),
+    "every_slot_of_a_device": ([2048, 0, 0, 0], 512),
+}
+
+
+@pytest.mark.parametrize("width", [12, 27, 100])
+@pytest.mark.parametrize("case", list(FETCH_CASES))
+def test_mesh_fetch_brings_back_each_devices_rows_whatever_their_count(
+        case, width):
+    """Device d is sent exactly ``counts[d]`` rows (the first key byte
+    says which range): the shards are ``numpy.lexsort``'s rows byte for
+    byte, cut where the counts say, at a width that is and is not whole
+    32-bit words; and no more left the devices than whole pieces."""
+    from tpumr.parallel.device_sort import (device_partition_sort,
+                                            key_columns, piece_rows)
+    from tpumr.parallel.mesh import make_mesh
+    counts, capacity = FETCH_CASES[case]
+    n_dev, klen, n = 4, 10, sum(FETCH_CASES[case][0])
+    rng = np.random.default_rng([29, width, n])
+    records = rng.integers(0, 256, size=(n, width), dtype=np.uint8)
+    records[:, 0] = np.repeat(np.arange(n_dev) * 0x40, counts) \
+        + rng.integers(1, 0x40, size=n)
+    records = records[rng.permutation(n)]
+    splitters = np.zeros((n_dev - 1, klen), np.uint8)
+    splitters[:, 0] = [0x40, 0x80, 0xC0]
+    stats = {}
+    shards, overflow = device_partition_sort(
+        make_mesh(n_dev), records, klen, splitters, n_dev,
+        capacity=capacity, stats=stats)
+    assert overflow == 0 and stats["retries"] == 0
+    assert [s.shape for s in shards] == [(c, width) for c in counts]
+    assert all(s.dtype == np.uint8 and s.flags.c_contiguous for s in shards)
+    kcols = key_columns(records, klen)
+    order = np.lexsort(tuple(kcols[:, c] for c in range(2, -1, -1)))
+    assert (np.concatenate(shards) == records[order]).all()
+    per_dev = n_dev * (capacity or 256)
+    piece = piece_rows(512, per_dev)
+    assert piece == 64 and max(counts) <= per_dev
+    assert records.nbytes <= stats["bytes_back"] <= _fetch_bound(shards,
+                                                                  piece)
+
+
+@pytest.mark.parametrize("counts", [[0, 0, 0, 0], [150, 1, 64, 0],
+                                    [149, 150, 128, 65]],
+                         ids=["none", "last_piece_cut", "to_the_last_slot"])
+def test_fetch_of_a_shard_that_is_no_whole_number_of_pieces(counts):
+    """150 slots a device in pieces of 64: the third piece cannot start
+    at row 128, so it is cut from the shard's end and the host skips
+    what it has; a full device comes back to its last slot."""
+    from tpumr.parallel.device_sort import fetch_live_rows
+    from tpumr.parallel.mesh import make_mesh, shard_over
+    n_dev, per_dev, w, piece = 4, 150, 27, 64
+    mesh = make_mesh(n_dev)
+    rng = np.random.default_rng(sum(counts))
+    slots = rng.integers(0, 256, size=(n_dev * per_dev, w + 1),
+                         dtype=np.uint8)
+    live = (np.arange(n_dev * per_dev) % per_dev
+            < np.repeat(counts, per_dev))
+    shards, back, pieces = fetch_live_rows(
+        mesh, shard_over(mesh, slots), shard_over(mesh, live), w, piece)
+    for d, c in enumerate(counts):
+        assert (shards[d] == slots[d * per_dev:d * per_dev + c, :w]).all()
+    assert pieces == sum(-(-c // piece) for c in counts)
+    assert back == pieces * piece * 28 + 4 * n_dev
+
+
 def test_mesh_programs_compile_once_per_bucket_not_per_input():
     """Three inputs in a row on one mesh: other splitters and another row
-    count inside the same bucket add nothing to the jitted functions'
-    caches; a row count in the next bucket adds one entry to each."""
+    count inside the same bucket, so other counts per device, add nothing
+    to the jitted functions' caches; a row count in the next bucket adds
+    one entry to each."""
     from tpumr.parallel.device_sort import (bucket_rows,
                                             device_partition_sort,
-                                            make_dest_fn, make_sort_fn)
+                                            make_count_fn, make_dest_fn,
+                                            make_piece_fn, make_sort_fn,
+                                            piece_rows)
     from tpumr.parallel.mesh import make_mesh
     from tpumr.parallel.shuffle import make_shuffle
     n_dev, klen, num_ranges = 4, 10, 4
@@ -192,26 +285,43 @@ def test_mesh_programs_compile_once_per_bucket_not_per_input():
         assert got.shape[0] == n
         keys = [bytes(k) for k in got[:, :klen]]
         assert keys == sorted(keys)
-        return splitters
+        return splitters, [s.shape[0] for s in shards]
 
     def compiled():
-        """Executables held by the three jitted functions, the exchange's
-        at the capacity of each of the two buckets."""
+        """Executables held by the jitted functions; the exchange's and
+        the piece's at the sizes of each of the two buckets."""
+        per_bucket = [(rows // n_dev, 2 * (rows // n_dev) // 4)
+                      for rows in (5120, 6144)]
         return [make_dest_fn(mesh, klen, 1, 4, "data")._cache_size(),
                 make_sort_fn(mesh, klen, "data")._cache_size()] + [
-            make_shuffle(mesh, 2 * (rows // n_dev) // 4, "data",
+            make_shuffle(mesh, capacity, "data",
                          with_keys=False)._cache_size()
-            for rows in (5120, 6144)]
+            for _, capacity in per_bucket] + [
+            make_piece_fn(mesh, width, piece_rows(local, n_dev * capacity),
+                          "data")._cache_size()
+            for local, capacity in per_bucket]
+
+    def counted():
+        # the count's shape has no row width in it: other tests share it
+        return make_count_fn(mesh, "data")._cache_size()
 
     before = compiled()
-    a = one(first)
-    after_first = compiled()
-    assert [x - y for x, y in zip(after_first, before)] == [1, 1, 1, 0]
-    b = one(second)
+    a, a_counts = one(first)
+    after_first, counted_first = compiled(), counted()
+    assert [x - y for x, y in zip(after_first, before)] \
+        == [1, 1, 1, 0, 1, 0]
+    b, b_counts = one(second)
     assert not (a == b).all()               # other splitters, other rows
+    # other counts a device, and another number of pieces for one of them
+    assert a_counts != b_counts
+    assert [-(-c // 160) for c in a_counts] != [-(-c // 160)
+                                                for c in b_counts]
     assert compiled() == after_first        # and nothing compiled
+    assert counted() == counted_first
     one(third)
-    assert [x - y for x, y in zip(compiled(), after_first)] == [1, 1, 0, 1]
+    assert [x - y for x, y in zip(compiled(), after_first)] \
+        == [1, 1, 0, 1, 0, 1]
+    assert counted() <= counted_first + 1
 
 
 def test_skewed_input_retries_then_gives_up_with_the_retries_counted():

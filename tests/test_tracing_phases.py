@@ -159,6 +159,8 @@ def test_the_mesh_device_call_has_a_child_span_per_step(sorted_job):
     put, _dest, exchange, _sort, get = (k["attributes"] for k in kids)
     assert put["bytes"] == device["attributes"]["bytes_in"]
     assert get["bytes"] == device["attributes"]["bytes_out"]
+    # every live row came back, from the four devices that hold a range
+    assert get["live_rows"] == ROWS and get["pieces"] >= RANGES
     assert exchange["attempt"] == 0 and exchange["overflow"] == 0
     # 6000 rows on eight devices: 768 a device, so 2 x 768 / 4 ranges...
     pad = counters.value(BackendCounter.GROUP,
@@ -170,6 +172,24 @@ def test_the_mesh_device_call_has_a_child_span_per_step(sorted_job):
                           BackendCounter.TPU_SHUFFLE_DEVICES) == 8
     assert counters.value(BackendCounter.GROUP,
                           BackendCounter.TPU_SHUFFLE_RETRIES) == 0
+
+
+def test_the_mesh_sort_copies_back_the_rows_and_not_the_slots(sorted_job):
+    """``TPU_SHUFFLE_BYTES_BACK`` is what ``dshuffle:get`` says left the
+    devices: the job's rows at least, and at most whole pieces of them
+    (96 rows: an eighth of a device's 768) and a count a device; the
+    slots the exchange reserved would be three times that."""
+    spans, counters = sorted_job["spans"], sorted_job["counters"]
+    get, = (s["attributes"] for s in spans if s["name"] == "dshuffle:get")
+    back = counters.value(BackendCounter.GROUP,
+                          BackendCounter.TPU_SHUFFLE_BYTES_BACK)
+    moved = counters.value(BackendCounter.GROUP,
+                           BackendCounter.TPU_SHUFFLE_BYTES)
+    assert back == get["bytes"]
+    assert get["pieces"] <= ROWS // 96 + RANGES
+    assert moved <= back <= get["pieces"] * 96 * 100 + 4 * 8
+    slots = 8 * 8 * (2 * 768 // RANGES) * 101
+    assert back < 0.4 * slots
 
 
 def test_every_finished_attempt_has_one_task_done_after_its_launch(

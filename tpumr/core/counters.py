@@ -73,10 +73,13 @@ class BackendCounter:
     SHUFFLE_HOST_FALLBACKS = "SHUFFLE_HOST_FALLBACKS"
     #: what the gang reduce's device call did: the mesh size the exchange
     #: and sort ran over (1: the one-device argsort), the overflow
-    #: retries of the exchange, the rows its shape bucket added
+    #: retries of the exchange, the rows its shape bucket added, the
+    #: bytes the mesh sort copied back from the devices (over
+    #: TPU_SHUFFLE_BYTES: how much more than the job's rows)
     TPU_SHUFFLE_DEVICES = "TPU_SHUFFLE_DEVICES"
     TPU_SHUFFLE_RETRIES = "TPU_SHUFFLE_RETRIES"
     TPU_SHUFFLE_PAD_ROWS = "TPU_SHUFFLE_PAD_ROWS"
+    TPU_SHUFFLE_BYTES_BACK = "TPU_SHUFFLE_BYTES_BACK"
     #: maps whose dense output the gang reduce read from the disk of its
     #: own tracker, not through the RPC of the tracker that serves it
     TPU_SHUFFLE_LOCAL_MAPS = "TPU_SHUFFLE_LOCAL_MAPS"

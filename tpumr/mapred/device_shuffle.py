@@ -396,6 +396,9 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
         reporter.incr_counter(BackendCounter.GROUP,
                               BackendCounter.TPU_SHUFFLE_PAD_ROWS,
                               stats.get("pad_rows", 0))
+        reporter.incr_counter(BackendCounter.GROUP,
+                              BackendCounter.TPU_SHUFFLE_BYTES_BACK,
+                              stats.get("bytes_back", 0))
         if shards is not None:  # count only records the device actually moved
             reporter.incr_counter(BackendCounter.GROUP,
                                   BackendCounter.TPU_SHUFFLE_DEVICES,

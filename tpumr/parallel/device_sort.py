@@ -12,11 +12,19 @@ becomes three XLA programs over a mesh:
    moves every record to the device that owns its range;
 3. ``sort_local_shards`` — each device lexsorts what it received.
 
+What comes back is the rows the job has, not the slots the exchange
+reserved (twice the balanced load): the sort leaves each device's live
+rows first, a small program counts them, and only that prefix is fetched,
+in pieces of a fixed row count, each piece leaving its device as flat
+little-endian 32-bit words and landing once, in its place in the shard
+the caller gets (``fetch_live_rows``).
+
 The mesh path compiles once per SHAPE BUCKET, never per job: the sampled
 splitters are a runtime argument of ``compute_dest``'s program, and row
 counts are padded up to ``bucket_rows`` (each device's share rounded up to
-4, 5, 6 or 7 x 2^k), from which the exchange's capacity and the sort's
-shape follow.
+4, 5, 6 or 7 x 2^k), from which the exchange's capacity, the sort's shape
+and the fetched piece's size follow; where a piece starts is a runtime
+argument of its program.
 
 Keys are fixed-width byte strings (the device-sortable case called out in
 SURVEY.md §7: terasort's 10-byte keys); they are packed into big-endian
@@ -158,6 +166,99 @@ def make_sort_fn(mesh: Mesh, klen: int, axis_name: str = "data"):
     return jax.jit(_sort)
 
 
+@functools.lru_cache(maxsize=32)
+def make_count_fn(mesh: Mesh, axis_name: str = "data"):
+    """Jitted SPMD count of each device's live rows (``make_sort_fn``'s
+    mask) → ``[n_dev]`` int32: the integers that decide what is fetched."""
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axis_name),
+             out_specs=P(axis_name))
+    def _count(live):
+        return jnp.sum(live, dtype=jnp.int32)[None]
+
+    return jax.jit(_count)
+
+
+def piece_rows(local: int, per_dev: int) -> int:
+    """Rows a fetched piece holds, from the bucket alone: an eighth of a
+    device's share of the padded rows (``local``, 4 to 7 x 2^k), at least
+    64, at most the ``per_dev`` slots a device has. A device with a
+    balanced load comes back in eight pieces or nine, under an eighth of
+    ``local`` fetched beyond its count."""
+    return min(max(64, local // 8), per_dev)
+
+
+@functools.lru_cache(maxsize=32)
+def make_piece_fn(mesh: Mesh, w: int, piece: int, axis_name: str = "data"):
+    """Jitted SPMD map ``(sorted rows, start)`` → ``piece`` rows of every
+    device's shard from row ``start`` on (a RUNTIME argument: one
+    executable a bucket serves every piece of every job), the validity
+    byte dropped, as flat 32-bit words: byte ``4i + k`` of a row, padded
+    with zeros to whole words, is bits ``8k`` and up of its word ``i``,
+    which is the order a little-endian host reads them back in."""
+    words = -(-w // 4)
+
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(axis_name), P()),
+             out_specs=P(axis_name))
+    def _piece(records, start):
+        rows = jax.lax.dynamic_slice_in_dim(records, start, piece)[:, :w]
+        rows = jnp.pad(rows, ((0, 0), (0, 4 * words - w)))
+        b = [rows[:, k::4].astype(jnp.uint32) for k in range(4)]
+        return (b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)).reshape(-1)
+
+    return jax.jit(_piece)
+
+
+#: piece programs dispatched, and their copies to the host started, beyond
+#: the piece being landed: the next pieces travel while this one is copied
+PIECES_IN_FLIGHT = 2
+
+
+def fetch_live_rows(mesh: Mesh, sorted_recs, live, w: int, piece: int,
+                    axis_name: str = "data"):
+    """The live prefix of every device's sorted shard → ``(shards, bytes,
+    pieces)``: ``n_dev`` ``[count_d, w]`` uint8 arrays, the bytes that
+    left the devices for them, the pieces fetched. Device d's shard comes
+    in ``ceil(count_d / piece)`` pieces and a device without rows sends
+    none; all devices' copies of a round are started before the first is
+    read, and each piece is copied once, into its place."""
+    n_dev = mesh.shape[axis_name]
+    per_dev = sorted_recs.shape[0] // n_dev
+    counts = np.asarray(make_count_fn(mesh, axis_name)(live))
+    fn = make_piece_fn(mesh, w, piece, axis_name)
+    shards = [np.empty((int(c), w), np.uint8) for c in counts]
+    rounds = -(-int(counts.max()) // piece)
+    bytes_back, pieces, flight = int(counts.nbytes), 0, []
+
+    def launch(r: int) -> None:
+        # the last piece of a full shard may not start at r * piece: it
+        # is cut from the shard's end, and the host skips what it has
+        lo = min(r * piece, per_dev - piece)
+        out = fn(sorted_recs, np.int32(lo))
+        parts = {}
+        for s in out.addressable_shards:
+            d = (s.index[0].start or 0) // s.data.shape[0]
+            if counts[d] > r * piece:
+                s.data.copy_to_host_async()
+                parts[d] = s.data
+        flight.append((r, r * piece - lo, parts))
+
+    for r in range(min(PIECES_IN_FLIGHT, rounds)):
+        launch(r)
+    while flight:
+        r, skip, parts = flight.pop(0)
+        if r + PIECES_IN_FLIGHT < rounds:
+            launch(r + PIECES_IN_FLIGHT)
+        for d, part in parts.items():
+            got = np.asarray(part)
+            bytes_back += int(got.nbytes)
+            pieces += 1
+            take = min(piece, int(counts[d]) - r * piece)
+            rows = got.view(np.uint8).reshape(piece, -1)
+            shards[d][r * piece:r * piece + take] = \
+                rows[skip:skip + take, :w]
+    return shards, bytes_back, pieces
+
+
 @functools.lru_cache(maxsize=8)
 def _argsort_keys(ncols: int):
     """Jitted stable argsort of [n, ncols] uint32 key columns (ascending
@@ -188,18 +289,23 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
     evenly over the devices and padded to ``bucket_rows(N, n_dev)``; a
     trailing validity byte distinguishes real rows from padding. The
     exchange's per-(src, dst) ``capacity`` (twice the bucket's balanced
-    load unless given) and the sort's shape follow from the bucket, and
-    the splitters are a runtime argument, so a second input in the same
-    bucket compiles nothing. An overflow is retried with doubled capacity
-    (a second and third bucket of the exchange and the sort).
+    load unless given), the sort's shape and the size of a fetched piece
+    follow from the bucket, and the splitters and a piece's start are
+    runtime arguments, so a second input in the same bucket compiles
+    nothing, whatever each device's count. An overflow is retried with
+    doubled capacity (a second and third bucket of the exchange, the sort
+    and the fetch). Of the ``n_dev x capacity`` slots a device then holds
+    only its live rows come back (``fetch_live_rows``), without their
+    validity byte, in pieces that land in the returned shard directly.
 
     Returns ``(shards, total_capacity_overflowed)`` where ``shards`` is a
     list of ``n_dev`` numpy arrays (device d's received rows, key-sorted,
     padding removed) or ``None`` when every retry overflowed (caller falls
     back to the host path — the reference's disk-spill role,
     ReduceTask.java:1080 ShuffleRamManager budget semantics). The mesh
-    branch notes ``pad_rows`` (what the bucket added) and ``retries``
-    (overflow retries made) in ``stats``. ``key_words`` hands over
+    branch notes ``pad_rows`` (what the bucket added), ``retries``
+    (overflow retries made) and, with a result, ``bytes_back`` (what was
+    copied from the devices) in ``stats``. ``key_words`` hands over
     ``key_columns(records, klen)`` where the caller has made it already:
     the one-device branch then sends those and computes none; the mesh
     branch, whose devices make their own from the rows, has no use for
@@ -209,10 +315,12 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
     ``dshuffle:pack`` (host: what goes to the device is laid out),
     ``dshuffle:device`` (from the first dispatch until the host holds the
     result: copy in, the programs, copy out) and ``dshuffle:gather``
-    (host: rows into their final order). On a mesh ``dshuffle:device``
-    has a child per step, each closed when its result is ready:
+    (host: rows into their final order; on a mesh they arrive in it, and
+    the span only says how many). On a mesh ``dshuffle:device`` has a
+    child per step, each closed when its result is ready:
     ``dshuffle:put``, ``dshuffle:dest``, ``dshuffle:exchange`` (one per
-    attempt), ``dshuffle:sort``, ``dshuffle:get``.
+    attempt), ``dshuffle:sort``, ``dshuffle:get`` (from the count to the
+    last piece landed: ``bytes``, ``live_rows``, ``pieces``).
     """
     from tpumr.parallel.mesh import shard_over
     from tpumr.parallel.shuffle import shuffle_dense
@@ -319,23 +427,20 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
             sorted_recs, live = _ready(sp, make_sort_fn(
                 mesh, klen, axis_name)(res.values, res.valid))
         with tracing.span("dshuffle:get") as sp:
-            host_recs = np.asarray(sorted_recs)
-            host_live = np.asarray(live)
-            bytes_out = int(host_recs.nbytes + host_live.nbytes)
+            # the sort put the live rows first: unfilled slots and
+            # padding stay on the devices, the validity byte too
+            shards, bytes_out, pieces = fetch_live_rows(
+                mesh, sorted_recs, live, w,
+                piece_rows(local, n_dev * capacity), axis_name)
+            rows_out = sum(s.shape[0] for s in shards)
             if sp is not None:
-                sp.set(bytes=bytes_out)
+                sp.set(bytes=bytes_out, live_rows=rows_out, pieces=pieces)
         if dev_sp is not None:
             dev_sp.set(bytes_out=bytes_out)
-    with tracing.span("dshuffle:gather") as sp:
-        per_dev = host_recs.shape[0] // n_dev
-        shards = []
-        for d in range(n_dev):
-            lo = d * per_dev
-            # the sort put the live rows first: unfilled slots and
-            # padding are cut off, the validity byte dropped
-            cnt = int(np.count_nonzero(host_live[lo:lo + per_dev]))
-            shards.append(np.ascontiguousarray(host_recs[lo:lo + cnt, :w]))
-        if sp is not None:
-            sp.set(rows=sum(s.shape[0] for s in shards),
-                   bytes=sum(int(s.nbytes) for s in shards))
+        if stats is not None:
+            stats["bytes_back"] = bytes_out
+    # the pieces landed in the shards themselves, so nothing is left to
+    # move: the span stays for those who read the phases by name
+    with tracing.span("dshuffle:gather", rows=rows_out, bytes=rows_out * w):
+        pass
     return shards, overflowed
