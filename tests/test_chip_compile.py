@@ -100,6 +100,24 @@ def test_kmeans_xla_step_keeps_the_narrow_layout(one_chip, record_property):
     assert unpadded <= mem.argument_size_in_bytes <= 1.05 * unpadded
 
 
+def test_kmeans_xla_step_at_sift_widths_keeps_no_rows_by_k_temporary(
+        one_chip, record_property):
+    """The same program at one split of the SIFT cell, (500,000, 128) x
+    (1024, 128): its arguments are the split's 256 MB, and the distance
+    matrix, the argmin and the one-hot are fused into the two dots, so
+    nothing rows-by-k (2 GB in float32) is kept."""
+    from tpumr.ops.kmeans import _assign_and_partials_jax
+    n, d, k = 500_000, 128, 1024
+    _c, mem, secs = _compile(
+        _assign_and_partials_jax,
+        _shape((n, d), np.float32, one_chip),
+        _shape((k, d), np.float32, one_chip))
+    _record(record_property, mem, secs)
+    unpadded = n * d * 4 + k * d * 4
+    assert unpadded <= mem.argument_size_in_bytes <= 1.05 * unpadded
+    assert mem.temp_size_in_bytes < n * k * 4
+
+
 @pytest.mark.parametrize("n,d,k", [(KM_ROWS, 16, 16), (1 << 18, 128, 1024)],
                          ids=["d16-k16", "d128-k1024"])
 def test_pallas_assign_lowers_to_a_mosaic_kernel(one_chip, record_property,

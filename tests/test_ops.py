@@ -40,6 +40,44 @@ def test_kmeans_assign_jax_matches_numpy():
                                        rtol=1e-4)
 
 
+@pytest.mark.parametrize("impl", ["device", "numpy"])
+def test_kmeans_assign_at_sift_widths_matches_a_float32_reference(impl):
+    """d = 128, k = 1024 on seeded whole-number points 0..255 (SIFT's
+    values), against plain ``jax.numpy`` in float32 at the highest matmul
+    precision: the device kernel's assignments, sums and counts, and the
+    CPU slot's numpy twin's sums and counts."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpumr.ops.kmeans import assign_and_partials_numpy
+    rng = np.random.default_rng(128_1024)
+    centres = rng.exponential(32.0, (2048, 128))
+    pts = np.clip(np.rint(centres[rng.integers(0, 2048, 4096)]
+                          + 12.0 * rng.standard_normal((4096, 128))),
+                  0, 255).astype(np.float32)
+    cents = pts[:1024] + np.float32(0.25)    # off the points: no exact tie
+    with jax.default_matmul_precision("highest"):
+        x, c = jnp.asarray(pts), jnp.asarray(cents)
+        d2 = (jnp.sum(x * x, axis=1, keepdims=True) - 2.0 * (x @ c.T)
+              + jnp.sum(c * c, axis=1)[None, :])
+        want = np.asarray(jnp.argmin(d2, axis=1))
+        margin = np.sort(np.asarray(d2), axis=1)
+    # the data decides every row by far more than float32 rounds away
+    assert (margin[:, 1] - margin[:, 0]).min() > 1.0
+    want_counts = np.bincount(want, minlength=1024)
+    want_sums = np.zeros((1024, 128), np.float64)
+    np.add.at(want_sums, want, pts.astype(np.float64))
+    assert len(np.unique(want)) > 900       # the clusters are in use
+    if impl == "device":
+        assign, sums, counts = assign_and_partials(pts, cents)
+        np.testing.assert_array_equal(np.asarray(assign), want)
+    else:
+        sums, counts = assign_and_partials_numpy(pts, cents, chunk=1000)
+    np.testing.assert_array_equal(np.asarray(counts), want_counts)
+    # whole numbers under 2^24: the sums are exact in float32
+    np.testing.assert_array_equal(np.asarray(sums, np.float64), want_sums)
+
+
 def test_kmeans_pallas_interpret_matches_numpy():
     rng = np.random.default_rng(1)
     pts = rng.normal(size=(100, 3)).astype(np.float32)

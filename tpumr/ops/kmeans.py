@@ -367,8 +367,16 @@ class KMeansAssignKernel(KernelMapper):
         """Two-phase protocol: dispatch the assign+partials program and
         hand the [k,d] sums / [k] counts back as device arrays — the
         runner fetches a whole window of tasks in one roundtrip."""
+        from tpumr.core import tracing
         centroids = _device_centroids(conf)
         use_pallas = conf.get_boolean("tpumr.kmeans.use.pallas", False)
+        ctx = tracing.current()
+        if ctx is not None and ctx[1].name == "tpu:execute":
+            # the runner's span around this call: its shape, and which of
+            # the two implementations ran it
+            ctx[1].set(rows=int(batch.values.shape[0]),
+                       d=int(centroids.shape[1]), k=int(centroids.shape[0]),
+                       impl="pallas" if use_pallas else "xla")
         _assign, sums, counts = assign_and_partials(batch.values, centroids,
                                                     use_pallas=use_pallas)
         return (sums, counts)
