@@ -274,22 +274,22 @@ class TestJobTpuQuarantine:
 
 
 class TestSchedulerQuarantineInteraction:
-    def test_optional_scheduling_deadlock_broken_by_quarantine(self):
-        """The regression this PR exists for: a quarantined job under
-        optional scheduling used to keep a zero CPU budget while the TPU
+    def test_cpu_share_deadlock_broken_by_quarantine(self):
+        """The regression this PR exists for: a quarantined job whose
+        estimate gave it a zero CPU budget used to keep it while the TPU
         pass skipped it — pending maps no pass could ever assign."""
         from test_scheduler import (finish_map, make_job, make_scheduler,
                                     tracker_status)
-        job = make_job(n_maps=8, optional=True)
+        job = make_job(n_maps=8)
         sched = make_scheduler([job])
-        # profile both backends so optional scheduling's starvation rule
-        # is live (TPU 10x faster; pending < accel * capacity)
+        # both costs known, so the rule is live (TPU 10x faster; the
+        # pending maps are fewer turns than one CPU map)
         t = job.obtain_new_map_task("h", run_on_tpu=True, tpu_device_id=0)
         finish_map(job, t, runtime=0.1, on_tpu=True)
         c = job.obtain_new_map_task("h", run_on_tpu=False)
         finish_map(job, c, runtime=1.0, on_tpu=False)
-        # starvation active: the CPU pass assigns nothing (only the TPU
-        # pass places work)
+        # no CPU share: the CPU pass assigns nothing (only the TPU pass
+        # places work)
         before = sched.assign_tasks(tracker_status(cpu=3, tpu=1,
                                                    reduce=0))
         assert before and all(x.run_on_tpu for x in before)

@@ -31,14 +31,11 @@ class FakeManager:
         return {"cpu": 3 * self._n, "tpu": 1 * self._n, "reduce": 2 * self._n}
 
 
-def make_job(n_maps=8, n_reduces=1, kernel=True, optional=False, job_num=1,
-             hosts=None):
+def make_job(n_maps=8, n_reduces=1, kernel=True, job_num=1, hosts=None):
     conf = {"mapred.reduce.tasks": n_reduces,
             "mapred.reduce.slowstart.completed.maps": 0.0}
     if kernel:
         conf["tpumr.map.kernel"] = "kmeans-assign"
-    if optional:
-        conf["mapred.jobtracker.map.optionalscheduling"] = True
     splits = [{"locations": (hosts or [])} for _ in range(n_maps)]
     return JobInProgress(JobID("test", job_num), conf, splits)
 
@@ -106,64 +103,60 @@ def test_no_free_device_no_tpu_task():
     assert all(not t.run_on_tpu for t in tasks)
 
 
-def test_optional_scheduling_starves_cpu_when_load_fits_tpu():
-    """The Shirahata rule (:290-291): with optionalscheduling and
-    pending_load < accel × tpu_capacity × n_trackers, skip the CPU pass."""
-    job = make_job(n_maps=20, optional=True)
-    # profile: CPU maps take 10s, TPU maps 1s → accel = 10
-    for on_tpu, runtime in [(False, 10.0), (True, 1.0)]:
+def profile(job, cpu_s, tpu_s):
+    """One finished map a backend: what the estimate stands on until a
+    slot has turned (the TPU map's runtime then stands for its turn)."""
+    for on_tpu, runtime in [(False, cpu_s), (True, tpu_s)]:
         t = job.obtain_new_map_task("host0", run_on_tpu=on_tpu,
                                     tpu_device_id=0 if on_tpu else -1)
         finish_map(job, t, runtime, on_tpu)
+
+
+def cpu_maps(tasks):
+    return [t for t in tasks if t.is_map and not t.run_on_tpu]
+
+
+def test_rule_starves_cpu_when_chip_ends_the_job_sooner():
+    """The paper's claim (:290-291, give the CPU slots nothing once the
+    accelerator is far ahead), by the one rule: 17 pending maps are 9
+    turns of two TPU slots at 1 s, sooner than ONE CPU map at 10 s."""
+    job = make_job(n_maps=20)
+    profile(job, cpu_s=10.0, tpu_s=1.0)
     assert job.acceleration_factor() == 10.0
 
     sched = make_scheduler([job], n_trackers=2)
-    # pending = 18 < 10 × 1 × 2 = 20 → CPU starved
     tasks = sched.assign_tasks(tracker_status())
     assert [t.run_on_tpu for t in tasks if t.is_map] == [True]
 
-    # without profile data (fresh job) CPU is NOT starved
-    fresh = make_job(n_maps=20, optional=True, job_num=2)
+    # with no estimate at all (a first job, first beat): the full share
+    fresh = make_job(n_maps=20, job_num=2)
     sched2 = make_scheduler([fresh], n_trackers=2)
-    tasks2 = sched2.assign_tasks(tracker_status())
-    assert len([t for t in tasks2 if t.is_map and not t.run_on_tpu]) == 3
+    assert len(cpu_maps(sched2.assign_tasks(tracker_status()))) == 3
 
 
-def test_optional_scheduling_keeps_cpu_under_heavy_load():
-    job = make_job(n_maps=500, optional=True)
-    for on_tpu, runtime in [(False, 10.0), (True, 1.0)]:
-        t = job.obtain_new_map_task("host0", run_on_tpu=on_tpu,
-                                    tpu_device_id=0 if on_tpu else -1)
-        finish_map(job, t, runtime, on_tpu)
+def test_rule_keeps_cpu_under_heavy_load():
+    job = make_job(n_maps=500)
+    profile(job, cpu_s=10.0, tpu_s=1.0)
     sched = make_scheduler([job], n_trackers=2)
-    # pending 498 >= 10 × 1 × 2 → CPU pass runs
-    tasks = sched.assign_tasks(tracker_status())
-    assert len([t for t in tasks if t.is_map and not t.run_on_tpu]) == 3
+    # 497 pending are 249 s of two TPU slots: every CPU wave shortens it
+    assert len(cpu_maps(sched.assign_tasks(tracker_status()))) == 3
 
 
-def test_minimize_mode_puts_everything_on_tpu_when_faster():
-    """The implemented f(x,y) minimization (reference's commented-out
-    :181-219): 8 pending maps, TPU 10× faster, 1 TPU slot → optimum is
-    x=0 CPU tasks (8×1s on TPU beats any CPU share at 10s each)."""
+def test_rule_puts_everything_on_tpu_when_faster():
+    """The f(x,y) minimization (reference's commented-out :181-219): 7
+    pending maps, TPU 10× faster, 1 TPU slot → optimum is x=0 CPU tasks
+    (7×1s on TPU beats any CPU share at 10s each)."""
     job = make_job(n_maps=10)
-    for on_tpu, runtime in [(False, 10.0), (True, 1.0)]:
-        t = job.obtain_new_map_task("host0", run_on_tpu=on_tpu,
-                                    tpu_device_id=0 if on_tpu else -1)
-        finish_map(job, t, runtime, on_tpu)
-    sched = make_scheduler([job], **{"tpumr.scheduler.mode": "minimize"})
+    profile(job, cpu_s=10.0, tpu_s=1.0)
+    sched = make_scheduler([job])
     tasks = sched.assign_tasks(tracker_status())
     assert [t.run_on_tpu for t in tasks if t.is_map] == [True]
 
     # inverse profile: CPU faster → CPU pass fills all slots
     job2 = make_job(n_maps=10, job_num=2)
-    for on_tpu, runtime in [(False, 1.0), (True, 10.0)]:
-        t = job2.obtain_new_map_task("host0", run_on_tpu=on_tpu,
-                                     tpu_device_id=0 if on_tpu else -1)
-        finish_map(job2, t, runtime, on_tpu)
-    sched2 = make_scheduler([job2], **{"tpumr.scheduler.mode": "minimize"})
-    tasks2 = sched2.assign_tasks(tracker_status())
-    cpu_maps = [t for t in tasks2 if t.is_map and not t.run_on_tpu]
-    assert len(cpu_maps) == 3
+    profile(job2, cpu_s=1.0, tpu_s=10.0)
+    sched2 = make_scheduler([job2])
+    assert len(cpu_maps(sched2.assign_tasks(tracker_status()))) == 3
 
 
 def test_locality_preference():
@@ -265,38 +258,46 @@ def test_lost_tracker_requeues_attempt_it_never_reported(is_map):
         assert tip.state == "running" and not tip.attempts
 
 
-def test_per_job_minimize_mode_override():
-    """A job may opt into the f(x,y) minimizer via its own conf while the
-    cluster default stays shirahata (the bench's convergence round uses
-    exactly this seam)."""
-    job = make_job(n_maps=10)
-    job.conf["tpumr.scheduler.mode"] = "minimize"
-    for on_tpu, runtime in [(False, 10.0), (True, 1.0)]:
-        t = job.obtain_new_map_task("host0", run_on_tpu=on_tpu,
-                                    tpu_device_id=0 if on_tpu else -1)
-        finish_map(job, t, runtime, on_tpu)
-    sched = make_scheduler([job])          # cluster mode: shirahata
+def test_rule_adapts_per_job_to_what_it_measures():
+    """One cluster, one rule, no key: two jobs in one queue get the CPU
+    share their OWN measured costs give them (a factor of 10 starves,
+    a factor of 1.5 over many maps does not)."""
+    far = make_job(n_maps=10)
+    profile(far, cpu_s=10.0, tpu_s=1.0)
+    near = make_job(n_maps=40, job_num=2)
+    profile(near, cpu_s=1.5, tpu_s=1.0)
+    sched = make_scheduler([far, near])
     tasks = sched.assign_tasks(tracker_status())
-    # optimum at 10x accel, 1 TPU slot: zero CPU share — only TPU maps
-    assert [t.run_on_tpu for t in tasks if t.is_map] == [True]
+    by_job = {str(j.job_id): [t for t in tasks if t.is_map
+                              and t.attempt_id.task.job == j.job_id]
+              for j in (far, near)}
+    # the TPU slot went to the head of the queue; its CPU share is 0
+    assert [t.run_on_tpu for t in by_job[str(far.job_id)]] == [True]
+    # the job behind it holds no chip: the rule's TPU side would be a
+    # promise nobody keeps, so the free CPU slots are its to use
+    assert len(cpu_maps(by_job[str(near.job_id)])) == 3
+    counters = far.counters
+    from tpumr.core.counters import JobCounter
+    assert counters.value(JobCounter.GROUP,
+                          JobCounter.CPU_MAPS_WITHHELD) == 1
+    assert near.counters.value(JobCounter.GROUP,
+                               JobCounter.CPU_MAPS_WITHHELD) == 0
 
-    # the same cluster WITHOUT the job override fills both pools
-    plain = make_job(n_maps=10, job_num=2)
-    for on_tpu, runtime in [(False, 10.0), (True, 1.0)]:
-        t = plain.obtain_new_map_task("host0", run_on_tpu=on_tpu,
-                                      tpu_device_id=0 if on_tpu else -1)
-        finish_map(plain, t, runtime, on_tpu)
-    sched2 = make_scheduler([plain])
-    tasks2 = sched2.assign_tasks(tracker_status())
-    assert len([t for t in tasks2 if t.is_map and not t.run_on_tpu]) == 3
+    # alone, with a chip of its own, the near job still gets CPU maps:
+    # 37 pending at 1 s a turn are longer than waves of 1.5 s
+    near2 = make_job(n_maps=40, job_num=3)
+    profile(near2, cpu_s=1.5, tpu_s=1.0)
+    sched2 = make_scheduler([near2])
+    assert len(cpu_maps(sched2.assign_tasks(tracker_status()))) == 3
 
 
 def test_within_job_convergence_timeline():
     """The convergence clause end-to-end at the scheduler level: a many-
-    map job starts with no profile (both pools fill); once per-backend
-    means exist and pending drops below accel x tpuCapacity x trackers,
-    the CPU pass stops and the TAIL of the job is all-TPU."""
-    job = make_job(n_maps=24, optional=True)
+    map job starts with no estimate (both pools fill); once both costs
+    are known and the pending maps are fewer turns of the chip than a
+    CPU map takes, the CPU pass stops and the TAIL of the job is
+    all-TPU."""
+    job = make_job(n_maps=24)
     sched = make_scheduler([job], n_trackers=2)
     placements = []
     for _hb in range(100):
@@ -319,8 +320,8 @@ def test_within_job_convergence_timeline():
         if not b:
             break
         tail += 1
-    # accel=10, capacity 1x2 -> starvation from pending<20: nearly the
-    # whole job after the first profiled wave goes TPU
+    # accel=10, two TPU slots -> no CPU share from pending<=20: nearly
+    # the whole job after the first profiled wave goes TPU
     assert tail >= 10, (placements, tail)
 
 

@@ -147,9 +147,6 @@ _ENTRIES: "tuple[ConfKey, ...]" = (
         "runner."),
     _K('mapred.job.tracker.http.port', 'int', -1,
         "JobTracker status HTTP port (-1 = auto)."),
-    _K('mapred.jobtracker.map.optionalscheduling', 'bool', False,
-        "Starve the CPU map pool when remaining maps fit the "
-        "accelerator capacity (Shirahata convergence rule)."),
     _K('mapred.jobtracker.restart.recover', 'bool', False,
         "Replay completed work from the history log on master restart."),
     _K('mapred.jobtracker.restart.recovery.grace.ms', 'int', 3000,
@@ -660,9 +657,6 @@ _ENTRIES: "tuple[ConfKey, ...]" = (
     _K('tpumr.scheduler.affinity.defer.passes', 'int', 3,
         "Heartbeats a job's TPU assignment may be deferred waiting for "
         "a tag-warm tracker before placing cold (0 = never defer)."),
-    _K('tpumr.scheduler.mode', 'str', 'shirahata',
-        "'shirahata' slot split or 'minimize' (the f(x,y) makespan "
-        "search)."),
     _K('tpumr.scenario.class', 'str', None,
         "Traffic class tag on a submitted job (scenario lab): keys the "
         "per-class latency percentiles and SLO verdicts."),

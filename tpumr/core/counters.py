@@ -99,6 +99,12 @@ class JobCounter:
     #: reaper failed for progress silence (failure_class=timeout)
     TPU_DEMOTIONS = "TPU_DEMOTIONS"
     TASKS_REAPED_TIMEOUT = "TASKS_REAPED_TIMEOUT"
+    #: the hybrid scheduler's estimate at work (mapred/map_cost.py):
+    #: asks at which a free CPU slot got no map of the job because the
+    #: chip ends it sooner, and running CPU maps done over on an idle
+    #: chip
+    CPU_MAPS_WITHHELD = "CPU_MAPS_WITHHELD"
+    TPU_TWINS_OF_CPU_MAPS = "TPU_TWINS_OF_CPU_MAPS"
     GROUP = "tpumr.JobCounter"
 
 

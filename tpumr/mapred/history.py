@@ -417,6 +417,7 @@ def job_metrics_rollup(jip: Any) -> dict:
     the task-time split from those same raw samples (NOT the scheduler's
     profile sums, which deliberately unwind on TPU quarantine)."""
     from tpumr.metrics.histogram import exact_percentiles
+    cpu_cost, t_tpu = jip.map_costs()
     with jip.lock:
         map_rts = list(jip.map_runtimes)
         reduce_rts = list(jip.reduce_runtimes)
@@ -448,6 +449,16 @@ def job_metrics_rollup(jip: Any) -> dict:
             "tpu_fraction_of_map_time":
                 tpu_s / map_task_s if map_task_s > 0 else 0.0,
         },
+        # the scheduler's estimate as the job ended (map_cost.py): what
+        # it took a CPU map and a TPU slot's turn to cost (the turn
+        # alone, and beside a running CPU map of the job), where the CPU
+        # number came from (job | running | carried | none), and the
+        # ratio of the first two; "observed" is finished maps' means
+        # alone
+        "t_cpu_estimate_s": cpu_cost.seconds,
+        "t_tpu_turn_s": t_tpu.alone,
+        "t_tpu_turn_beside_cpu_s": t_tpu.beside,
+        "estimate_from": cpu_cost.source,
         "acceleration_factor_profiled": jip.acceleration_factor(),
         "acceleration_factor_observed": observed_accel,
         "finished_tpu_maps": len(tpu),

@@ -32,6 +32,9 @@ from tpumr.mapred.ids import JobID, TaskAttemptID, TaskID
 from tpumr.mapred.task import (Task, TaskPhase, TaskReport, TaskState,
                                TaskStatus)
 from tpumr.core import confkeys
+from tpumr.mapred.map_cost import (TWIN_TURNS, CarriedCost, CpuCost,
+                                   MapCostEstimate, TurnCost,
+                                   map_cost_key)
 from tpumr.metrics.locks import RANK_JOB, InstrumentedRLock
 
 
@@ -319,6 +322,14 @@ class JobInProgress:
                                               "tpumr.profile.ewma")
         self._cpu_ewma = 0.0
         self._tpu_ewma = 0.0
+        #: what a CPU map and a TPU slot's turn cost in this job, from
+        #: running and killed attempts and the job before as well as
+        #: from finished maps (map_cost.py): what the scheduler's CPU
+        #: share and the twin on an idle chip decide by
+        self.map_cost = MapCostEstimate()
+        #: what the master carries the estimate under from job to job;
+        #: None for a job with no device kernel
+        self.map_cost_key = map_cost_key(self.conf, splits)
         # completion events for reduce fetchers (≈ TaskCompletionEvents).
         # APPEND-ONLY: consumers read incrementally by cursor, so a
         # withdrawn map output is marked status=OBSOLETE in place AND
@@ -496,16 +507,56 @@ class JobInProgress:
             if self.finished_tpu_maps else 0.0
 
     def acceleration_factor(self) -> float:
-        """cpuMean / tpuMean (JobQueueTaskScheduler.java:175-178); 1.0 until
-        both backends have profile data — and again after a job-level TPU
-        quarantine (the unwound sums must not resurrect via in-flight
-        TPU completions trickling in post-quarantine)."""
+        """What a CPU map costs over what a turn of a TPU slot costs, by
+        the job's estimate (≈ cpuMean / tpuMean,
+        JobQueueTaskScheduler.java:175-178, which knows nothing until a
+        map of each kind has finished); 1.0 while either is unknown —
+        and again after a job-level TPU quarantine (the unwound sums
+        must not resurrect via in-flight TPU completions trickling in
+        post-quarantine)."""
         if self.tpu_disabled:
             return 1.0
-        cpu, tpu = self.cpu_map_mean_time(), self.tpu_map_mean_time()
-        if cpu > 0 and tpu > 0:
-            return cpu / tpu
+        cpu, t_tpu = self.map_costs()
+        if cpu.seconds > 0 and t_tpu.alone > 0:
+            return cpu.seconds / t_tpu.alone
         return 1.0
+
+    def map_costs(self) -> "tuple[CpuCost, TurnCost]":
+        """The estimate now: what a CPU map costs (and where the number
+        is from) and what one turn of a TPU slot costs, alone and beside
+        a CPU map of the job, in seconds; 0.0 where nothing says."""
+        with self.lock:
+            return (self.map_cost.t_cpu(time.monotonic(),
+                                        self.finished_cpu_maps,
+                                        self.cpu_map_mean_time()),
+                    self.map_cost.t_tpu(self.tpu_map_mean_time()))
+
+    def tpu_serving(self) -> bool:
+        """Is a TPU attempt of this job running: is a chip serving it,
+        and not another job ahead of it in the queue."""
+        return self.map_cost.tpu_serving()
+
+    def adopt_carried_cost(self, carried: "CarriedCost | None") -> None:
+        """Start from what the job before with this job's
+        ``map_cost_key`` ended with (the master, at submit)."""
+        with self.lock:
+            self.map_cost.carried = carried
+
+    def cost_to_carry(self) -> "CarriedCost | None":
+        with self.lock:
+            if self.tpu_disabled:
+                return None
+            return self.map_cost.to_carry(
+                time.monotonic(), self.finished_cpu_maps,
+                self.cpu_map_mean_time(), self.tpu_map_mean_time())
+
+    def note_cpu_maps_withheld(self) -> None:
+        """One ask at which a free CPU slot got no map of this job
+        because the estimate says the chip ends it sooner."""
+        from tpumr.core.counters import JobCounter
+        with self.lock:
+            self.counters.incr(JobCounter.GROUP,
+                               JobCounter.CPU_MAPS_WITHHELD)
 
     def map_progress(self) -> float:
         if not self.maps:
@@ -662,10 +713,15 @@ class JobInProgress:
 
     def obtain_new_map_task(self, host: str, run_on_tpu: bool,
                             tpu_device_id: int = -1,
-                            rack: "str | None" = None) -> Task | None:
+                            rack: "str | None" = None,
+                            tracker: str = "") -> Task | None:
         """Locality-preferring map assignment ≈ obtainNewNodeLocalMapTask →
         obtainNewNonLocalMapTask (selection path of
-        JobQueueTaskScheduler.java:306-317)."""
+        JobQueueTaskScheduler.java:306-317). ``tracker`` names the
+        asking tracker: with the device id it is the TPU slot whose
+        turns the cost estimate times (``host`` where it is not
+        given)."""
+        slot = (tracker or host, tpu_device_id)
         with self.lock:
             if self.state != JobState.RUNNING:
                 return None
@@ -674,12 +730,21 @@ class JobInProgress:
                 return None  # recovery grace: re-joining trackers first
             if run_on_tpu and self.tpu_disabled:
                 return None  # job-level accelerator quarantine
-            # demoted TIPs never land on TPU again; the CPU pass sees all
+            # demoted TIPs never land on TPU again; the CPU pass sees
+            # all, those first that can run nowhere else (the floor of
+            # CPU slots the scheduler keeps for them is theirs)
             eligible = (self._pending_maps - self._cpu_only_maps
-                        if run_on_tpu else self._pending_maps)
+                        if run_on_tpu else
+                        (self._pending_maps & self._cpu_only_maps)
+                        or self._pending_maps)
+            if run_on_tpu and not eligible:
+                # a free device and no map it may take: a CPU map the
+                # chip would end sooner is done over on it
+                twin = self._obtain_tpu_twin(slot)
+                if twin is not None:
+                    return twin
             if not self._pending_maps:
-                return self._obtain_speculative_map(host, run_on_tpu,
-                                                    tpu_device_id)
+                return self._obtain_speculative_map(run_on_tpu, slot)
             if not eligible:
                 return None  # pending work exists but none TPU-eligible
             # tiers: node-local → rack-local → any (≈ obtainNewNodeLocal /
@@ -705,13 +770,84 @@ class JobInProgress:
             # stamp placement on the report ≈ JobTracker.java:3414-3433
             tip.report.run_on_tpu = run_on_tpu
             tip.report.tpu_device_id = tpu_device_id
+            self._note_map_launch(attempt, run_on_tpu, slot,
+                                  still_pending=len(eligible) > 1)
             return Task(attempt, partition=idx, num_reduces=self.num_reduces,
                         split=tip.split, num_maps=len(self.maps),
                         run_on_tpu=run_on_tpu, tpu_device_id=tpu_device_id,
                         memory_mb=self.map_memory_mb())
 
-    def _obtain_speculative_map(self, host: str, run_on_tpu: bool,
-                                tpu_device_id: int) -> Task | None:
+    def _note_map_launch(self, attempt: TaskAttemptID, run_on_tpu: bool,
+                         slot: tuple, still_pending: bool) -> None:
+        """Tell the cost estimate of one map launch (caller holds the
+        lock). ``still_pending``: a map the device may take is left."""
+        now = time.monotonic()
+        if run_on_tpu:
+            self.map_cost.tpu_launched(str(attempt), slot, now,
+                                       still_pending)
+        else:
+            self.map_cost.cpu_launched(str(attempt), now)
+
+    def _launch_twin(self, tip: TaskInProgress, run_on_tpu: bool,
+                     slot: tuple) -> Task:
+        """A duplicate attempt of a running map; first completion wins
+        (the loser is killed by the master). Caller holds the lock."""
+        attempt = tip.new_attempt()
+        self.speculative_map_tasks += 1
+        self._note_spec_launch(attempt)
+        self._record_placement(run_on_tpu)
+        tip.report.run_on_tpu = run_on_tpu
+        tip.report.tpu_device_id = slot[1]
+        self._note_map_launch(attempt, run_on_tpu, slot,
+                              still_pending=False)
+        return Task(attempt, partition=tip.partition,
+                    num_reduces=self.num_reduces, split=tip.split,
+                    num_maps=len(self.maps), run_on_tpu=run_on_tpu,
+                    tpu_device_id=slot[1],
+                    memory_mb=self.map_memory_mb())
+
+    def _obtain_tpu_twin(self, slot: tuple) -> Task | None:
+        """The twin on an idle chip: a device of the asking tracker is
+        free and no map it may take is pending, so a RUNNING CPU map
+        (not CPU-pinned, not yet twinned) is done over on it as soon as
+        what the CPU attempt has left exceeds what the chip needs to do
+        it over, ``TWIN_TURNS`` turns as THIS job measured them. The
+        floor and lag factor of ``_obtain_speculative_map`` were written
+        to keep CPU attempts from twinning each other too early, not to
+        keep a chip that would end the map in a tenth of a second from
+        taking it; they keep governing every other speculation. The
+        speculation switch, the brownout hold and the cap hold here
+        too. Caller holds self.lock."""
+        cost = self.map_cost
+        if not cost.cpu_running or not self.speculative \
+                or self.speculation_hold or self.tpu_disabled \
+                or len(self._spec_attempts) >= self.speculative_cap:
+            return None
+        turn = cost.t_tpu_own(self.tpu_map_mean_time())
+        if turn <= 0:
+            return None
+        now = time.monotonic()
+        cpu, _ = self.map_costs()
+        worst, worst_left = None, TWIN_TURNS * turn
+        for aid, started in cost.cpu_running.items():
+            tip = self._tip_of_attempt(aid)
+            if tip is None or tip.state != "running" \
+                    or tip.next_attempt != 1 \
+                    or tip.partition in self._cpu_only_maps:
+                continue
+            left = self._tip_remaining_s(
+                tip, now, cpu.left_after(now - started))
+            if left > worst_left:
+                worst, worst_left = tip, left
+        if worst is None:
+            return None
+        from tpumr.core.counters import JobCounter
+        self.counters.incr(JobCounter.GROUP,
+                           JobCounter.TPU_TWINS_OF_CPU_MAPS)
+        return self._launch_twin(worst, True, slot)
+
+    def _obtain_speculative_map(self, run_on_tpu: bool,
+                                slot: tuple) -> Task | None:
         """Straggler mitigation ≈ JobInProgress.hasSpeculativeMap /
         speculativeMapTasks (JobInProgress.java:2777): when all maps are
         assigned but some lag, issue a duplicate attempt; first
@@ -774,17 +910,7 @@ class JobInProgress:
                     continue  # lagging, but not on the critical path
             elif elapsed <= max(floor, factor * mean):
                 continue
-            attempt = tip.new_attempt()
-            self.speculative_map_tasks += 1
-            self._note_spec_launch(attempt)
-            self._record_placement(run_on_tpu)
-            tip.report.run_on_tpu = run_on_tpu
-            tip.report.tpu_device_id = tpu_device_id
-            return Task(attempt, partition=tip.partition,
-                        num_reduces=self.num_reduces, split=tip.split,
-                        num_maps=len(self.maps), run_on_tpu=run_on_tpu,
-                        tpu_device_id=tpu_device_id,
-                        memory_mb=self.map_memory_mb())
+            return self._launch_twin(tip, run_on_tpu, slot)
         return None
 
     def should_kill_attempt(self, attempt_id: str) -> bool:
@@ -987,6 +1113,9 @@ class JobInProgress:
                 # that FAILED or SUCCEEDED on its own must not leak a
                 # stale entry for the life of the job)
                 self._fail_requested.discard(aid_s)
+                self.map_cost.attempt_ended(
+                    aid_s, time.monotonic(),
+                    killed=status.state == TaskState.KILLED)
             tip.attempts[str(status.attempt_id)] = status
             tip.report.progress = max(tip.report.progress, status.progress)
             if status.state == TaskState.RUNNING \
@@ -1184,6 +1313,7 @@ class JobInProgress:
             self.finished_tpu_maps = 0
             self._tpu_time_sum = 0.0
             self._tpu_ewma = 0.0
+            self.map_cost.forget_tpu()
             self._accel_events.append({
                 "kind": "job_tpu_quarantined",
                 "failed_tips": len(self._tpu_failed_tips),
@@ -1350,6 +1480,10 @@ class JobInProgress:
                                           "failed by operator (-fail-task)")
                     else:
                         st.state = TaskState.KILLED
+                    # (not as killed: when it stopped running nobody
+                    # knows, so its age proves nothing of a map's cost)
+                    self.map_cost.attempt_ended(aid, time.monotonic(),
+                                                killed=False)
                     self._on_failure(tip, st)
                 elif (tip.is_map and tip.state == "succeeded"
                       and tip.successful_attempt == aid

@@ -7,13 +7,17 @@ a direct equivalent exists (so its GPU keys map 1:1 to TPU keys):
 
 - ``mapred.tasktracker.map.cpu.tasks.maximum``  (TaskTracker.java:1427)
 - ``mapred.tasktracker.map.tpu.tasks.maximum``  (≈ ...map.gpu.tasks.maximum, :1429)
-- ``mapred.jobtracker.map.optionalscheduling``  (JobQueueTaskScheduler.java:78)
 - ``tpumr.map.kernel``                          (≈ hadoop.pipes.gpu.executable,
   Submitter.java:110 — here it names a registered Pallas kernel mapper
   instead of a CUDA binary)
 - ``mapred.map.runner.tpu.class``               (≈ mapred.map.runnner.gpu.class,
   JobConf.java:978 — the reference's getter/setter key typo is documented and
   intentionally NOT reproduced)
+
+The reference's optional-scheduling switch
+(JobQueueTaskScheduler.java:78) has no key here: how many of a hybrid
+job's maps the CPU slots get is decided by what the master measures a CPU
+map and a TPU slot's turn to cost (mapred/map_cost.py), not by a switch.
 """
 
 from __future__ import annotations
@@ -44,7 +48,6 @@ _REGISTRY_SEEDED = (
     "mapred.tasktracker.map.cpu.tasks.maximum",
     "mapred.tasktracker.map.tpu.tasks.maximum",
     "mapred.tasktracker.reduce.tasks.maximum",
-    "mapred.jobtracker.map.optionalscheduling",
     "mapred.reduce.slowstart.completed.maps",
     "mapred.speculative.execution",
     "mapred.job.shuffle.input.buffer.percent",
@@ -237,11 +240,6 @@ class JobConf(Configuration):
     def max_reduce_slots(self) -> int:
         return confkeys.get_int(
             self, "mapred.tasktracker.reduce.tasks.maximum")
-
-    @property
-    def optional_scheduling(self) -> bool:
-        return confkeys.get_boolean(
-            self, "mapred.jobtracker.map.optionalscheduling")
 
     # ------------------------------------------------------------ sort/spill
 

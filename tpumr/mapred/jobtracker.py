@@ -64,6 +64,7 @@ from tpumr.mapred.ids import JobID, TaskAttemptID
 from tpumr.mapred.jobconf import JobConf
 from tpumr.mapred.job_in_progress import (JobInProgress, JobState,
                                           normalize_priority)
+from tpumr.mapred.map_cost import CarriedCost
 from tpumr.mapred.scheduler import HybridQueueScheduler, TaskScheduler
 from tpumr.mapred.task import TaskState, TaskStatus
 from tpumr.utils.reflection import new_instance
@@ -247,6 +248,13 @@ class JobMaster:
         #: heartbeat-path lookups read it lock-free under the GIL;
         #: writers still serialize on the global lock
         self.jobs: dict[str, JobInProgress] = {}
+        #: what the last finished job of each ``map_cost_key`` learned a
+        #: CPU map and a TPU slot's turn cost, for the next job with the
+        #: key to start from (a loop's round 7 knows what round 6
+        #: learned). Lives and dies with the job table: in memory, one
+        #: entry a key, gone with a restart. Single-key get/set,
+        #: GIL-atomic, lock-free
+        self._map_costs: dict[tuple, CarriedCost] = {}
         from tpumr.mapred.tracker_registry import TrackerRegistry
         self.trackers = TrackerRegistry(
             confkeys.get_int(conf, "tpumr.tracker.registry.shards"))
@@ -1432,6 +1440,8 @@ class JobMaster:
             # jobs born while the master is shedding start with
             # speculation paused; released on step-down with the rest
             jip.speculation_hold = True
+        if jip.map_cost_key is not None:
+            jip.adopt_carried_cost(self._map_costs.get(jip.map_cost_key))
         if jip.traffic_class:
             self._mreg.incr(
                 f"class_jobs_submitted|class={jip.traffic_class}")
@@ -1850,6 +1860,11 @@ class JobMaster:
                     committer.abort_job()
         except Exception as e:  # noqa: BLE001
             jip.error = jip.error or f"job finalization failed: {e}"
+        if jip.map_cost_key is not None \
+                and jip.state == JobState.SUCCEEDED:
+            carried = jip.cost_to_carry()
+            if carried is not None:
+                self._map_costs[jip.map_cost_key] = carried
         try:
             self.history.job_finished(jip)
             self._mreg.incr(f"jobs_{jip.state.lower()}")

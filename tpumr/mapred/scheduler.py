@@ -5,30 +5,34 @@
 JobQueueTaskScheduler.java, 628 LoC — the Shirahata et al. hybrid
 scheduler, SURVEY.md §2.1). The algorithm is ported faithfully:
 
-- per-job mean CPU/TPU map runtimes → ``accelerationFactor = cpuMean/tpuMean``
-  (:127-178);
-- **optional scheduling** (:78, :290-291): when
-  ``mapred.jobtracker.map.optionalscheduling`` is on and the remaining map
-  load fits the accelerator capacity
-  (``pendingMapLoad < accelFactor × tpuCapacity × numTrackers``), the CPU
-  pass is SKIPPED — work converges onto the faster backend;
+- per-job CPU/TPU map costs → ``accelerationFactor = cpuMean/tpuMean``
+  (:127-178); here the job's ESTIMATE (map_cost.py), which also reads
+  running and killed attempts and the job before, so that it says
+  something before a map of each kind has finished;
+- **the CPU share of a hybrid job, one rule**: the reference's
+  commented-out load-split minimization ``f(x,y) =
+  max(⌈x/n_cpu⌉·t_cpu, ⌈y/n_tpu⌉·t_tpu)`` (:181-219), fed with the
+  estimate: a free CPU slot gets a map of a hybrid job only where that
+  shortens the job. It stands where the reference's optional scheduling
+  stood (:78, :290-291: skip the CPU pass once ``pendingMapLoad <
+  accelFactor × tpuCapacity × numTrackers``), which was off by default
+  and blind in a job whose CPU maps never finish. With no estimate (a
+  first job's first beat) it is the full share;
 - the TPU pass requires the job to have a device kernel (≈ the
   ``hadoop.pipes.gpu.executable`` gate :342-347) and assigns a concrete free
   device id per task (:355-361), consuming device availability locally
-  within the same heartbeat (:373-378);
-- at most ONE reduce task per heartbeat (:527-560);
-- the reference's commented-out load-split minimization ``f(x,y) =
-  max(⌈x/n_cpu⌉·t_cpu, ⌈y/n_tpu⌉·t_tpu)`` (:181-219) is implemented here as
-  a selectable mode (``tpumr.scheduler.mode = minimize``) instead of dead
-  code.
+  within the same heartbeat (:373-378); a free device with no pending
+  map to take twins a running CPU map the chip would end sooner
+  (job_in_progress.py ``_obtain_tpu_twin``);
+- at most ONE reduce task per heartbeat (:527-560).
 """
 
 from __future__ import annotations
 
-import math
 from typing import Any, Protocol
 
 from tpumr.core import confkeys
+from tpumr.mapred import map_cost
 from tpumr.mapred.job_in_progress import (JobInProgress, JobState,
                                           priority_rank)
 from tpumr.mapred.task import Task
@@ -282,16 +286,12 @@ class HybridQueueScheduler(TaskScheduler):
 
         assigned: list[Task] = []
 
-        cluster_mode = str(self.conf.get("tpumr.scheduler.mode",
-                                         "shirahata")) \
-            if self.conf else "shirahata"
-
         # ---- per-JOB CPU budgets (a starved hybrid job must not block CPU
         # slots for kernel-less jobs that can only ever run on CPU).
         # Computed LAZILY on first visit: the passes walk the job order
         # front-to-first-assignable, so a wide queue's tail — the common
         # case at fleet scale, where this ran per asking heartbeat —
-        # never pays the accel-profile/minimizer arithmetic.
+        # never pays the estimate's arithmetic.
         cpu_budget: dict[str, int] = {}
 
         def budget_of(job: JobInProgress) -> int:
@@ -302,32 +302,16 @@ class HybridQueueScheduler(TaskScheduler):
             b = free_cpu
             if job.has_kernel() and not job.tpu_disabled:
                 # (quarantined jobs keep the full budget: the TPU pass
-                # skips them entirely, so neither starvation mode may
-                # zero their CPU share — that combination would deadlock
-                # the job with pending maps no pass can assign)
-                accel = job.acceleration_factor()
-                # per-job override, same seam as optionalscheduling (a
-                # job may opt into the f(x,y) minimizer on a shirahata
-                # cluster)
-                mode = str(job.conf.get("tpumr.scheduler.mode",
-                                        cluster_mode))
-                if mode == "minimize":
-                    # the f(x,y) optimum may put everything on TPU —
-                    # demoted (CPU-pinned) TIPs still need a floor of
-                    # CPU slots
-                    b = max(
-                        self._minimize_cpu_share(job, free_cpu,
-                                                 max_tpu * n_trackers),
+                # skips them entirely, so the rule may not zero their
+                # CPU share — that combination would deadlock the job
+                # with pending maps no pass can assign.) The optimum
+                # may put everything on TPU — demoted (CPU-pinned) TIPs
+                # still need a floor of CPU slots
+                b = max(self._cpu_share(job, free_cpu,
+                                        max_tpu * n_trackers),
                         min(free_cpu, job.cpu_pinned_pending_count()))
-                elif (self._optional_scheduling(job)
-                        and job.cpu_pinned_pending_count() == 0
-                        and job.pending_map_count()
-                        < accel * max_tpu * n_trackers):
-                    # optional scheduling: starve THIS job's CPU share
-                    # so its remaining maps converge to the accelerator
-                    # (:290-327). CPU-pinned (demoted) TIPs lift the
-                    # starvation: they can only ever run on the CPU pass
-                    b = 0
+                if b < min(free_cpu, job.pending_map_count()):
+                    job.note_cpu_maps_withheld()
             cpu_budget[jid] = b
             return b
 
@@ -359,9 +343,10 @@ class HybridQueueScheduler(TaskScheduler):
                     # for its heartbeat (bounded by the defer budget)
                     continue
                 device = free_devices[0]
-                task = job.obtain_new_map_task(host, run_on_tpu=True,
-                                               tpu_device_id=device,
-                                               rack=tts.get("rack"))
+                task = job.obtain_new_map_task(
+                    host, run_on_tpu=True, tpu_device_id=device,
+                    rack=tts.get("rack"),
+                    tracker=str(tts.get("tracker_name") or ""))
                 if task is not None:
                     free_devices.pop(0)  # consume locally (:373-378)
                     break
@@ -414,37 +399,27 @@ class HybridQueueScheduler(TaskScheduler):
 
         return assigned
 
-    def _optional_scheduling(self, job: JobInProgress) -> bool:
-        return bool(job.conf.get("mapred.jobtracker.map.optionalscheduling",
-                                 False))
-
-    def _minimize_cpu_share(self, job: JobInProgress, n_cpu: int,
-                            n_tpu_total: int) -> int:
-        """Implemented form of the commented-out minimization
-        (JobQueueTaskScheduler.java:181-219): choose the CPU share x of the
-        pending maps minimizing
-        ``f(x, y) = max(⌈x/n_cpu⌉·t_cpu, ⌈y/n_tpu⌉·t_tpu)``; returns how
-        many CPU slots are worth filling this heartbeat (0 when the optimum
-        puts everything on TPU)."""
-        pending = job.pending_map_count()
-        t_cpu = job.cpu_map_mean_time()
-        t_tpu = job.tpu_map_mean_time()
-        if pending == 0 or t_cpu <= 0 or t_tpu <= 0 or n_tpu_total == 0:
-            return n_cpu  # no profile yet: behave like plain FIFO
-        best_x, best_f = 0, math.inf
-        for x in range(pending + 1):
-            y = pending - x
-            f = max(math.ceil(x / max(1, n_cpu)) * t_cpu,
-                    math.ceil(y / n_tpu_total) * t_tpu)
-            if f < best_f:
-                best_x, best_f = x, f
-        return min(n_cpu, best_x)
+    def _cpu_share(self, job: JobInProgress, n_cpu: int,
+                   n_tpu_total: int) -> int:
+        """How many of ``n_cpu`` free CPU slots are worth a map of this
+        hybrid job now: those that shorten it by the job's estimate
+        (``map_cost.cpu_share`` over its pending maps); 0 when the chip
+        ends them all sooner. The full share while no chip is serving
+        the job (none in the cluster, or another job ahead of it holds
+        them): the rule's TPU side would be a promise nobody keeps."""
+        if not job.tpu_serving():
+            return n_cpu
+        cpu, t_tpu = job.map_costs()
+        return map_cost.cpu_share(job.pending_map_count(), n_cpu,
+                                  n_tpu_total, cpu.seconds, t_tpu)
 
 
 class FifoScheduler(HybridQueueScheduler):
     """Plain FIFO: hybrid logic off — every map is a CPU map unless the
-    tracker has TPU slots and the job a kernel (no starvation, no
-    minimization). Mirrors stock JobQueueTaskScheduler behavior."""
+    tracker has TPU slots and the job a kernel (every free CPU slot gets
+    a map whatever the estimate). Mirrors stock JobQueueTaskScheduler
+    behavior."""
 
-    def _optional_scheduling(self, job: JobInProgress) -> bool:
-        return False
+    def _cpu_share(self, job: JobInProgress, n_cpu: int,
+                   n_tpu_total: int) -> int:
+        return n_cpu

@@ -238,9 +238,11 @@ class BackendPlacement(DiagnosticTest):
                 f"({stats.acceleration_factor:.2f}) says {fast} map "
                 f"slots are faster for this job: raise that pool's slot "
                 f"count (mapred.tasktracker.map."
-                f"{fast.lower()}.tasks.maximum) or enable "
-                f"mapred.jobtracker.map.optionalscheduling so the "
-                f"scheduler concentrates maps there.")
+                f"{fast.lower()}.tasks.maximum). The scheduler already "
+                f"gives the slower pool a map only where that shortens "
+                f"the job; the rollup's CPU_MAPS_WITHHELD and "
+                f"estimate_from say whether it knew the factor in time "
+                f"(a first job of its kind learns it as it runs).")
 
 
 class MapGranularity(DiagnosticTest):
