@@ -168,6 +168,38 @@ def test_one_device_argsort(one_chip, record_property):
     assert mem.output_size_in_bytes >= SORT_ROWS * 4
 
 
+@pytest.mark.parametrize("program", ["sort", "segment_sum", "piece"])
+def test_one_device_reduce_program(one_chip, record_property, program):
+    """What a one-chip tracker runs for a job whose reducer is a kernel
+    (the aggregation's 16-byte key: four key columns and the value's):
+    the sort (a loop of stable single-key passes that carry the
+    permutation, then the gather of the columns), the segment-sum kernel,
+    and a piece of its table as flat words. Columns are rows of the
+    array, so a column's words are not padded; the array is, from 5 to 8
+    rows."""
+    from tpumr.ops.segment_sum import segment_sum_program
+    from tpumr.parallel import device_sort
+    n, cols = SORT_ROWS, 5
+    words = _shape((cols, n), np.uint32, one_chip)
+    scalar = _shape((), np.int32, one_chip)
+    if program == "sort":
+        compiled, mem, secs = _compile(device_sort.sort_columns(cols - 1),
+                                       words)
+        assert n * cols * 4 <= mem.output_size_in_bytes <= n * 8 * 4
+        # the passes are a loop over one sort, not four sorts
+        assert compiled.as_text().count(" sort(") == 1
+    elif program == "segment_sum":
+        _c, mem, secs = _compile(segment_sum_program(cols - 1), words,
+                                 scalar)
+        assert n * cols * 4 <= mem.output_size_in_bytes <= n * 8 * 4 + 4096
+    else:
+        piece = max(64, n // 64)
+        _c, mem, secs = _compile(device_sort._piece_of_columns(piece),
+                                 words, scalar)
+        assert 0 <= mem.output_size_in_bytes - piece * cols * 4 < 4096
+    _record(record_property, mem, secs)
+
+
 @pytest.mark.parametrize("program", ["dest", "exchange", "sort"])
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_device_shuffle_program(meshes, record_property, n_dev, program):

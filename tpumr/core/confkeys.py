@@ -636,6 +636,10 @@ _ENTRIES: "tuple[ConfKey, ...]" = (
         "RandomWriter: min key size, bytes."),
     _K('tpumr.randomwriter.min.value', 'int', 0,
         "RandomWriter: min value size, bytes."),
+    _K('tpumr.reduce.kernel', 'str', None,
+        "Registered reduce kernel name (ops registry): the reducer of a "
+        "device-shuffled job, run on the device where the rows were "
+        "sorted."),
     _K('tpumr.rpc.client.backoff.ms', 'int', 200,
         "Base jittered backoff between RPC transport retries, ms."),
     _K('tpumr.rpc.client.retries', 'int', 1,

@@ -83,6 +83,17 @@ class BackendCounter:
     #: maps whose dense output the gang reduce read from the disk of its
     #: own tracker, not through the RPC of the tracker that serves it
     TPU_SHUFFLE_LOCAL_MAPS = "TPU_SHUFFLE_LOCAL_MAPS"
+    #: a gang reduce whose reducer is a kernel (tpumr.reduce.kernel):
+    #: the rows and groups the DEVICE reduced where it had sorted them,
+    #: the bytes of groups it copied back (near the output's size, not
+    #: the input's), whether that device was a real accelerator, and the
+    #: gang reduces that reduced on the host with the kernel's numpy twin
+    #: instead (an overflow, a host fallback, a mesh)
+    TPU_REDUCE_RECORDS = "TPU_REDUCE_RECORDS"
+    TPU_REDUCE_GROUPS = "TPU_REDUCE_GROUPS"
+    TPU_REDUCE_BYTES_BACK = "TPU_REDUCE_BYTES_BACK"
+    DEVICE_REDUCE_ON_ACCEL = "DEVICE_REDUCE_ON_ACCEL"
+    REDUCE_HOST_TWIN = "REDUCE_HOST_TWIN"
     GROUP = "tpumr.BackendCounter"
 
 

@@ -35,6 +35,7 @@ def _load_all() -> None:
     from tpumr.examples import sleep  # noqa: F401
     from tpumr.examples import sort  # noqa: F401
     from tpumr.examples import terasort  # noqa: F401
+    from tpumr.examples import uservisits  # noqa: F401
 
 
 def main(argv: list[str]) -> int:

@@ -92,6 +92,18 @@ def prepare_device_shuffle_job(conf: Any) -> None:
         raise ValueError("device shuffle does not support a grouping "
                          "comparator (secondary sort) — use the host "
                          "shuffle")
+    name = conf.get_reduce_kernel()
+    if name:
+        from tpumr.ops import get_reduce_kernel
+        kernel = get_reduce_kernel(name)
+        if conf.get_reducer_class() is not None:
+            raise ValueError(f"the job names the reduce kernel {name!r} "
+                             f"and a reducer class: one reducer a job")
+        if conf.get_int(VALUE_BYTES_KEY, 0) != kernel.value_bytes:
+            raise ValueError(
+                f"reduce kernel {name!r} takes {kernel.value_bytes}-byte "
+                f"values; {VALUE_BYTES_KEY} is "
+                f"{conf.get_int(VALUE_BYTES_KEY, 0)}")
     if not conf.get(RANGES_KEY):
         conf.set(RANGES_KEY, r)
     conf.set_num_reduce_tasks(1)
@@ -155,6 +167,14 @@ class DenseMapOutputBuffer:
                                    TaskCounter.MAP_OUTPUT_BYTES,
                                    n * (self.klen + self.vlen))
 
+    def collect_fixed_rows(self, rows: np.ndarray, klen: int) -> None:
+        """``OutputCollector``'s bulk lane: ``[n, klen + vlen]`` rows of a
+        map that makes its records in arrays."""
+        if klen != self.klen:
+            raise ValueError(f"device shuffle requires {self.klen}-byte "
+                             f"keys, got rows cut at {klen}")
+        self.collect_fixed_batch(rows[:, :klen], rows[:, klen:])
+
     def flush(self) -> tuple[str, dict]:
         path = os.path.join(self.local_dir, "file.dense")
         with open(path, "wb") as f:
@@ -192,12 +212,13 @@ DenseFetchFn = Callable[[int], tuple[np.ndarray, np.ndarray]]
 class RowLanding:
     """Where the copy phase puts what it fetched: the job's rows
     ``[n, klen + vlen]`` (key first) and, where asked for, their key
-    words ``[n, cols]`` (``key_columns``), each map's share written once
-    when that map arrives, in the order of arrival. How many rows the job
-    has is known only after the last map, so the buffers are sized from
-    the maps seen so far (their mean for every map to come, and an eighth
-    more) and grown, by a copy of what has landed, when a map does not
-    fit."""
+    words ``[n, cols]`` (``key_columns``) or, for a reduce kernel, their
+    key and value words ``[cols, n]`` (``reduce_words``), each map's share
+    written once when that map arrives, in the order of arrival. How many
+    rows the job has is known only after the last map, so the buffers are
+    sized from the maps seen so far (their mean for every map to come, and
+    an eighth more) and grown, by a copy of what has landed, when a map
+    does not fit."""
 
     def __init__(self, klen: int, vlen: int, num_maps: int) -> None:
         from tpumr.parallel.device_sort import num_key_columns
@@ -208,6 +229,9 @@ class RowLanding:
         self._n = 0
         self._words = np.empty((0, num_key_columns(klen)), np.uint32)
         self._n_words = 0
+        self._reduce_words = np.empty(
+            (num_key_columns(klen) + vlen // 4, 0), np.uint32)
+        self._n_reduce_words = 0
 
     def land_rows(self, keys: np.ndarray, values: np.ndarray) -> bool:
         """Append one map's rows; True where the buffer had to grow."""
@@ -233,6 +257,27 @@ class RowLanding:
                                    max(end, self._rows.shape[0]))
         self._words[self._n_words:end] = key_columns(keys, self.klen)
         self._n_words = end
+
+    def land_reduce_words(self) -> None:
+        """What a reduce kernel's device call sends up, for the rows that
+        have landed since the last call."""
+        from tpumr.parallel.device_sort import reduce_words
+        at, end = self._n_reduce_words, self._n
+        if end > self._reduce_words.shape[1]:
+            grown = np.empty((self._reduce_words.shape[0],
+                              max(end, self._rows.shape[0])), np.uint32)
+            grown[:, :at] = self._reduce_words[:, :at]
+            self._reduce_words = grown
+        self._reduce_words[:, at:end] = reduce_words(self._rows[at:end],
+                                                     self.klen)
+        self._n_reduce_words = end
+
+    @property
+    def reduce_words(self) -> "np.ndarray | None":
+        """The reduce kernel's words that have landed; None where none
+        were asked for."""
+        return self._reduce_words[:, :self._n_reduce_words] \
+            if self._n_reduce_words else None
 
     @property
     def rows(self) -> np.ndarray:
@@ -329,8 +374,13 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     ``dshuffle:device`` (on a mesh with a child per step: ``:put``,
     ``:dest``, ``:exchange``, ``:sort``, ``:get``) / ``dshuffle:gather``
     (``device_partition_sort``) or
-    ``dshuffle:host_sort`` (the fallback), ``dshuffle:write``. What the
-    parent does not spend in a child is its self time."""
+    ``dshuffle:host_sort`` (the fallback), ``dshuffle:write``. Where the
+    job's reducer is a kernel (``tpumr.reduce.kernel``) the device call
+    reduces where it sorted (``dshuffle:sort`` and ``dshuffle:reduce``
+    under ``dshuffle:device``) and groups are written, not rows; rows
+    sorted elsewhere are reduced by the kernel's numpy twin
+    (``dshuffle:reduce`` with ``host_twin``). What the parent does not
+    spend in a child is its self time."""
     with tracing.span("dshuffle") as ds:
         _device_reduce(conf, task, dense_fetch, reporter or Reporter(), ds)
 
@@ -352,6 +402,10 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     t0 = time.monotonic()
     landing = RowLanding(klen, vlen, task.num_maps)
     mesh = None
+    kernel = None
+    if conf.get_reduce_kernel():
+        from tpumr.ops import get_reduce_kernel
+        kernel = get_reduce_kernel(conf.get_reduce_kernel())
     for m in range(task.num_maps):
         k, v = dense_fetch(m)
         if k.shape[1] != klen or v.shape[1] != vlen:
@@ -367,10 +421,14 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
             if sp is not None:
                 sp.set(grown=grown)
         if mesh is not None and mesh.size == 1:
-            # the one-device sort sends the key words, not the rows
+            # the one-device sort sends the key words, not the rows; a
+            # reduce kernel's value column goes up beside them
             with tracing.span("dshuffle:pack", map_index=m,
                               rows=int(k.shape[0])):
-                landing.land_key_words(k)
+                if kernel is not None:
+                    landing.land_reduce_words()
+                else:
+                    landing.land_key_words(k)
     records = landing.rows
     n = records.shape[0]
     with tracing.span("dshuffle:assemble", rows=n,
@@ -383,13 +441,15 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     # ---- exchange + sort phase (device)
     shards = None
     overflow = 0
+    reduced_on_device = False
     if n > 0:
         from tpumr.parallel.device_sort import device_partition_sort
         capacity = conf.get_int(CAPACITY_KEY, 0) or None
         stats: dict = {}
         shards, overflow = device_partition_sort(
             mesh, records, klen, splitters, num_ranges, capacity=capacity,
-            stats=stats, key_words=landing.key_words)
+            stats=stats, key_words=landing.key_words, reduce=kernel,
+            reduce_words_made=landing.reduce_words)
         reporter.incr_counter(BackendCounter.GROUP,
                               BackendCounter.TPU_SHUFFLE_RETRIES,
                               stats.get("retries", 0))
@@ -408,9 +468,24 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
             reporter.incr_counter(BackendCounter.GROUP,
                                   BackendCounter.TPU_SHUFFLE_BYTES,
                                   int(records.nbytes))
-            if mesh.devices.flat[0].platform != "cpu":
+            on_accel = mesh.devices.flat[0].platform != "cpu"
+            if on_accel:
                 reporter.incr_counter(BackendCounter.GROUP,
                                       BackendCounter.DEVICE_SORT_ON_ACCEL)
+            if "reduced_groups" in stats:   # the shards hold groups
+                reduced_on_device = True
+                reporter.incr_counter(BackendCounter.GROUP,
+                                      BackendCounter.TPU_REDUCE_RECORDS, n)
+                reporter.incr_counter(BackendCounter.GROUP,
+                                      BackendCounter.TPU_REDUCE_GROUPS,
+                                      stats["reduced_groups"])
+                reporter.incr_counter(BackendCounter.GROUP,
+                                      BackendCounter.TPU_REDUCE_BYTES_BACK,
+                                      stats["reduce_bytes_back"])
+                if on_accel:
+                    reporter.incr_counter(
+                        BackendCounter.GROUP,
+                        BackendCounter.DEVICE_REDUCE_ON_ACCEL)
     host_fallback = shards is None
     if host_fallback:
         # host fallback: full numpy lexsort, then the same range split
@@ -430,6 +505,28 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
         shards = [all_sorted]
     else:
         n_dev = len(shards)
+    if kernel is not None:
+        # every counter of a kernel's reduce is in the rollup, 0 or not
+        for c in (BackendCounter.TPU_REDUCE_RECORDS,
+                  BackendCounter.TPU_REDUCE_GROUPS,
+                  BackendCounter.TPU_REDUCE_BYTES_BACK,
+                  BackendCounter.DEVICE_REDUCE_ON_ACCEL,
+                  BackendCounter.REDUCE_HOST_TWIN):
+            reporter.incr_counter(BackendCounter.GROUP, c, 0)
+        if not reduced_on_device:
+            # an overflow, a host fallback, a mesh: the sorted rows are
+            # on the host, and the kernel's numpy twin reduces them (a
+            # group never spans two shards: the exchange is by key range)
+            with tracing.span("dshuffle:reduce", rows=n, kernel=kernel.name,
+                              host_twin=True) as sp:
+                shards = [kernel.reduce_host(s, klen) for s in shards]
+                if sp is not None:
+                    sp.set(groups=sum(s.shape[0] for s in shards))
+            reporter.incr_counter(BackendCounter.GROUP,
+                                  BackendCounter.REDUCE_HOST_TWIN)
+        reporter.incr_counter(TaskCounter.FRAMEWORK_GROUP,
+                              TaskCounter.REDUCE_INPUT_GROUPS,
+                              sum(s.shape[0] for s in shards))
     if ds is not None:
         ds.set(rows=n, n_dev=n_dev, overflow=overflow,
                host_fallback=host_fallback)
@@ -438,10 +535,14 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
         f"device shuffle: {n} records over {n_dev} devices in "
         f"{time.monotonic() - t0:.3f}s (overflow retries seen: {overflow})")
 
-    # ---- reduce + write phase (host, range-ordered part files)
+    # ---- reduce + write phase (host, range-ordered part files). Three
+    # ways through it: the identity reducer writes the rows; a kernel's
+    # groups are rows already and are written the same way; any other
+    # reducer is called group by group
     reducer_cls = conf.get_reducer_class()
     from tpumr.mapred.api import IdentityReducer
-    identity = reducer_cls is None or reducer_cls is IdentityReducer
+    identity = kernel is not None or reducer_cls is None \
+        or reducer_cls is IdentityReducer
     committer = FileOutputCommitter(conf)
     wd = committer.setup_task(str(task.attempt_id))
     out_fmt = new_instance(conf.get_output_format(), conf)
@@ -513,18 +614,17 @@ def _reduce_rows(conf: Any, reducer_cls: type, rows: np.ndarray, klen: int,
         writer.write(k, v)
 
     collector = OutputCollector(emit)
+    # the group boundaries once, by comparing adjacent rows' key bytes
+    from tpumr.ops.segment_sum import group_starts
+    starts = np.flatnonzero(group_starts(rows[:, :klen])) if n else []
+    ends = list(starts[1:]) + [n]
     try:
-        i = 0
-        while i < n:
-            key = rows[i, :klen].tobytes()
-            j = i
-            while j < n and rows[j, :klen].tobytes() == key:
-                j += 1
+        for i, j in zip(starts, ends):
             reporter.incr_counter(TaskCounter.FRAMEWORK_GROUP,
                                   TaskCounter.REDUCE_INPUT_GROUPS)
             values = (rows[t, klen:].tobytes() for t in range(i, j))
-            reducer.reduce(key, values, collector, reporter)
-            i = j
+            reducer.reduce(rows[i, :klen].tobytes(), values, collector,
+                           reporter)
     finally:
         reducer.close()
 

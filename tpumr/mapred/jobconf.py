@@ -216,6 +216,15 @@ class JobConf(Configuration):
     def get_map_kernel(self) -> str | None:
         return self.get("tpumr.map.kernel")
 
+    def set_reduce_kernel(self, name: str) -> None:
+        """Name a registered reduce kernel (tpumr.ops registry) as the
+        job's reducer: behind the device shuffle it runs on the device
+        where the rows were sorted, and only its groups come back."""
+        self.set("tpumr.reduce.kernel", name)
+
+    def get_reduce_kernel(self) -> str | None:
+        return self.get("tpumr.reduce.kernel")
+
     def set_device_shuffle(self, key_bytes: int, value_bytes: int) -> None:
         """Opt this job into the device-shuffled reduce (ICI all_to_all +
         per-device sort — tpumr.mapred.device_shuffle): map outputs must be

@@ -311,7 +311,8 @@ def run_map_task(conf: Any, task: Task, local_dir: str,
             return out
     else:
         buffer = MapOutputBuffer(conf, task.num_reduces, local_dir, reporter)
-    run_mapper(OutputCollector(buffer.collect))
+    run_mapper(OutputCollector(
+        buffer.collect, getattr(buffer, "collect_fixed_rows", None)))
     out = buffer.flush()
     reporter.incr_counter(BackendCounter.GROUP, backend_tasks)
     reporter.incr_counter(BackendCounter.GROUP, backend_ms,

@@ -24,9 +24,11 @@ PARTITION_PATH_KEY = "total.order.partitioner.path"
 
 
 def sample_input(conf: Any, num_samples: int = 1000,
-                 max_splits: int = 10) -> list:
+                 max_splits: int = 10, key_of: Any = None) -> list:
     """Draw up to ``num_samples`` keys from the job's input (SplitSampler
-    semantics: evenly across the first ``max_splits`` splits)."""
+    semantics: evenly across the first ``max_splits`` splits). Where the
+    job's map makes its output key from the record, ``key_of(key,
+    value)`` makes the sample's the same way."""
     input_format = new_instance(conf.get_input_format(), conf)
     splits = input_format.get_splits(conf, conf.num_map_tasks_hint)
     splits = splits[:max_splits]
@@ -39,7 +41,7 @@ def sample_input(conf: Any, num_samples: int = 1000,
         for i, (key, _value) in enumerate(reader):
             if i >= per_split:
                 break
-            samples.append(key)
+            samples.append(key if key_of is None else key_of(key, _value))
     return samples
 
 

@@ -11,7 +11,9 @@ socket stream.
 Importing this package registers the built-in kernels.
 """
 
-from tpumr.ops.registry import KernelMapper, get_kernel, register_kernel, kernels
+from tpumr.ops.registry import (KernelMapper, ReduceKernel, get_kernel,
+                                get_reduce_kernel, kernels, register_kernel,
+                                register_reduce_kernel)
 
 # built-ins register on import
 import tpumr.ops.kmeans    # noqa: F401,E402
@@ -19,5 +21,7 @@ import tpumr.ops.matmul    # noqa: F401,E402
 import tpumr.ops.pi        # noqa: F401,E402
 import tpumr.ops.wordcount  # noqa: F401,E402
 import tpumr.ops.grep      # noqa: F401,E402
+import tpumr.ops.segment_sum  # noqa: F401,E402
 
-__all__ = ["KernelMapper", "get_kernel", "register_kernel", "kernels"]
+__all__ = ["KernelMapper", "ReduceKernel", "get_kernel", "get_reduce_kernel",
+           "kernels", "register_kernel", "register_reduce_kernel"]

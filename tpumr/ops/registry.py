@@ -1,10 +1,12 @@
-"""Kernel-mapper registry.
+"""Kernel registry: device mappers and device reducers.
 
 ≈ the role of DistributedCache executable slots in the reference
 (mapred/pipes/Submitter.java:349-379: CPU binary → cache[0], GPU binary →
 cache[1]): jobs name their accelerator mapper; the node runner resolves it at
 launch. Names are strings in job conf (``tpumr.map.kernel``) so submission
-stays wire-serializable.
+stays wire-serializable. A job whose reduce runs behind the device shuffle
+names its reducer the same way (``tpumr.reduce.kernel``,
+:class:`ReduceKernel`).
 """
 
 from __future__ import annotations
@@ -118,3 +120,55 @@ def get_kernel(name: str) -> KernelMapper:
 
 def kernels() -> list[str]:
     return sorted(_REGISTRY)
+
+
+class ReduceKernel:
+    """A whole-range device reducer behind the device shuffle.
+
+    Where a map kernel consumes a staged split, a reduce kernel consumes
+    the job's rows once the device has ORDERED them by key: the value
+    column goes up beside the key words, the kernel's program runs on the
+    device right after the sort, and only its output rows (one a group)
+    come back (``tpumr.parallel.device_sort.device_partition_sort``).
+    Input and output rows are fixed-width: the job's key bytes, then
+    ``value_bytes`` of value, the output's key being the group's.
+
+    ``device_program(key_cols)`` returns the jitted program ``(words,
+    n_live) -> (table, groups)``: ``words`` is ``[key_cols + value words,
+    n]`` uint32, one COLUMN a row of the array, ordered by the key
+    columns with the ``n_live`` real rows first; ``table`` holds the
+    output rows the same way, the live ones first, and ``groups`` is how
+    many those are. ``reduce_host(rows, klen)`` is its numpy twin
+    over key-sorted ``[n, klen + value_bytes]`` uint8 rows: what an
+    overflow, a host fallback or a mesh the kernel has no program for
+    reduces with, to the same rows.
+    """
+
+    #: registry name
+    name: str = ""
+    #: width of the value a map emits and of the value a group gets
+    value_bytes: int = 0
+
+    def device_program(self, key_cols: int) -> Callable:
+        raise NotImplementedError
+
+    def reduce_host(self, rows: Any, klen: int) -> Any:
+        raise NotImplementedError
+
+
+_REDUCE_REGISTRY: dict[str, ReduceKernel] = {}
+
+
+def register_reduce_kernel(kernel: ReduceKernel) -> ReduceKernel:
+    if not kernel.name:
+        raise ValueError("reduce kernel needs a name")
+    _REDUCE_REGISTRY[kernel.name] = kernel
+    return kernel
+
+
+def get_reduce_kernel(name: str) -> ReduceKernel:
+    try:
+        return _REDUCE_REGISTRY[name]
+    except KeyError:
+        raise KeyError(f"no reduce kernel {name!r}; registered: "
+                       f"{sorted(_REDUCE_REGISTRY)}") from None

@@ -271,6 +271,148 @@ def _argsort_keys(ncols: int):
     return _argsort
 
 
+@functools.lru_cache(maxsize=8)
+def sort_columns(key_cols: int):
+    """Jitted stable sort of ``[cols, n]`` uint32 words, one column a row
+    of the array, by their first ``key_cols`` columns (ascending
+    lexicographic, column 0 most significant); the other columns travel
+    with their keys. The order is made a key column at a time, least
+    significant first, in a loop whose body is ONE stable single-key sort
+    that carries the permutation: the sort's compile time grows with its
+    operands (three keys and an index compile for 74 s on the chip's host,
+    a key and a payload for 26 s, all five columns at once for over two
+    minutes), and the loop compiles its body once whatever the key's
+    width. The columns are gathered once, at the end."""
+    @jax.jit
+    def _sort_words(words):
+        def one_pass(i, perm):
+            column = jax.lax.dynamic_index_in_dim(
+                words, key_cols - 1 - i, 0, keepdims=False)
+            return jax.lax.sort((jnp.take(column, perm), perm), num_keys=1,
+                                is_stable=True)[1]
+
+        perm = jax.lax.fori_loop(
+            0, key_cols, one_pass,
+            jnp.arange(words.shape[1], dtype=jnp.int32))
+        return jnp.take(words, perm, axis=1)
+
+    return _sort_words
+
+
+@functools.lru_cache(maxsize=32)
+def _piece_of_columns(piece: int):
+    """Jitted ``(table [cols, n], start)`` → columns ``start`` to ``start
+    + piece`` as flat 32-bit words (``start`` a RUNTIME argument: one
+    executable a bucket serves every piece of every job)."""
+    @jax.jit
+    def _piece(table, start):
+        return jax.lax.dynamic_slice_in_dim(table, start, piece,
+                                            axis=1).reshape(-1)
+
+    return _piece
+
+
+def fetch_live_columns(table, live: int, piece: int):
+    """The first ``live`` columns of a device ``[cols, n]`` uint32 table →
+    ``(host [cols, live], bytes, pieces)``, in pieces of a fixed column
+    count as flat 32-bit words (``fetch_live_rows`` has why), each landed
+    once, the next ones travelling meanwhile."""
+    cols, n = table.shape
+    out = np.empty((cols, live), np.uint32)
+    fn = _piece_of_columns(piece)
+    rounds = -(-live // piece)
+    flight: list = []
+
+    def launch(r: int) -> None:
+        # the last piece of a full table is cut from its end
+        lo = min(r * piece, n - piece)
+        part = fn(table, np.int32(lo))
+        part.copy_to_host_async()
+        flight.append((r, r * piece - lo, part))
+
+    for r in range(min(PIECES_IN_FLIGHT, rounds)):
+        launch(r)
+    bytes_back = 0
+    while flight:
+        r, skip, part = flight.pop(0)
+        if r + PIECES_IN_FLIGHT < rounds:
+            launch(r + PIECES_IN_FLIGHT)
+        got = np.asarray(part).reshape(cols, piece)
+        bytes_back += int(got.nbytes)
+        take = min(piece, live - r * piece)
+        out[:, r * piece:r * piece + take] = got[:, skip:skip + take]
+    return out, bytes_back, rounds
+
+
+def reduce_words(records: np.ndarray, klen: int) -> np.ndarray:
+    """What a reduce kernel's device call sends up for ``[n, klen + v]``
+    uint8 rows (``v`` a whole number of words): ``[key columns + v / 4,
+    n]`` uint32, one column a row of the array, the key big-endian
+    (``key_columns``), the value's words as the host reads them."""
+    n, w = records.shape
+    kc = num_key_columns(klen)
+    out = np.empty((kc + (w - klen) // 4, n), np.uint32)
+    out[:kc] = key_columns(records, klen).T
+    out[kc:] = np.ascontiguousarray(records[:, klen:]).view("<u4").T
+    return out
+
+
+def rows_of_words(words: np.ndarray, klen: int) -> np.ndarray:
+    """``reduce_words`` undone: ``[cols, n]`` uint32 → ``[n, klen + v]``
+    uint8 rows."""
+    kc = num_key_columns(klen)
+    n = words.shape[1]
+    out = np.empty((n, klen + 4 * (words.shape[0] - kc)), np.uint8)
+    out[:, :klen] = np.ascontiguousarray(words[:kc].T).astype(">u4") \
+        .view(np.uint8).reshape(n, 4 * kc)[:, :klen]
+    out[:, klen:] = np.ascontiguousarray(words[kc:].T).astype("<u4") \
+        .view(np.uint8).reshape(n, -1)
+    return out
+
+
+def _sort_and_reduce(words: np.ndarray, klen: int, reduce,
+                     stats: "dict | None"):
+    """The one-device call of a job whose reducer is a kernel: the key
+    words AND the value column go up, sort and kernel run back to back on
+    the device, and only the groups come back, as the live prefix of the
+    kernel's table in flat 32-bit words. ``words`` is ``reduce_words`` of
+    the job's rows. Returns the groups' rows ``[groups, klen + v]``."""
+    kc = num_key_columns(klen)
+    cols, n0 = words.shape
+    with tracing.span("dshuffle:pack") as sp:
+        # padded to the bucket with all-FF keys and zero values: the sort
+        # is stable, so padding lands after the real rows even where a
+        # real key is all FF, and the kernel is told how many are real
+        n_pad = bucket_rows(n0, 1)
+        padded = np.empty((cols, n_pad), np.uint32)
+        padded[:, :n0] = words
+        padded[:kc, n0:] = 0xFFFFFFFF
+        padded[kc:, n0:] = 0
+        if sp is not None:
+            sp.set(n_pad=n_pad, bytes_in=int(padded.nbytes))
+    with tracing.span("dshuffle:device", devices=1, retries=0,
+                      bytes_in=int(padded.nbytes)) as dev_sp:
+        with tracing.span("dshuffle:sort") as sp:
+            ordered = _ready(sp, sort_columns(kc)(padded))
+        with tracing.span("dshuffle:reduce", rows=n0,
+                          kernel=reduce.name) as sp:
+            table, groups = reduce.device_program(kc)(ordered, np.int32(n0))
+            groups = int(groups)        # waits for the kernel
+            got, bytes_back, pieces = fetch_live_columns(
+                table, groups, max(64, n_pad // 64))
+            bytes_back += 4             # the count
+            if sp is not None:
+                sp.set(groups=groups, bytes_back=bytes_back, pieces=pieces)
+        if dev_sp is not None:
+            dev_sp.set(bytes_out=bytes_back)
+    if stats is not None:
+        stats.update(pad_rows=n_pad - n0, retries=0, reduced_groups=groups,
+                     reduce_bytes_back=bytes_back)
+    with tracing.span("dshuffle:gather", rows=groups,
+                      bytes=groups * (klen + 4 * (cols - kc))):
+        return rows_of_words(got, klen)
+
+
 def _ready(sp, out):
     """``out``, waited for where a span is open to time it: an untraced
     job dispatches the next program without waiting."""
@@ -283,7 +425,9 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
                           max_retries: int = 2,
                           axis_name: str = "data",
                           stats: "dict | None" = None,
-                          key_words: "np.ndarray | None" = None):
+                          key_words: "np.ndarray | None" = None,
+                          reduce=None,
+                          reduce_words_made: "np.ndarray | None" = None):
     """Full device path: records [N, w] uint8 (first ``klen`` bytes = the
     sort key) → per-device key-sorted rows. On a mesh ``records`` is dealt
     evenly over the devices and padded to ``bucket_rows(N, n_dev)``; a
@@ -310,6 +454,16 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
     the one-device branch then sends those and computes none; the mesh
     branch, whose devices make their own from the rows, has no use for
     them.
+
+    ``reduce`` names the job's reducer where it is a kernel
+    (``tpumr.ops.registry.ReduceKernel``). Both branches keep one
+    contract: with ``stats["reduced_groups"]`` set the shards hold the
+    kernel's OUTPUT rows (one a group, key-sorted), reduced on the device
+    where they were sorted; without it they hold the sorted rows, and the
+    caller reduces them with the kernel's numpy twin. The one-device
+    branch reduces (``_sort_and_reduce``; ``reduce_words_made`` hands
+    over ``reduce_words(records, klen)`` where the caller has made them);
+    the mesh branch has no kernel program yet and returns its rows.
 
     Under a traced task both branches record the same three spans:
     ``dshuffle:pack`` (host: what goes to the device is laid out),
@@ -338,6 +492,13 @@ def device_partition_sort(mesh: Mesh, records: np.ndarray, klen: int,
         # sorted rows down); the value payload never leaves the host.
         if n0 == 0:
             return [records.copy()], 0
+        if reduce is not None:
+            words = reduce_words(records, klen) \
+                if reduce_words_made is None else reduce_words_made
+            if words.shape[1] != n0:
+                raise ValueError(f"reduce words of {words.shape[1]} rows "
+                                 f"handed over with {n0} rows")
+            return [_sort_and_reduce(words, klen, reduce, stats)], 0
         if key_words is not None and key_words.shape[0] != n0:
             raise ValueError(f"key words of {key_words.shape[0]} rows "
                              f"handed over with {n0} rows")
