@@ -235,6 +235,150 @@ class TestAppendFixedRows:
         assert list(sf.Reader(b)) == [(bytes(r), b"") for r in rows]
 
 
+def _pinned_writer(stream, **kw):
+    """A Writer whose sync marker is 16 S's, so two files compare."""
+    import os as _os
+    from tpumr.io import sequencefile as sf
+    orig = _os.urandom
+    _os.urandom = lambda n: b"S" * n
+    try:
+        return sf.Writer(stream, **kw)
+    finally:
+        _os.urandom = orig
+
+
+def _chunk_rows(width, klen, block_records):
+    """Rows in one chunk of the bulk writer: the whole blocks that fit its
+    buffer (a block's slot is its frames behind 24 bytes and the count)."""
+    from tpumr.io import sequencefile as sf
+    from tpumr.io.writable import _vint_bytes
+    frame = sf._FixedFrame(klen, width - klen).size
+    slot = 24 + len(_vint_bytes(block_records)) + block_records * frame
+    return max(1, sf._BULK_CHUNK_BYTES // slot) * block_records
+
+
+#: (rows a bulk call, ...) in row counts or in chunks of the writer
+_N = {"none": [0], "one": [1], "a-block-less-one": [999],
+      "a-block": [1000], "a-block-and-one": [1001],
+      "a-chunk": ["chunk"], "a-chunk-and-one": ["chunk+1"],
+      "chunks-and-a-partial-block": ["3chunk+517"],
+      "two-calls-in-a-row": [1500, 2000],
+      "a-call-of-none-between": [700, 0, 1300]}
+
+
+@pytest.mark.parametrize("scalars", [False, True],
+                         ids=["bulk-alone", "scalar-appends-around"])
+@pytest.mark.parametrize("shape", [
+    (100, 10, 1000, "none"),   # the sort's rows: a block is 106 KB
+    (14, 10, 1000, "none"),
+    (14, 10, 7, "none"),       # a block under SYNC_INTERVAL: syncs now and then
+    (14, 10, 1, "none"),       # a record a block
+    (5, 3, 1000, "none"),
+    (10, 10, 1000, "none"),    # zero-width values
+    (20, 16, 1000, "none"),    # the aggregation's groups
+    (14, 10, 1000, "zlib"),    # a codec wants a block's bytes
+    (14, 10, 7, "zlib"),
+], ids=lambda s: "w%d-k%d-b%d-%s" % s)
+@pytest.mark.parametrize("calls", list(_N), ids=list(_N))
+def test_bulk_append_is_byte_identical_to_per_record_appends(
+        calls, shape, scalars, monkeypatch):
+    """``append_fixed_rows`` against n ``append(bytes, bytes)`` calls, a
+    bulk call starting and ending a block as it always has: for every n
+    around a block and around a chunk of the writer's buffer, every block
+    size, rows so narrow that blocks share a sync marker, a compressing
+    codec, scalar appends before and after, calls in a row."""
+    import io as _io
+    import numpy as np
+    from tpumr.io import sequencefile as sf
+    width, klen, per, codec = shape
+    # a chunk of a few blocks, so that several chunks are a few thousand
+    # rows whatever the block size
+    monkeypatch.setattr(sf, "_BULK_CHUNK_BYTES",
+                        4 * (24 + 3 + per * (width + 6)) + 11)
+    chunk = _chunk_rows(width, klen, per)
+    assert chunk == 4 * per
+    counts = [n if isinstance(n, int) else
+              {"chunk": chunk, "chunk+1": chunk + 1,
+               "3chunk+517": 3 * chunk + 517}[n] for n in _N[calls]]
+    rng = np.random.default_rng(sum(counts) + width)
+    bulk, plain = _io.BytesIO(), _io.BytesIO()
+    w1 = _pinned_writer(bulk, codec=codec, block_records=per)
+    w2 = _pinned_writer(plain, codec=codec, block_records=per)
+    if scalars:
+        for w in (w1, w2):
+            w.append(b"first", b"xx")
+            w.append("second", 2)
+    for n in counts:
+        rows = rng.integers(0, 256, size=(n, width), dtype=np.uint8)
+        w1.append_fixed_rows(rows, klen)
+        if n:
+            w2._flush_block()
+            for r in rows:
+                w2.append(bytes(r[:klen]), bytes(r[klen:]))
+            w2._flush_block()
+    if scalars:
+        for w in (w1, w2):
+            w.append(b"last", b"yy")
+    w1.close()
+    w2.close()
+    assert bulk.getvalue() == plain.getvalue()
+    assert w1._since_sync == w2._since_sync
+    bulk.seek(0)
+    assert sum(1 for _ in sf.Reader(bulk)) == sum(counts) + 3 * scalars
+
+
+def test_bulk_append_of_rows_that_are_a_strided_view():
+    """Rows that are columns of a wider array (no row-contiguous bytes to
+    reshape) are framed like their copy."""
+    import io as _io
+    import numpy as np
+    rng = np.random.default_rng(9)
+    wide = rng.integers(0, 256, size=(2300, 40), dtype=np.uint8)
+    outs = []
+    for rows in (wide[:, 5:19], np.ascontiguousarray(wide[:, 5:19])):
+        b = _io.BytesIO()
+        w = _pinned_writer(b)
+        w.append_fixed_rows(rows, 10)
+        w.close()
+        outs.append(b.getvalue())
+    assert outs[0] == outs[1]
+
+
+def test_bulk_appends_on_threads_each_write_their_own_file():
+    """Four writers at once, a file each, the interpreter switching threads
+    every few microseconds: each file is what its writer alone wrote."""
+    import io as _io
+    import sys
+    import threading
+    import numpy as np
+    rng = np.random.default_rng(11)
+    rows = [rng.integers(0, 256, size=(30_000 + i, 100), dtype=np.uint8)
+            for i in range(4)]
+
+    def write(r, out):
+        w = _pinned_writer(out)
+        w.append_fixed_rows(r, 10)
+        w.close()
+
+    alone = [_io.BytesIO() for _ in rows]
+    for r, out in zip(rows, alone):
+        write(r, out)
+    beside = [_io.BytesIO() for _ in rows]
+    threads = [threading.Thread(target=write, args=a)
+               for a in zip(rows, beside)]
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in threads)
+    assert [b.getvalue() for b in beside] == [a.getvalue() for a in alone]
+
+
 # ---------------------------------------------------------------- TFile
 
 

@@ -30,8 +30,8 @@ def sorted_job(tmp_path_factory):
     assert cli_main(["examples", "teragen", str(ROWS), "mem:///tph/gen",
                      "-m", str(MAPS)]) == 0
     master_conf = JobConf()
-    master_conf.set("tpumr.history.dir",
-                    str(tmp_path_factory.mktemp("tph-hist")))
+    hist = str(tmp_path_factory.mktemp("tph-hist"))
+    master_conf.set("tpumr.history.dir", hist)
     with MiniMRCluster(num_trackers=1, cpu_slots=2, tpu_slots=0,
                        conf=master_conf) as c:
         conf = make_terasort_conf("mem:///tph/gen", "mem:///tph/out", RANGES,
@@ -49,7 +49,8 @@ def sorted_job(tmp_path_factory):
                     or time.monotonic() > deadline:
                 break
             time.sleep(0.05)
-    return {"spans": spans, "counters": result.counters, "job_id": jid}
+    return {"spans": spans, "counters": result.counters, "job_id": jid,
+            "history": hist}
 
 
 def _children(spans, parent):
@@ -87,7 +88,7 @@ def test_the_phases_are_the_children_in_order_and_do_not_overlap(sorted_job):
     assert order == ["dshuffle:locate", "dshuffle:fetch",
                      "dshuffle:assemble"] * MAPS + [
         "dshuffle:assemble", "dshuffle:pack", "dshuffle:device",
-        "dshuffle:gather"] + ["dshuffle:write"] * RANGES
+        "dshuffle:gather", "dshuffle:write"]
     for a, b in zip(kids, kids[1:]):
         assert a["end"] <= b["start"] + 1e-6, (a["name"], b["name"])
     for k in kids:
@@ -133,10 +134,47 @@ def test_phase_rows_and_bytes_match_the_jobs_counters(sorted_job):
     assert device["bytes_out"] >= moved
     gather, = attrs("dshuffle:gather")
     assert gather["rows"] == rows_in and gather["bytes"] == moved
-    writes = attrs("dshuffle:write")
-    assert sorted(w["range"] for w in writes) == list(range(RANGES))
-    assert sum(w["rows"] for w in writes) == rows_out
-    assert sum(w["bytes"] for w in writes) == moved
+    write, = attrs("dshuffle:write")
+    assert write["ranges"] == RANGES
+    assert write["rows"] == rows_out and write["bytes"] == moved
+    written = attrs("dshuffle:range")
+    assert sorted(w["range"] for w in written) == list(range(RANGES))
+    assert sum(w["rows"] for w in written) == rows_out
+    assert sum(w["bytes"] for w in written) == moved
+
+
+def test_the_write_phase_is_one_span_with_a_span_a_range_under_it(
+        sorted_job):
+    """ONE ``dshuffle:write`` around the phase (so the benchmark's
+    ``_phase_s`` reads its wall time), and under it a ``dshuffle:range``
+    a range, opened in the worker that wrote it; the counter says how
+    many workers the phase had."""
+    import os
+    spans, counters = sorted_job["spans"], sorted_job["counters"]
+    top = next(s for s in spans if s["name"] == "dshuffle")
+    write, = (s for s in spans if s["name"] == "dshuffle:write")
+    assert write["parent_span_id"] == top["span_id"]
+    written = _children(spans, write)
+    assert [w["name"] for w in written] == ["dshuffle:range"] * RANGES
+    for w in written:
+        assert write["start"] - 1e-4 <= w["start"] <= w["end"] \
+            <= write["end"] + 1e-4
+        assert w["trace_id"] == sorted_job["job_id"]
+    # four ranges over eight devices: a range a device
+    assert sorted((w["attributes"]["range"], w["attributes"]["device"])
+                  for w in written) == [(r, r) for r in range(RANGES)]
+    writers = min(RANGES, os.cpu_count() or 1)
+    assert write["attributes"]["writers"] == writers
+    assert 0 <= write["attributes"]["cut_s"] < 0.1
+    assert counters.value(BackendCounter.GROUP,
+                          BackendCounter.TPU_SHUFFLE_WRITERS) == writers
+    # and the master's rollup of the job has it beside the mesh size
+    import json
+    with open(os.path.join(sorted_job["history"],
+                           f"metrics-{sorted_job['job_id']}.json")) as f:
+        rolled = json.load(f)["counters"][BackendCounter.GROUP]
+    assert rolled["TPU_SHUFFLE_WRITERS"] == writers
+    assert rolled["TPU_SHUFFLE_DEVICES"] == 8
 
 
 def test_the_mesh_device_call_has_a_child_span_per_step(sorted_job):

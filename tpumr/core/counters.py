@@ -83,6 +83,11 @@ class BackendCounter:
     #: maps whose dense output the gang reduce read from the disk of its
     #: own tracker, not through the RPC of the tracker that serves it
     TPU_SHUFFLE_LOCAL_MAPS = "TPU_SHUFFLE_LOCAL_MAPS"
+    #: the most range writers the gang reduce's write phase ran side by
+    #: side: the lesser of its ranges and the host's cores where the rows
+    #: are written as they are, 1 where a user's reducer is called (one
+    #: range after another, in the task's thread)
+    TPU_SHUFFLE_WRITERS = "TPU_SHUFFLE_WRITERS"
     #: a gang reduce whose reducer is a kernel (tpumr.reduce.kernel):
     #: the rows and groups the DEVICE reduced where it had sorted them,
     #: the bytes of groups it copied back (near the output's size, not

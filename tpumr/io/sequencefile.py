@@ -18,13 +18,52 @@ from io import BytesIO
 from typing import Any, BinaryIO, Iterator
 
 from tpumr.io.compress import get_codec
-from tpumr.io.writable import read_vint, write_vint, serialize, deserialize
+from tpumr.io.writable import (_vint_bytes, deserialize, read_vint, serialize,
+                               write_vint)
 
 MAGIC = b"TSEQ"
 VERSION = 1
 SYNC_SIZE = 16
 SYNC_INTERVAL = 100 * SYNC_SIZE  # bytes between syncs ≈ SequenceFile.SYNC_INTERVAL
 _SYNC_ESCAPE = 0xFFFFFFFF  # uint32 length sentinel preceding a sync marker
+#: bytes of blocks a bulk append frames before it hands them to the stream:
+#: small enough to stay in a core's cache between the copy in and the
+#: write, large enough that a write is not a call a block (sized on the
+#: chip's host: PERF.md section 6, PR 34)
+_BULK_CHUNK_BYTES = 4 << 20
+
+
+class _FixedFrame:
+    """One record of a ``klen``-byte key and a ``vlen``-byte value as
+    ``append(bytes, bytes)`` frames it: each field behind its serialized
+    length, its tag and its payload length, which are constants of the
+    file. Works on arrays of frames of any leading shape."""
+
+    def __init__(self, klen: int, vlen: int) -> None:
+        import numpy as np
+
+        def prefix(length: int) -> "np.ndarray":
+            ser = serialize(b"\x00" * length)
+            tag = ser[:len(ser) - length]  # tag+vint, payload off
+            return np.frombuffer(_vint_bytes(len(ser)) + tag, np.uint8)
+
+        self._kf, self._vf = prefix(klen), prefix(vlen)
+        self._klen = klen
+        self._key_at = len(self._kf)
+        self._value_at = self._key_at + klen + len(self._vf)
+        self.size = self._value_at + vlen
+
+    def laid_out(self, frames):
+        """``frames`` ([..., size] uint8) with the constants written."""
+        frames[..., :self._key_at] = self._kf
+        frames[..., self._key_at + self._klen:self._value_at] = self._vf
+        return frames
+
+    def fill(self, frames, rows) -> None:
+        """Each row's key and value copied into its frame."""
+        frames[..., self._key_at:self._key_at + self._klen] = \
+            rows[..., :self._klen]
+        frames[..., self._value_at:] = rows[..., self._klen:]
 
 
 class Writer:
@@ -89,41 +128,75 @@ class Writer:
         ``append(bytes, bytes)`` calls (every serialized length is a
         per-file constant, so frames are a numpy tile job) — the write
         path of the device-shuffled reduce, where per-record Python append
-        would dominate the whole job."""
-        import numpy as np
+        would dominate the whole job.
 
-        from tpumr.io.writable import serialize
+        A row is copied once: into its frame, in a buffer this call
+        allocates once and hands the stream views of. Neither the copy
+        nor the stream's ``write`` holds the interpreter, so several
+        writers on threads of one process run beside each other."""
+        import numpy as np
         n = int(rows.shape[0])
         if n == 0:
             return
         self._flush_block()  # keep scalar-appended records ordered first
-        vlen = int(rows.shape[1]) - klen
-
-        def field_prefix(length: int) -> bytes:
-            ser = serialize(b"\x00" * length)
-            ser_prefix = ser[:len(ser) - length]  # tag+vint, payload off
-            head = BytesIO()
-            write_vint(head, len(ser_prefix) + length)
-            return head.getvalue() + ser_prefix
-
-        kf = np.frombuffer(field_prefix(klen), np.uint8)
-        vf = np.frombuffer(field_prefix(vlen), np.uint8)
-        frame_len = len(kf) + klen + len(vf) + vlen
-        frames = np.empty((n, frame_len), np.uint8)
-        frames[:, :len(kf)] = kf
-        frames[:, len(kf):len(kf) + klen] = rows[:, :klen]
-        off = len(kf) + klen
-        frames[:, off:off + len(vf)] = vf
-        frames[:, off + len(vf):] = rows[:, klen:]
-
+        frame = _FixedFrame(klen, int(rows.shape[1]) - klen)
         per = self._block_records  # same block granularity as scalar appends
-        for lo in range(0, n, per):
-            m = min(per, n - lo)
-            head = BytesIO()
-            write_vint(head, m)
-            # block-sized copies only — one big tobytes() would double the
-            # peak memory of exactly the large-partition path this serves
-            self._emit_block(head.getvalue() + frames[lo:lo + m].tobytes())
+        # without a codec a full block is its frames behind constants; a
+        # codec wants a block's bytes, and a partial last block has a
+        # record count of its own: those go a block at a time
+        done = n // per * per if self._codec.name == "none" else 0
+        if done:
+            self._append_full_blocks(rows[:done], frame)
+        if done < n:
+            frames = frame.laid_out(np.empty(
+                (min(per, n - done), frame.size), np.uint8))
+            for lo in range(done, n, per):
+                block = frames[:min(per, n - lo)]
+                frame.fill(block, rows[lo:lo + per])
+                self._emit_block(_vint_bytes(block.shape[0])
+                                 + block.tobytes())
+
+    def _append_full_blocks(self, rows, frame: "_FixedFrame") -> None:
+        """Uncompressed blocks of exactly ``block_records`` rows each, a
+        chunk of blocks at a time. A block has a slot of fixed size in the
+        chunk's buffer: sync escape and marker, length word and record
+        count, laid once like the field prefixes inside the frames, then
+        the frames. The escape and marker go to the stream only where
+        ``_emit_block`` would have written them, so what is written is a
+        slot from its start or from its length word, and neighbouring
+        slots written whole leave in one ``write``."""
+        import numpy as np
+        per = self._block_records
+        head = 4 + SYNC_SIZE           # the escape and the marker
+        body = len(_vint_bytes(per)) + per * frame.size
+        before = struct.pack(">I", _SYNC_ESCAPE) + self._sync \
+            + struct.pack(">I", body) + _vint_bytes(per)
+        slot = head + 4 + body
+        blocks = rows.shape[0] // per
+        at_once = max(1, min(blocks, _BULK_CHUNK_BYTES // slot))
+        buf = np.empty(at_once * slot, np.uint8)
+        buf.reshape(at_once, slot)[:, :len(before)] = np.frombuffer(
+            before, np.uint8)
+        frames = frame.laid_out(np.ndarray(
+            (at_once, per, frame.size), np.uint8, buf,
+            offset=len(before), strides=(slot, frame.size, 1)))
+        view = memoryview(buf)
+        for lo in range(0, blocks, at_once):
+            nb = min(at_once, blocks - lo)
+            frame.fill(frames[:nb], rows[lo * per:(lo + nb) * per]
+                       .reshape(nb, per, rows.shape[1]))
+            start = 0
+            for b in range(nb):
+                synced = self._since_sync >= SYNC_INTERVAL
+                if not synced:
+                    # this block starts at its length word: what is laid
+                    # out before it leaves first
+                    if b:
+                        self._out.write(view[start:b * slot])
+                    start = b * slot + head
+                self._since_sync = (0 if synced else self._since_sync) \
+                    + body + 4
+            self._out.write(view[start:nb * slot])
 
     def sync_now(self) -> None:
         self.sync_pos()

@@ -34,9 +34,11 @@ reference does over HTTP + disk merges — run on device.
 
 from __future__ import annotations
 
+import bisect
 import os
 import struct
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable
 
 import numpy as np
@@ -339,24 +341,21 @@ def _load_splitters(conf: Any, keys: np.ndarray, num_ranges: int,
 def _range_boundaries(sorted_keys: np.ndarray, splitters: np.ndarray,
                       lo_range: int, hi_range: int) -> list[int]:
     """Split one device's key-sorted shard into its ranges: boundary after
-    range i = #keys <= splitters[i] (vectorized lexicographic count —
-    consistent with compute_dest's 'equal goes low' convention). Cut lists
-    can be SHORT (write_partition_file dedups duplicate samples): a missing
-    splitter acts as +inf, leaving the top ranges empty — same tolerance
-    as the host TotalOrderPartitioner."""
-    from tpumr.parallel.device_sort import _lex_gt, key_columns
-    n, klen = sorted_keys.shape
-    if n == 0:
-        return [0] * (hi_range - lo_range - 1)
-    kcols = key_columns(sorted_keys, klen)
-    scols = key_columns(splitters, klen) if len(splitters) else None
-    bounds = []
-    for i in range(lo_range, hi_range - 1):
-        if scols is None or i >= len(scols):
-            bounds.append(n)  # +inf splitter: everything stays below
-        else:
-            bounds.append(int(n - _lex_gt(kcols, scols[i]).sum()))
-    return bounds
+    range i = #keys <= splitters[i] ('equal goes low', as compute_dest and
+    the host TotalOrderPartitioner have it), found by bisection: the
+    shard's key bytes are in byte order, so a cut is two dozen probes of
+    one key each, whatever the shard holds. Cut lists can be SHORT
+    (write_partition_file dedups duplicate samples): a missing splitter
+    acts as +inf, leaving the top ranges empty — same tolerance as the
+    host TotalOrderPartitioner."""
+    n = sorted_keys.shape[0]
+
+    def key_at(i: int) -> bytes:
+        return sorted_keys[i].tobytes()
+
+    return [bisect.bisect_right(range(n), splitters[i].tobytes(), key=key_at)
+            if i < len(splitters) else n
+            for i in range(lo_range, hi_range - 1)]
 
 
 def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
@@ -374,7 +373,13 @@ def run_device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     ``dshuffle:device`` (on a mesh with a child per step: ``:put``,
     ``:dest``, ``:exchange``, ``:sort``, ``:get``) / ``dshuffle:gather``
     (``device_partition_sort``) or
-    ``dshuffle:host_sort`` (the fallback), ``dshuffle:write``. Where the
+    ``dshuffle:host_sort`` (the fallback), then ONE ``dshuffle:write``
+    around the whole write phase with a ``dshuffle:range`` under it for
+    every range: each shard is cut at the job's splitters by bisection,
+    and where the rows are written as they are (the identity reducer, a
+    kernel's groups) every range has a writer of its own on a thread of
+    its own (``TPU_SHUFFLE_WRITERS`` at a time); a user's reducer class
+    is called one range after another in this thread. Where the
     job's reducer is a kernel (``tpumr.reduce.kernel``) the device call
     reduces where it sorted (``dshuffle:sort`` and ``dshuffle:reduce``
     under ``dshuffle:device``) and groups are written, not rows; rows
@@ -547,46 +552,70 @@ def _device_reduce(conf: Any, task: Task, dense_fetch: DenseFetchFn,
     wd = committer.setup_task(str(task.attempt_id))
     out_fmt = new_instance(conf.get_output_format(), conf)
 
-    def write_range(range_idx: int, rows: np.ndarray,
-                    sp: "tracing.Span | None") -> None:
-        if sp is not None:
-            sp.set(rows=int(rows.shape[0]), bytes=int(rows.nbytes))
-        writer = out_fmt.get_record_writer(conf, wd, range_idx)
-        try:
-            if identity:
-                _write_rows(writer, rows, klen, reporter)
-            else:
-                _reduce_rows(conf, reducer_cls, rows, klen, writer, reporter)
-        finally:
-            writer.close()
+    with tracing.span("dshuffle:write", ranges=num_ranges) as sp:
+        # every range of every shard first: a shard is in key order (the
+        # device sort's, the host fallback's, a kernel's groups alike), so
+        # its cuts cost nothing and all ranges are known at once; a device
+        # that received no row leaves its ranges empty
+        t_cut = time.monotonic()
+        ranges: list[np.ndarray] = []
+        for lo_r in range(0, num_ranges, ranges_per_dev):
+            hi_r = min(lo_r + ranges_per_dev, num_ranges)
+            shard = shards[lo_r // ranges_per_dev]
+            cuts = [0] + _range_boundaries(shard[:, :klen], splitters,
+                                           lo_r, hi_r) + [shard.shape[0]]
+            ranges += [shard[a:b] for a, b in zip(cuts, cuts[1:])]
+        cut_s = time.monotonic() - t_cut
+        ctx = tracing.capture()   # this span, for the workers' spans
 
-    emitted = set()
-    for d in range(n_dev):
-        lo_r = d * ranges_per_dev
-        hi_r = min((d + 1) * ranges_per_dev, num_ranges)
-        if lo_r >= hi_r:
-            continue
-        shard = shards[d]
-        cuts: "list[int] | None" = None
-        for i, r in enumerate(range(lo_r, hi_r)):
-            with tracing.span("dshuffle:write", range=r) as sp:
-                if cuts is None:
-                    # cutting a device's shard is booked to its first range
-                    cuts = [0] + _range_boundaries(
-                        shard[:, :klen], splitters, lo_r, hi_r) \
-                        + [shard.shape[0]]
-                write_range(r, shard[cuts[i]:cuts[i + 1]], sp)
-            emitted.add(r)
-    for r in range(num_ranges):  # ranges on idle devices: empty parts
-        if r not in emitted:
-            with tracing.span("dshuffle:write", range=r) as sp:
-                write_range(r, np.zeros((0, klen + vlen), np.uint8), sp)
+        def write_range(range_idx: int, rows: np.ndarray) -> None:
+            with tracing.activate_captured(ctx), tracing.span(
+                    "dshuffle:range", range=range_idx,
+                    device=range_idx // ranges_per_dev,
+                    rows=int(rows.shape[0]), bytes=int(rows.nbytes)):
+                writer = out_fmt.get_record_writer(conf, wd, range_idx)
+                try:
+                    if identity:
+                        _write_rows(writer, rows, klen)
+                    else:
+                        _reduce_rows(conf, reducer_cls, rows, klen, writer,
+                                     reporter)
+                finally:
+                    writer.close()
+
+        total = sum(r.shape[0] for r in ranges)
+        if identity:
+            # rows as they are: a writer a range, side by side, each into
+            # its own part file. Framing and writing leave the interpreter
+            # free (Writer.append_fixed_rows), so the phase lasts about as
+            # long as its largest range
+            writers = min(num_ranges, os.cpu_count() or 1)
+            with ThreadPoolExecutor(
+                    writers, thread_name_prefix="dshuffle-range") as pool:
+                written = [pool.submit(write_range, r, rows)
+                           for r, rows in enumerate(ranges)]
+            # every worker has ended and closed its stream: the first
+            # error, if any, fails the task
+            for w in written:
+                w.result()
+            reporter.incr_counter(TaskCounter.FRAMEWORK_GROUP,
+                                  TaskCounter.REDUCE_OUTPUT_RECORDS, total)
+        else:
+            # a user's reducer is Python a record and was never promised
+            # to run beside itself: one range after another, in this thread
+            writers = 1
+            for r, rows in enumerate(ranges):
+                write_range(r, rows)
+        reporter.incr_counter(BackendCounter.GROUP,
+                              BackendCounter.TPU_SHUFFLE_WRITERS, writers)
+        if sp is not None:
+            sp.set(writers=writers, cut_s=round(cut_s, 6), rows=total,
+                   bytes=sum(int(r.nbytes) for r in ranges))
     # commit is the CALLER's job (tracker: master-gated can_commit;
     # local runner: direct commit_task) — same contract as run_reduce_task
 
 
-def _write_rows(writer: Any, rows: np.ndarray, klen: int,
-                reporter: Reporter) -> None:
+def _write_rows(writer: Any, rows: np.ndarray, klen: int) -> None:
     bulk = getattr(writer, "write_fixed_rows", None)
     if bulk is not None:
         bulk(rows, klen)  # vectorized framing — per-record append would
@@ -596,8 +625,6 @@ def _write_rows(writer: Any, rows: np.ndarray, klen: int,
         vb = rows[:, klen:]
         for i in range(rows.shape[0]):
             writer.write(kb[i].tobytes(), vb[i].tobytes())
-    reporter.incr_counter(TaskCounter.FRAMEWORK_GROUP,
-                          TaskCounter.REDUCE_OUTPUT_RECORDS, rows.shape[0])
 
 
 def _reduce_rows(conf: Any, reducer_cls: type, rows: np.ndarray, klen: int,
