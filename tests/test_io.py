@@ -466,3 +466,240 @@ class TestTFile:
         vals = [v for k, v in r.scanner(b"dup") if k == b"dup"]
         assert vals == [b"v%d" % i for i in range(6)]
         assert r.get(b"dup") == b"v0"
+
+
+# ------------------------------------------ the whole-split batch read
+# Reader.read_batch_range reads a split into one buffer and parses every
+# block at once as one strided view where the file shows fixed-width
+# bytes records; the per-block parser (today's iter_block_bodies walk and
+# _batch_of_bodies) is the reference it has to equal, byte for byte.
+
+
+def _rows_file(n, block_records=1000, widths=(10, 90), sync_every=0,
+               codec="none", seed=0):
+    """A SequenceFile of ``n`` fixed-width rows and the (key, value)
+    pairs it holds; ``sync_every`` > 0 adds a ``sync_pos`` marker after
+    that many rows (the MapFile case) on top of the writer's own."""
+    klen, vlen = widths
+    rows = np.random.default_rng(seed).integers(
+        0, 256, (n, klen + vlen), dtype=np.uint8)
+    buf = BytesIO()
+    w = sequencefile.Writer(buf, codec=codec, block_records=block_records)
+    if sync_every:
+        for lo in range(0, n, sync_every):
+            for r in rows[lo:lo + sync_every]:
+                w.append(r[:klen].tobytes(), r[klen:].tobytes())
+            w.sync_pos()
+    else:
+        w.append_fixed_rows(rows, klen)
+    w.close()
+    return buf.getvalue(), [(r[:klen].tobytes(), r[klen:].tobytes())
+                            for r in rows]
+
+
+def _per_block(raw, start, end):
+    """The split read a block at a time: the reference form."""
+    r = sequencefile.Reader(BytesIO(raw))
+    if not r._position_for_range(start, end):
+        return sequencefile._batch_of_bodies(iter(()))
+    return sequencefile._batch_of_bodies(r.iter_block_bodies(end))
+
+
+def _read(raw, start, end):
+    stats = {}
+    batch = sequencefile.Reader(BytesIO(raw)).read_batch_range(
+        start, end, stats)
+    return batch, stats
+
+
+def _pairs(batch):
+    return [(batch.key(i), batch.value(i)) for i in range(len(batch))]
+
+
+def _assert_same(a, b):
+    for col in ("key_data", "key_offsets", "value_data", "value_offsets"):
+        x, y = getattr(a, col), getattr(b, col)
+        assert x.dtype == y.dtype and np.array_equal(x, y), col
+    assert a.key_data.flags.c_contiguous and a.value_data.flags.c_contiguous
+
+
+def _escapes(raw):
+    """Byte positions of every sync escape (the marker's 4-byte prefix)
+    after the header, and the header's end."""
+    r = sequencefile.Reader(BytesIO(raw))
+    out, at = [], r._header_end
+    while (at := raw.find(r._sync, at)) >= 0:
+        if at - 4 >= r._header_end:
+            out.append(at - 4)
+        at += 1
+    return out, r._header_end
+
+
+def _cuts(raw):
+    """Split boundaries worth trying: inside and at the header's end, on,
+    just before and just after escapes, mid-block, and the file's end."""
+    escapes, hend = _escapes(raw)
+    cuts = {0, 1, hend - 1, hend, hend + 1, len(raw) - 1, len(raw)}
+    for e in escapes[:6] + escapes[-4:]:
+        cuts |= {e - 1, e, e + 1, e + 7}
+    for a, b in zip(escapes[:5], escapes[1:6]):
+        cuts.add((a + b) // 2)
+    return sorted(c for c in cuts if 0 <= c <= len(raw))
+
+
+@pytest.mark.parametrize("block_records,n,sync_every", [
+    (1, 40, 0), (7, 300, 0), (7, 298, 0), (1000, 2500, 0),
+    (1000, 5000, 0), (7, 300, 23), (1000, 2500, 900)],
+    ids=["per1", "per7", "per7-last-partial", "per1000-last-partial",
+         "per1000", "per7-mapfile-syncs", "per1000-mapfile-syncs"])
+def test_strided_read_is_the_per_block_read_at_every_cut(block_records, n,
+                                                        sync_every):
+    raw, _ = _rows_file(n, block_records, sync_every=sync_every)
+    cuts = _cuts(raw)
+    owned_nothing = strided = 0
+    for i, start in enumerate(cuts):
+        for end in cuts[i + 1:i + 5]:
+            got, stats = _read(raw, start, end)
+            _assert_same(got, _per_block(raw, start, end))
+            if len(got):
+                assert stats["form"] == "strided", (start, end)
+                strided += 1
+            else:
+                owned_nothing += 1
+            assert stats["reads"] >= 1 or len(got) == 0
+    assert strided and owned_nothing  # both kinds of split were tried
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_random_covering_splits_read_every_record_exactly_once(seed):
+    rng = np.random.default_rng(seed)
+    block_records = int(rng.choice([1, 7, 1000]))
+    raw, pairs = _rows_file(int(rng.integers(50, 3000)), block_records,
+                            seed=seed)
+    for _ in range(5):
+        inner = rng.choice(np.arange(1, len(raw)), size=int(
+            rng.integers(1, 12)), replace=False)
+        cuts = [0] + sorted(int(c) for c in inner) + [len(raw)]
+        got = []
+        for start, end in zip(cuts, cuts[1:]):
+            batch, _ = _read(raw, start, end)
+            _assert_same(batch, _per_block(raw, start, end))
+            got += _pairs(batch)
+        assert got == pairs
+
+
+def _with_empty_block(raw_pairs):
+    buf = BytesIO()
+    w = sequencefile.Writer(buf, block_records=2)
+    for i, (k, v) in enumerate(raw_pairs):
+        w.append(k, v)
+        if i == 2:
+            w._flush_block()
+            w._emit_block(b"\x00")  # a block of no records
+    w.close()
+    return buf.getvalue()
+
+
+def _written(pairs, block_records=3, codec="none"):
+    buf = BytesIO()
+    w = sequencefile.Writer(buf, codec=codec, block_records=block_records)
+    for k, v in pairs:
+        w.append(k, v)
+    w.close()
+    return buf.getvalue()
+
+
+FIXED = [(b"k%09d" % i, b"v" * 90) for i in range(9)]
+
+
+@pytest.mark.parametrize("case", ["codec", "ragged", "widths",
+                                  "shifted", "empty-block", "typed"])
+def test_what_the_strided_view_cannot_prove_is_parsed_a_block_at_a_time(
+        case):
+    pairs = {"codec": FIXED, "empty-block": FIXED,
+             "ragged": [(b"k%d" % i, b"x" * (i + 1)) for i in range(9)],
+             "widths": FIXED[:6] + [(b"a", b"bb")] * 3,
+             # frames of one size whose key ends at another byte, every
+             # block's first record as wide as the others' first
+             "shifted": [(b"k" * (9 + 2 * (i % 3 == 1)),
+                          b"v" * (91 - 2 * (i % 3 == 1))) for i in range(9)],
+             "typed": [(f"key{i}", {"n": i}) for i in range(9)]}[case]
+    raw = (_with_empty_block(pairs) if case == "empty-block" else
+           _written(pairs, codec="zlib" if case == "codec" else "none"))
+    got, stats = _read(raw, 0, len(raw))
+    assert stats["form"] == "blocks"
+    _assert_same(got, _per_block(raw, 0, len(raw)))
+    if case != "typed":
+        assert _pairs(got) == pairs
+
+
+@pytest.mark.parametrize("fault", ["truncated-body", "truncated-length",
+                                   "bad-marker", "bad-last-marker",
+                                   "count-short-by-one"])
+def test_a_broken_file_raises_as_the_per_block_read_does(fault):
+    raw, _ = _rows_file(3500, 1000)
+    escapes, _ = _escapes(raw)
+    if fault == "truncated-body":
+        raw = raw[:escapes[2] + 20 + 4 + 500]
+    elif fault == "truncated-length":
+        raw = raw[:escapes[2] + 20 + 2]    # 2 bytes of a length word
+    elif fault == "count-short-by-one":
+        # block 2 says 999 records (vint 0xe7 0x07 for 0xe8 0x07): the
+        # per-record parser reads 999 and leaves the last frame unread
+        at = escapes[1] + 20 + 4
+        assert raw[at:at + 2] == bytes((0xE8, 0x07))
+        raw = raw[:at] + bytes((0xE7,)) + raw[at + 1:]
+    else:
+        at = escapes[-1 if fault == "bad-last-marker" else 1] + 9
+        raw = raw[:at] + bytes([raw[at] ^ 0xFF]) + raw[at + 1:]
+
+    def outcome(read):
+        try:
+            return _pairs(read(raw, 0, len(raw)))
+        except (IOError, EOFError) as e:
+            return type(e), str(e)
+
+    want = outcome(_per_block)
+    assert outcome(lambda *a: _read(*a)[0]) == want
+    if fault == "truncated-length":
+        assert len(want) == 3000    # three whole blocks, then the walk ends
+    elif fault == "count-short-by-one":
+        assert len(want) == 3499
+    else:
+        assert want == ((EOFError, "truncated block")
+                        if fault == "truncated-body" else
+                        (OSError, "corrupt file: bad sync marker"))
+
+
+@pytest.mark.parametrize("scan", [1, 7, 16, 17, 4096])
+def test_sync_lands_where_a_search_of_the_bytes_does(monkeypatch, scan):
+    """The chunked scan finds a marker that straddles two chunks."""
+    monkeypatch.setattr(sequencefile, "_SCAN_BYTES", scan)
+    raw, _ = _rows_file(60, 7)
+    r0 = sequencefile.Reader(BytesIO(raw))
+    for pos in range(r0._header_end + 1, len(raw) + 2, 5):
+        r = sequencefile.Reader(BytesIO(raw))
+        found = raw.find(r._sync, pos + 4)
+        assert r.sync(pos) == (found >= 0)
+        if found >= 0:
+            assert r.tell() == found + sequencefile.SYNC_SIZE
+
+
+@pytest.mark.parametrize("chunk,tail", [(16 << 20, 256 << 10), (4096, 64),
+                                        (1000, 1)])
+def test_a_split_takes_a_read_a_chunk_and_grows_for_a_long_last_block(
+        monkeypatch, chunk, tail):
+    """A bounded chunk a read call; a last block longer than the room the
+    buffer keeps past the split's end grows it, with the same batch."""
+    monkeypatch.setattr(sequencefile, "_READ_CHUNK_BYTES", chunk)
+    monkeypatch.setattr(sequencefile, "_TAIL_BYTES", tail)
+    raw, _ = _rows_file(5000, 1000)
+    escapes, _ = _escapes(raw)
+    for start, end in [(0, len(raw)), (escapes[1] + 3, escapes[3] + 100)]:
+        stats = {}
+        got = sequencefile.Reader(BytesIO(raw)).read_batch_range(
+            start, end, stats)
+        _assert_same(got, _per_block(raw, start, end))
+        assert stats["form"] == "strided"
+        assert stats["reads"] <= (end - start) // chunk + 5 \
+            + (end - start) // 200_000 * (chunk < 200_000)

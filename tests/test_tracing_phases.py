@@ -230,6 +230,29 @@ def test_the_mesh_sort_copies_back_the_rows_and_not_the_slots(sorted_job):
     assert back < 0.4 * slots
 
 
+def test_every_map_reads_its_split_once_in_the_strided_form(sorted_job):
+    """A sort map's whole-split read is one ``map:read`` span under its
+    ``task:run``, and every split of teragen's files is parsed as one
+    strided view: the job counts each map in MAP_INPUT_SPLITS_STRIDED."""
+    spans = sorted_job["spans"]
+    by_id = {s["span_id"]: s for s in spans}
+    reads = [s for s in spans if s["name"] == "map:read"]
+    runs = [by_id[s["parent_span_id"]] for s in reads]
+    assert len(reads) == MAPS
+    assert all(r["name"] == "task:run" and "_m_" in r["attributes"][
+        "attempt_id"] for r in runs)
+    assert len({r["span_id"] for r in runs}) == MAPS
+    for s in reads:
+        a = s["attributes"]
+        assert a["form"] == "strided"
+        assert 1 <= a["reads"] <= 5
+        assert a["bytes"] >= a["records"] * 100
+    assert sum(s["attributes"]["records"] for s in reads) == ROWS
+    assert sorted_job["counters"].value(
+        TaskCounter.FRAMEWORK_GROUP,
+        TaskCounter.MAP_INPUT_SPLITS_STRIDED) == MAPS
+
+
 def test_every_finished_attempt_has_one_task_done_after_its_launch(
         sorted_job):
     spans = sorted_job["spans"]

@@ -52,6 +52,11 @@ class TaskCounter:
     #: io.sort.factor): intermediate passes run and segments they merged
     MERGE_PASSES = "MERGE_PASSES"
     MERGE_PASS_SEGMENTS = "MERGE_PASS_SEGMENTS"
+    #: maps whose whole-split read parsed every block of a SequenceFile
+    #: split at once, as one strided view (sequencefile.read_batch_range's
+    #: ``strided`` form); the others read no such split or parsed a block
+    #: at a time
+    MAP_INPUT_SPLITS_STRIDED = "MAP_INPUT_SPLITS_STRIDED"
     FRAMEWORK_GROUP = "tpumr.TaskCounter"
 
 

@@ -31,6 +31,15 @@ _SYNC_ESCAPE = 0xFFFFFFFF  # uint32 length sentinel preceding a sync marker
 #: write, large enough that a write is not a call a block (sized on the
 #: chip's host: PERF.md section 6, PR 34)
 _BULK_CHUNK_BYTES = 4 << 20
+#: bytes a whole-split read asks its stream for in one call: a 64 MB split
+#: is a handful of calls, not four a block (each call gives the
+#: interpreter away and, beside busy threads, waits to win it back)
+_READ_CHUNK_BYTES = 16 << 20
+#: room a split's buffer keeps past the split's end for the rest of its
+#: last block and the sync that ends it; a longer block grows the buffer
+_TAIL_BYTES = 256 << 10
+#: bytes ``Reader.sync`` reads a call while it looks for the next marker
+_SCAN_BYTES = 128 << 10
 
 
 class _FixedFrame:
@@ -224,18 +233,22 @@ class Writer:
         self.close()
 
 
-def _parse_fixed_block(body: bytes):
-    """Vectorized block parse: when every record in the block shares the
-    first record's exact frame bytes outside the two payloads — i.e.
+def _fixed_run(buf, at: int, length: int, count: int, stride: int):
+    """Vectorized parse of ``count`` block bodies of ``length`` bytes each,
+    the first at ``buf[at]`` and the rest ``stride`` bytes apart (``buf``
+    a 1-D uint8 array): when every record of them shares the first
+    record's exact frame bytes outside the two payloads — i.e.
     bytes-tagged keys and values of one constant width each, the terasort
-    layout — the whole block is one ``[n, frame]`` reshape. Returns
-    ``(keys [n, klen] u8, values [n, vlen] u8)`` or None (caller falls
-    back to the per-record parser)."""
+    layout — and every block the first one's record count, the run is one
+    ``[count, n, frame]`` strided view of ``buf``. Returns the views
+    ``(keys [count, n, klen], values [count, n, vlen])`` or None (the
+    caller falls back to the per-record parser)."""
     import numpy as np
 
     from tpumr.io.writable import _TAG_BYTES, _vint_at
+    body = memoryview(buf)
     try:
-        n, rec0 = _vint_at(body, 0)
+        n, rec0 = _vint_at(body, at)
         if n <= 0:
             return None
         # first record, scalar: vint(len kser) ++ kser ++ vint(len vser)
@@ -254,20 +267,140 @@ def _parse_fixed_block(body: bytes):
             return None
     except IndexError:
         return None
+    head = rec0 - at
     frame = vpay0 + vlen - rec0
-    if len(body) - rec0 != n * frame:
+    if length - head != n * frame:
         return None
-    arr = np.frombuffer(body, np.uint8, n * frame, rec0).reshape(n, frame)
-    # every non-payload column must match record 0's bytes exactly (same
-    # lengths, same tags) — a cheap full proof that the reshape is valid
+    heads = np.ndarray((count, head), np.uint8, buf, at, (stride, 1))
+    frames = np.ndarray((count, n, frame), np.uint8, buf, rec0,
+                        (stride, frame, 1))
+    # every block's record count and every record's non-payload columns
+    # must match the first record's bytes exactly (same lengths, same
+    # tags) — a cheap full proof that the view is valid
     kpay = kpay0 - rec0
     vhdr = kpay + klen
     vpay = vpay0 - rec0
-    meta_idx = np.concatenate([np.arange(0, kpay),
-                               np.arange(vhdr, vpay)])
-    if n > 1 and not (arr[1:, meta_idx] == arr[0, meta_idx]).all():
+    if not ((heads == heads[0]).all()
+            and (frames[..., :kpay] == frames[0, 0, :kpay]).all()
+            and (frames[..., vhdr:vpay] == frames[0, 0, vhdr:vpay]).all()):
         return None
-    return arr[:, kpay:kpay + klen], arr[:, vpay:vpay + vlen]
+    return frames[..., kpay:vhdr], frames[..., vpay:]
+
+
+def _parse_fixed_block(body: bytes):
+    """One block body as :func:`_fixed_run` parses it: ``(keys [n, klen]
+    u8, values [n, vlen] u8)`` or None."""
+    import numpy as np
+    run = _fixed_run(np.frombuffer(body, np.uint8), 0, len(body), 1, 0)
+    return None if run is None else (run[0][0], run[1][0])
+
+
+def _fixed_batch(keys: list, values: list):
+    """One RecordBatch of fixed-width records from parsed runs (views of
+    shape ``[..., klen]`` and ``[..., vlen]``, every run of one width):
+    each run copied once into the batch's two contiguous columns."""
+    import numpy as np
+
+    from tpumr.io.recordbatch import RecordBatch
+    klen, vlen = keys[0].shape[-1], values[0].shape[-1]
+    sizes = [int(np.prod(k.shape[:-1])) for k in keys]
+    n = sum(sizes)
+    kd = np.empty((n, klen), np.uint8)
+    vd = np.empty((n, vlen), np.uint8)
+    at = 0
+    for k, v, m in zip(keys, values, sizes):
+        kd[at:at + m].reshape(k.shape)[...] = k
+        vd[at:at + m].reshape(v.shape)[...] = v
+        at += m
+    # one numpy call a column: each call on a large array gives the
+    # interpreter away, and beside busy threads waits to win it back
+    ko = np.arange(n + 1, dtype=np.int32)
+    ko *= klen
+    vo = np.arange(n + 1, dtype=np.int32)
+    vo *= vlen
+    return RecordBatch(kd.reshape(-1), ko, vd.reshape(-1), vo)
+
+
+def _strided_batch(buf, blocks: "list[tuple[int, int]]"):
+    """A split's uncompressed blocks (``(offset, length)`` of each body in
+    ``buf``) parsed a run at a time: neighbouring blocks of one length at
+    one stride are one :func:`_fixed_run`, so a split of full blocks
+    behind sync markers is one strided view, and a last partial block or
+    any block that breaks the stride is a run of its own. Returns the
+    RecordBatch, or None where a run is not fixed-width or the widths
+    change between runs (the caller parses the blocks one at a time)."""
+    keys: list = []
+    values: list = []
+    i = 0
+    while i < len(blocks):
+        at, length = blocks[i]
+        j, stride = i + 1, 0
+        if j < len(blocks) and blocks[j][1] == length:
+            stride = blocks[j][0] - at
+            while (j < len(blocks) and blocks[j][1] == length
+                   and blocks[j][0] - blocks[j - 1][0] == stride):
+                j += 1
+        run = _fixed_run(buf, at, length, j - i, stride)
+        if run is None or (keys and (
+                run[0].shape[-1] != keys[0].shape[-1]
+                or run[1].shape[-1] != values[0].shape[-1])):
+            return None
+        keys.append(run[0])
+        values.append(run[1])
+        i = j
+    return _fixed_batch(keys, values)
+
+
+def _batch_of_bodies(bodies: "Iterator[bytes]"):
+    """Decompressed block bodies as one RecordBatch, a block at a time:
+    blocks whose records all share the first record's frame parse as ONE
+    numpy reshape each; anything else falls back to the per-record path
+    with the same bytes/str/serialize value semantics as the reader-drain
+    staging path (tpu_runner.stage_batch)."""
+    from tpumr.io.recordbatch import RecordBatch
+
+    key_chunks: list = []   # [n, klen] u8 per fast block
+    val_chunks: list = []
+    slow: list[tuple[bytes, bytes]] = []  # (key, value) payloads
+
+    for body in bodies:
+        if body[:1] == b"\x00":  # vint 0: empty block, nothing to parse
+            continue
+        if not slow:
+            parsed = _parse_fixed_block(body)
+            if parsed is not None and key_chunks and (
+                    parsed[0].shape[1] != key_chunks[0].shape[1]
+                    or parsed[1].shape[1] != val_chunks[0].shape[1]):
+                parsed = None  # widths changed across blocks: go slow
+            if parsed is not None:
+                key_chunks.append(parsed[0])
+                val_chunks.append(parsed[1])
+                continue
+            # first ragged block: demote prior fast chunks to the slow
+            # list so record order is preserved (and stay slow — a
+            # mixed file is rare and order beats vectorization)
+            for karr, varr in zip(key_chunks, val_chunks):
+                slow.extend((karr[i].tobytes(), varr[i].tobytes())
+                            for i in range(karr.shape[0]))
+            key_chunks, val_chunks = [], []
+        block = BytesIO(body)
+        n = read_vint(block)
+        for _ in range(n):
+            klen = read_vint(block)
+            k = deserialize(block.read(klen))
+            vlen = read_vint(block)
+            v = deserialize(block.read(vlen))
+            k = k if isinstance(k, (bytes, bytearray)) else (
+                k.encode("utf-8") if isinstance(k, str) else serialize(k))
+            v = v if isinstance(v, (bytes, bytearray)) else (
+                v.encode("utf-8") if isinstance(v, str) else serialize(v))
+            slow.append((bytes(k), bytes(v)))
+
+    if slow:
+        return RecordBatch.from_pairs(slow)
+    if not key_chunks:
+        return RecordBatch.empty()
+    return _fixed_batch(key_chunks, val_chunks)
 
 
 class Reader:
@@ -288,6 +421,22 @@ class Reader:
         self._codec = get_codec(self.metadata.get("codec", "none"))
         self._sync = self._in.read(SYNC_SIZE)
         self._header_end = self._in.tell()
+        #: read calls this reader has made on its stream past the header,
+        #: and the bytes they brought
+        self.reads = 0
+        self.bytes_read = 0
+
+    def _read(self, n: int) -> bytes:
+        got = self._in.read(n)
+        self.reads += 1
+        self.bytes_read += len(got)
+        return got
+
+    def _readinto(self, view: memoryview) -> int:
+        n = self._in.readinto(view) or 0
+        self.reads += 1
+        self.bytes_read += n
+        return n
 
     def __iter__(self) -> Iterator[tuple[Any, Any]]:
         for k, v in self.iter_raw():
@@ -326,21 +475,68 @@ class Reader:
         the first sync at/after ``end`` (iter_raw's end-side rule)."""
         while True:
             pos = self._in.tell()
-            raw = self._in.read(4)
+            raw = self._read(4)
             if len(raw) < 4:
                 return
             (length,) = struct.unpack(">I", raw)
             if length == _SYNC_ESCAPE:
-                marker = self._in.read(SYNC_SIZE)
+                marker = self._read(SYNC_SIZE)
                 if marker != self._sync:
                     raise IOError("corrupt file: bad sync marker")
                 if end is not None and pos >= end:
                     return
                 continue
-            payload = self._in.read(length)
+            payload = self._read(length)
             if len(payload) < length:
                 raise EOFError("truncated block")
             yield self._codec.decompress(payload)
+
+    def _read_owned_blocks(self, end: int):
+        """The blocks of a split from the current position (its first
+        block) up to the first sync at/after ``end``: one buffer filled by
+        a few large reads, then walked with no I/O. Returns ``(buf,
+        [(offset, length) of each block body in buf])``; the walk, its
+        end-side rule and its errors are :meth:`iter_block_bodies`'."""
+        import numpy as np
+        base = self._in.tell()
+        buf = np.empty(max(0, end - base) + _TAIL_BYTES, np.uint8)
+        filled = 0
+
+        def fill(upto: int) -> bool:
+            """Read on until ``buf[:upto]`` holds the file's bytes; False
+            where the file ends first."""
+            nonlocal buf, filled
+            if upto > buf.shape[0]:  # a last block longer than the room
+                grown = np.empty(upto + _TAIL_BYTES, np.uint8)
+                grown[:filled] = buf[:filled]
+                buf = grown
+            while filled < upto:
+                got = self._readinto(memoryview(buf)[
+                    filled:min(buf.shape[0], filled + _READ_CHUNK_BYTES)])
+                if not got:
+                    return False
+                filled += got
+            return True
+
+        blocks: list[tuple[int, int]] = []
+        at = 0
+        while at + 4 <= filled or fill(at + 4):
+            (length,) = struct.unpack_from(">I", buf, at)
+            if length == _SYNC_ESCAPE:
+                marker = at + 4
+                if not (marker + SYNC_SIZE <= filled
+                        or fill(marker + SYNC_SIZE)) or \
+                        buf[marker:marker + SYNC_SIZE].tobytes() != self._sync:
+                    raise IOError("corrupt file: bad sync marker")
+                if base + at >= end:
+                    break
+                at = marker + SYNC_SIZE
+                continue
+            if not (at + 4 + length <= filled or fill(at + 4 + length)):
+                raise EOFError("truncated block")
+            blocks.append((at + 4, length))
+            at += 4 + length
+        return buf, blocks
 
     def iter_raw(self, end: int | None = None) -> Iterator[tuple[bytes, bytes]]:
         for body in self.iter_block_bodies(end):
@@ -353,70 +549,34 @@ class Reader:
                 v = block.read(vlen)
                 yield k, v
 
-    def read_batch_range(self, start: int, end: int):
+    def read_batch_range(self, start: int, end: int,
+                         stats: "dict | None" = None):
         """Records of the split [start, end) as one
         :class:`~tpumr.io.recordbatch.RecordBatch` — the whole-split read
-        for kernel jobs. Blocks whose serialized records all share the
-        first record's byte-level frame (fixed-width bytes keys/values —
-        the terasort layout) parse as ONE numpy reshape; anything else
-        falls back to the per-record path with the same
-        bytes/str/serialize value semantics as the reader-drain staging
-        path (tpu_runner.stage_batch)."""
-        import numpy as np
-
-        from tpumr.io.recordbatch import RecordBatch
-        from tpumr.io.writable import serialize
-
-        if not self._position_for_range(start, end):
-            return RecordBatch.empty()
-
-        key_chunks: list[np.ndarray] = []   # [n, klen] u8 per fast block
-        val_chunks: list[np.ndarray] = []
-        slow: list[tuple[bytes, bytes]] = []  # (key, value) payloads
-
-        for body in self.iter_block_bodies(end):
-            if body[:1] == b"\x00":  # vint 0: empty block, nothing to parse
-                continue
-            if not slow:
-                parsed = _parse_fixed_block(body)
-                if parsed is not None and key_chunks and (
-                        parsed[0].shape[1] != key_chunks[0].shape[1]
-                        or parsed[1].shape[1] != val_chunks[0].shape[1]):
-                    parsed = None  # widths changed across blocks: go slow
-                if parsed is not None:
-                    key_chunks.append(parsed[0])
-                    val_chunks.append(parsed[1])
-                    continue
-                # first ragged block: demote prior fast chunks to the slow
-                # list so record order is preserved (and stay slow — a
-                # mixed file is rare and order beats vectorization)
-                for karr, varr in zip(key_chunks, val_chunks):
-                    slow.extend((karr[i].tobytes(), varr[i].tobytes())
-                                for i in range(karr.shape[0]))
-                key_chunks, val_chunks = [], []
-            block = BytesIO(body)
-            n = read_vint(block)
-            for _ in range(n):
-                klen = read_vint(block)
-                k = deserialize(block.read(klen))
-                vlen = read_vint(block)
-                v = deserialize(block.read(vlen))
-                k = k if isinstance(k, (bytes, bytearray)) else (
-                    k.encode("utf-8") if isinstance(k, str) else serialize(k))
-                v = v if isinstance(v, (bytes, bytearray)) else (
-                    v.encode("utf-8") if isinstance(v, str) else serialize(v))
-                slow.append((bytes(k), bytes(v)))
-
-        if slow:
-            return RecordBatch.from_pairs(slow)
-        if not key_chunks:
-            return RecordBatch.empty()
-        keys = np.concatenate(key_chunks)
-        vals = np.concatenate(val_chunks)
-        n = keys.shape[0]
-        ko = (np.arange(n + 1, dtype=np.int64) * keys.shape[1]).astype(np.int32)
-        vo = (np.arange(n + 1, dtype=np.int64) * vals.shape[1]).astype(np.int32)
-        return RecordBatch(keys.reshape(-1), ko, vals.reshape(-1), vo)
+        for kernel jobs and vectorized maps. The split's blocks are read
+        into one buffer in a few calls. Where the file shows uncompressed
+        blocks of fixed-width bytes keys and values (the terasort layout),
+        every block parses at once as one strided view (``strided``);
+        anything else — a codec, ragged records, widths that change
+        between blocks, an empty block — parses a block at a time
+        (``blocks``) with the same records. ``stats``, where given, gets
+        the split's ``bytes`` and ``reads`` (read calls) and that
+        ``form``."""
+        reads, nbytes = self.reads, self.bytes_read
+        buf, blocks = (self._read_owned_blocks(end)
+                       if self._position_for_range(start, end)
+                       else (None, []))
+        batch = _strided_batch(buf, blocks) \
+            if blocks and self._codec.name == "none" else None
+        form = "blocks" if batch is None else "strided"
+        if batch is None:
+            batch = _batch_of_bodies(
+                self._codec.decompress(buf[at:at + length].tobytes())
+                for at, length in blocks)
+        if stats is not None:
+            stats.update(bytes=self.bytes_read - nbytes,
+                         reads=self.reads - reads, form=form)
+        return batch
 
     def sync(self, pos: int) -> bool:
         """Position the reader at the first sync marker at/after byte ``pos``.
@@ -429,18 +589,22 @@ class Reader:
         # itself starts at >= pos+4. Scanning from pos+4 keeps this side
         # consistent with iter_raw's end-side rule (escape pos >= end), so
         # adjacent splits never double-own the 4-byte escape window.
-        self._in.seek(pos + 4)
-        # scan for the 16-byte marker
-        window = self._in.read(SYNC_SIZE)
-        if len(window) < SYNC_SIZE:
-            return False
-        buf = bytearray(window)
-        while bytes(buf) != self._sync:
-            nxt = self._in.read(1)
-            if not nxt:
+        at = pos + 4
+        self._in.seek(at)
+        # scan for the 16-byte marker a chunk at a time, keeping the last
+        # SYNC_SIZE - 1 bytes of a chunk for a marker cut between two
+        carry = b""
+        while True:
+            chunk = self._read(_SCAN_BYTES)
+            if not chunk:
                 return False
-            buf = buf[1:] + nxt
-        return True
+            window = carry + chunk
+            found = window.find(self._sync)
+            if found >= 0:
+                self._in.seek(at - len(carry) + found + SYNC_SIZE)
+                return True
+            at += len(chunk)
+            carry = window[1 - SYNC_SIZE:]
 
     def tell(self) -> int:
         return self._in.tell()

@@ -149,7 +149,8 @@ class TextInputFormat(FileInputFormat):
                                      split.split_length, self.keep_bytes))
 
     @staticmethod
-    def _read_owned_bytes(split, conf) -> bytes:
+    def _read_owned_bytes(split, conf, stats: "dict | None" = None
+                          ) -> bytes:
         """The split's OWNED byte range under the LineRecordReader
         ownership rule — the subtlest invariant of text splitting, so it
         lives exactly once: skip the partial first line when start > 0,
@@ -166,17 +167,19 @@ class TextInputFormat(FileInputFormat):
                     return b""                  # mid-line: owns nothing
                 buf = buf[nl + 1:]
             buf += f.readline()
+        if stats is not None:   # summed over a combined split's parts
+            stats["bytes"] = stats.get("bytes", 0) + len(buf)
         return buf
 
-    def read_batch(self, split, conf):
+    def read_batch(self, split, conf, stats=None):
         """Whole-split vectorized read for kernel jobs: ONE file read +
         C-speed newline scan instead of 100k+ Python ``readline`` calls.
         Ownership matches :class:`LineRecordReader` exactly (see
         :meth:`_read_owned_bytes`); trailing ``\\r``/``\\n`` stripped
-        per line."""
+        per line. ``stats``, where given, gets the owned ``bytes``."""
         from tpumr.io.recordbatch import RecordBatch
         assert isinstance(split, FileSplit)
-        buf = self._read_owned_bytes(split, conf)
+        buf = self._read_owned_bytes(split, conf, stats)
         if not buf:
             return RecordBatch.empty()
         arr = np.frombuffer(buf, dtype=np.uint8)
@@ -229,10 +232,10 @@ class RawTextInputFormat(TextInputFormat):
 
     keep_bytes = True
 
-    def read_batch(self, split, conf):
+    def read_batch(self, split, conf, stats=None):
         from tpumr.io.recordbatch import RecordBatch
         assert isinstance(split, FileSplit)
-        buf = self._read_owned_bytes(split, conf)
+        buf = self._read_owned_bytes(split, conf, stats)
         if not buf:
             return RecordBatch.empty()
         # zero-copy: the batch's value_data is a view over buf
@@ -335,16 +338,17 @@ class SequenceFileInputFormat(FileInputFormat):
 
         return gen()
 
-    def read_batch(self, split, conf):
-        """Whole-split read for kernel jobs — fixed-width bytes records
-        (terasort's 10+90 layout) parse as one numpy reshape per block
-        (sequencefile._parse_fixed_block); anything else falls back to
-        the per-record parser with reader-equivalent value bytes."""
+    def read_batch(self, split, conf, stats=None):
+        """Whole-split read for kernel jobs and vectorized maps —
+        fixed-width bytes records (terasort's 10+90 layout) parse as one
+        strided view of the split's blocks; anything else falls back to
+        the per-record parser with reader-equivalent value bytes
+        (sequencefile.Reader.read_batch_range, which fills ``stats``)."""
         assert isinstance(split, FileSplit)
         fs = FileSystem.get(split.path, conf)
         with fs.open(split.path) as f:
             return sequencefile.Reader(f).read_batch_range(
-                split.start, split.start + split.split_length)
+                split.start, split.start + split.split_length, stats)
 
 
 class WholeFileInputFormat(FileInputFormat):
@@ -396,14 +400,14 @@ class CombineFileInputFormat(FileInputFormat):
 
         return gen()
 
-    def read_batch(self, split, conf):
+    def read_batch(self, split, conf, stats=None):
         """Kernel jobs over many small files: one vectorized text batch
         per part, concatenated — no per-line Python."""
         from tpumr.io.recordbatch import RecordBatch
         assert isinstance(split, MultiFileSplit)
         text = TextInputFormat()
         return RecordBatch.concat([
-            text.read_batch(FileSplit([], path, start, length), conf)
+            text.read_batch(FileSplit([], path, start, length), conf, stats)
             for path, start, length in split.parts])
 
 

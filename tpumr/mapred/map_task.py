@@ -365,17 +365,31 @@ def _declared_mapper_class(conf: Any, attr: str):
     return None
 
 
-def _read_batch_for_fast_path(conf: Any, in_fmt: Any, split: Any):
+def _read_batch_for_fast_path(conf: Any, in_fmt: Any, split: Any,
+                              reporter: Reporter):
     """One RecordBatch for a vectorized map fast path, or None when the
     input shape is ineligible (no batch reader; dense splits have no
     byte keys to pass through). Shared gate for the identity-dense and
-    host-batch-mapper paths so their eligibility can't drift apart."""
+    host-batch-mapper paths so their eligibility can't drift apart.
+
+    The read is the ``map:read`` span: ``records`` and what the format
+    says of it (``bytes``; a SequenceFile's ``reads``, its read calls,
+    and ``form``, ``strided`` or ``blocks``); a split read in the
+    ``strided`` form counts in ``MAP_INPUT_SPLITS_STRIDED``."""
     if split is None or getattr(in_fmt, "read_batch", None) is None:
         return None
     from tpumr.mapred.split import DenseSplit
     if isinstance(split, DenseSplit):
         return None
-    batch = in_fmt.read_batch(split, conf)
+    from tpumr.core import tracing
+    stats: dict = {}
+    with tracing.span("map:read") as span:
+        batch = in_fmt.read_batch(split, conf, stats=stats)
+        if span is not None:
+            span.set(records=len(batch), **stats)
+    if stats.get("form") == "strided":
+        reporter.incr_counter(TaskCounter.FRAMEWORK_GROUP,
+                              TaskCounter.MAP_INPUT_SPLITS_STRIDED)
     if not hasattr(batch, "padded_keys"):
         return None  # DenseBatch-shaped input: no byte keys
     return batch
@@ -394,7 +408,7 @@ def _identity_dense_fast_path(conf: Any, in_fmt: Any, split: Any,
     it only happens on misconfigured fixed-width declarations)."""
     if _declared_mapper_class(conf, "identity_map") is None:
         return False
-    batch = _read_batch_for_fast_path(conf, in_fmt, split)
+    batch = _read_batch_for_fast_path(conf, in_fmt, split, reporter)
     if batch is None:
         return False
     n = batch.num_records
@@ -422,7 +436,7 @@ def _host_batch_fast_path(conf: Any, in_fmt: Any, split: Any,
     mapper_cls = _declared_mapper_class(conf, "map_record_batch")
     if mapper_cls is None:
         return False
-    batch = _read_batch_for_fast_path(conf, in_fmt, split)
+    batch = _read_batch_for_fast_path(conf, in_fmt, split, reporter)
     if batch is None:
         return False
     # new_instance already ran configure(conf) — JobConfigurable seam
