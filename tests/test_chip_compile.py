@@ -200,6 +200,34 @@ def test_one_device_reduce_program(one_chip, record_property, program):
     _record(record_property, mem, secs)
 
 
+@pytest.mark.parametrize("program", ["map", "last_arcs"])
+def test_pagerank_programs_at_graph500_23(one_chip, record_property,
+                                          program):
+    """The PageRank map over graph500-23 as ONE split (258.7M arcs of
+    (destination, source) int32, 4.61M vertices, padded to their shape
+    buckets): the [n, 2] arcs are not
+    padded to 128 lanes, the program fits one chip's 16 GB beside the
+    resident arcs, and it holds no scatter and no loop (a gather, the
+    segmented scan's passes, a binary search unrolled)."""
+    from tpumr.ops.pagerank import _last_arcs, _pagerank_map
+    from tpumr.parallel.device_sort import bucket_rows
+    # the shapes a seed's graph is padded to (268,435,456 and 5,242,880)
+    n, v = bucket_rows(258_657_378, 1), bucket_rows(4_609_823, 1)
+    arcs = _shape((n, 2), np.int32, one_chip)
+    if program == "map":
+        compiled, mem, secs = _compile(
+            _pagerank_map, arcs, _shape((v,), np.float32, one_chip),
+            _shape((v,), np.int32, one_chip))
+    else:
+        compiled, mem, secs = _compile(
+            _last_arcs, arcs, _shape((), np.int32, one_chip), v)
+    text = compiled.as_text()
+    assert " while(" not in text and "scatter(" not in text
+    assert n * 8 <= mem.argument_size_in_bytes <= n * 8 + 2 * v * 4 + 4096
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 10e9
+    _record(record_property, mem, secs)
+
+
 @pytest.mark.parametrize("program", ["dest", "exchange", "sort"])
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_device_shuffle_program(meshes, record_property, n_dev, program):

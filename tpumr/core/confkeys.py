@@ -565,6 +565,11 @@ _ENTRIES: "tuple[ConfKey, ...]" = (
         "over this arms an incident snapshot (0 = recorder off)."),
     _K('tpumr.ops.device.cache.mb', 'int', 1024,
         "Ops-level device cache budget, MiB."),
+    _K('tpumr.pagerank.state', 'str', None,
+        "PageRank op: the state directory of the round before "
+        "(ranks.npy, contrib.npy) the maps and the reduce read."),
+    _K('tpumr.pagerank.state.out', 'str', None,
+        "PageRank op: the state directory the round's reduce writes."),
     _K('tpumr.pipeline.conf.hooks.allowed', 'strings', 'tpumr.',
         "Dotted-prefix allowlist for pipeline conf_hook callables — "
         "hooks run IN THE MASTER PROCESS, so only operator-vetted "

@@ -30,6 +30,7 @@ def _load_all() -> None:
     # import for registration side effects
     from tpumr.examples import basic  # noqa: F401
     from tpumr.examples import join  # noqa: F401
+    from tpumr.examples import pagerank  # noqa: F401
     from tpumr.examples import random_writer  # noqa: F401
     from tpumr.examples import secondary_sort  # noqa: F401
     from tpumr.examples import sleep  # noqa: F401

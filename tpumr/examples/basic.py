@@ -200,7 +200,6 @@ def kmeans(argv: list[str]) -> int:
     ap.add_argument("--split-rows", type=int, default=1 << 17)
     _common(ap)
     args = ap.parse_args(argv)
-    from tpumr.ops.kmeans import clear_centroid_cache
     fs = get_filesystem(args.output)
     out = args.output.rstrip("/")
     cent_path = f"{out}/centroids.npy"
@@ -208,7 +207,8 @@ def kmeans(argv: list[str]) -> int:
     save_npy(fs, cent_path, seeds.astype(np.float32))
     centroids = None
     for it in range(args.iterations):
-        clear_centroid_cache()
+        # the file is rewritten in place each round: every tracker keys
+        # its copies by the file's length and mtime (ops/devcache.py)
         conf = JobConf()
         conf.set_job_name(f"kmeans-iter-{it}")
         conf.set_input_paths(args.points)

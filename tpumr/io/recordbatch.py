@@ -183,10 +183,13 @@ class DenseBatch:
     values: np.ndarray                       # [n, d] float32/bf16/…
     ids: np.ndarray | None = None            # [n] int64 record ids
     meta: dict = field(default_factory=dict)
+    #: the split's own records where ``values`` was padded past them for
+    #: the device (``KernelMapper.staged_form``); None: every row counts
+    rows: int | None = None
 
     @property
     def num_records(self) -> int:
-        return int(self.values.shape[0])
+        return int(self.values.shape[0] if self.rows is None else self.rows)
 
     def __len__(self) -> int:
         return self.num_records

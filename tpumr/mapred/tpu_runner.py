@@ -251,6 +251,7 @@ class TpuMapRunner(MapRunnable):
             reporter.incr_counter(BackendCounter.GROUP,
                                   BackendCounter.TPU_DEVICE_BYTES_STAGED,
                                   pre.staged_bytes)
+            _count(kernel, reporter, pre.num_records, True)
             t0 = time.monotonic()
             with tracing.span("tpu:window_drain", backend="tpu",
                               records=pre.num_records,
@@ -320,6 +321,7 @@ class TpuMapRunner(MapRunnable):
                     for key, value in records:
                         output.collect(key, value)
                     _mark_dispatched(name, batch)
+            _count(kernel, reporter, getattr(batch, "num_records", 0), True)
         except Exception as e:  # noqa: BLE001 — classify at the site
             from tpumr.mapred.task import (classify_accelerator_exception,
                                            tag_failure)
@@ -337,8 +339,11 @@ def stage_batch(conf, reader, task_ctx, device=None) -> tuple[Any, bool, int]:
     inputs are values, matching the pipes data path where keys were
     offsets). With a ``device``, dense splits go through the HBM split
     cache: a cache hit skips storage I/O and the host→device transfer
-    entirely; ``device=None`` stages on host (the CPU batch runner).
-    Returns (batch, counted_by_reader, bytes_actually_staged)."""
+    entirely; ``device=None`` stages on host (the CPU batch runner). A
+    split read from storage is uploaded in the job kernel's
+    ``staged_form`` (a padded shape keeps the split's own count in
+    ``DenseBatch.rows``). Returns (batch, counted_by_reader,
+    bytes_actually_staged)."""
     if device is not None:
         from tpumr.parallel.jaxruntime import configure_persistent_cache
         configure_persistent_cache(conf)
@@ -361,8 +366,8 @@ def stage_batch(conf, reader, task_ctx, device=None) -> tuple[Any, bool, int]:
                    split.dtype, split.data_offset, st.length, st.mtime)
             entry = cache.get(key)
             if entry is not None:
-                staged, ids, meta = entry
-                return DenseBatch(staged, ids, dict(meta)), False, 0
+                staged, ids, meta, rows = entry
+                return DenseBatch(staged, ids, dict(meta), rows), False, 0
             # output chain: a predecessor job may have left this FILE's
             # image resident (device_output.publish) — slice the split's
             # rows on device, skipping the read AND the upload
@@ -379,14 +384,19 @@ def stage_batch(conf, reader, task_ctx, device=None) -> tuple[Any, bool, int]:
                 ids = np.arange(split.row_start,
                                 split.row_start + split.num_rows,
                                 dtype=np.int64)
-                cache.put(key, (staged, ids, {}), int(staged.nbytes))
+                cache.put(key, (staged, ids, {}, None), int(staged.nbytes))
                 return DenseBatch(staged, ids, {}), False, 0
             batch = in_fmt.read_batch(split, conf)
-            staged = jax.device_put(batch.values, device)
-            cache.put(key, (staged, batch.ids, dict(batch.meta)),
-                      int(batch.values.nbytes))
-            return DenseBatch(staged, batch.ids, batch.meta), False, \
-                int(batch.values.nbytes)
+            host = batch.values
+            if conf.get_map_kernel():
+                from tpumr.ops import get_kernel
+                host = get_kernel(conf.get_map_kernel()).staged_form(host)
+            rows = None if host is batch.values else batch.num_records
+            staged = jax.device_put(host, device)
+            cache.put(key, (staged, batch.ids, dict(batch.meta), rows),
+                      int(host.nbytes))
+            return DenseBatch(staged, batch.ids, batch.meta, rows), False, \
+                int(host.nbytes)
         batch = in_fmt.read_batch(split, conf)
         return batch, False, int(getattr(batch, "nbytes", 0))
     values = []
@@ -399,6 +409,12 @@ def stage_batch(conf, reader, task_ctx, device=None) -> tuple[Any, bool, int]:
             values.append(serialize(v))
     batch = RecordBatch.from_values(values)
     return batch, True, int(batch.nbytes)
+
+
+def _count(kernel, reporter, records: int, on_tpu: bool) -> None:
+    """Add the task counters the kernel names for the split it mapped."""
+    for (group, name), n in kernel.task_counters(records, on_tpu).items():
+        reporter.incr_counter(group, name, n)
 
 
 def _select_device(dev_id: int):
@@ -553,6 +569,7 @@ class CpuBatchMapRunner(MapRunnable):
         with runner_metrics().histogram("tpu_cpu_batch_seconds").time():
             for key, value in kernel.map_batch_cpu(batch, conf, task_ctx):
                 output.collect(key, value)
+        _count(kernel, reporter, getattr(batch, "num_records", 0), False)
         reporter.set_status(
             f"cpu-batch kernel {kernel.name}: "
             f"{getattr(batch, 'num_records', 0)} records in "

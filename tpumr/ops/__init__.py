@@ -22,6 +22,7 @@ import tpumr.ops.pi        # noqa: F401,E402
 import tpumr.ops.wordcount  # noqa: F401,E402
 import tpumr.ops.grep      # noqa: F401,E402
 import tpumr.ops.segment_sum  # noqa: F401,E402
+import tpumr.ops.pagerank  # noqa: F401,E402
 
 __all__ = ["KernelMapper", "ReduceKernel", "get_kernel", "get_reduce_kernel",
            "kernels", "register_kernel", "register_reduce_kernel"]

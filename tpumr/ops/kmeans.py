@@ -156,40 +156,30 @@ def make_distributed_step(mesh, axis_name: str = "data"):
 # ----------------------------------------------------------------- mapper
 
 
-_centroid_cache: dict[str, np.ndarray] = {}
-
-#: host-cache bound: PIPELINE rounds version their centroid path (one
-#: NEW entry per round, nothing invalidated), so the dict would other-
-#: wise grow one k×d array per round for the life of the process
-_CENTROID_CACHE_CAP = 8
+#: the side-file family of the centroids (ops/devcache.py): host and
+#: device copies are keyed by the file's path, length and mtime
+CENTROIDS = "kmeans-centroids"
 
 
-def _load_centroids(conf) -> np.ndarray:
-    from tpumr.fs.filesystem import FileSystem
-    from tpumr.mapred.input_formats import load_dense
+def _centroids_path(conf) -> str:
     path = conf.get("tpumr.kmeans.centroids")
     if not path:
         raise ValueError("tpumr.kmeans.centroids not set (path to .npy)")
-    cached = _centroid_cache.get(path)
-    if cached is None:
-        fs = FileSystem.get(path, conf)
-        while len(_centroid_cache) >= _CENTROID_CACHE_CAP:
-            _centroid_cache.pop(next(iter(_centroid_cache)))
-        cached = _centroid_cache[path] = load_dense(fs, path).astype(np.float32)
-    return cached
+    return path
+
+
+def _load_centroids(conf) -> np.ndarray:
+    from tpumr.ops.devcache import side_file
+    return side_file(CENTROIDS, _centroids_path(conf), conf).astype(
+        np.float32, copy=False)
 
 
 def clear_centroid_cache() -> None:
-    """SEQUENTIAL iterative drivers rewrite one centroid file between
-    rounds, so both the host cache and the device-resident copy go
-    stale and must be dropped per round. Pipeline loop nodes do NOT
-    call this between rounds: their conf templates a fresh centroid
-    path per round (``cents-r{round}.npy``), so every cache key stays
-    valid — call :func:`clear_pipeline_caches` once at convergence or
-    pipeline teardown instead."""
-    from tpumr.ops.devcache import clear_device_cache
-    _centroid_cache.clear()
-    clear_device_cache("kmeans-centroids:")
+    """Free the host and device copies of every centroid file. Never
+    needed for freshness: a rewritten file has a new length or mtime,
+    and the copies are keyed by them (ops/devcache.py)."""
+    from tpumr.ops.devcache import clear_side_files
+    clear_side_files(CENTROIDS)
 
 
 def clear_pipeline_caches() -> None:
@@ -206,12 +196,11 @@ def clear_pipeline_caches() -> None:
 
 def _device_centroids(conf):
     """Centroids as a DEVICE-resident array, uploaded once per
-    (file, device) instead of once per map task (one upload instead of
-    25 of identical bytes per job; see ops/devcache.py)."""
-    from tpumr.ops.devcache import device_cached
-    host = _load_centroids(conf)
-    tag = f"kmeans-centroids:{conf.get('tpumr.kmeans.centroids')}"
-    return device_cached(tag, host.astype(np.float32, copy=False), conf)
+    (file version, device) instead of once per map task (one upload
+    instead of 25 of identical bytes per job; see ops/devcache.py)."""
+    from tpumr.ops.devcache import device_side_file
+    return device_side_file(CENTROIDS, _centroids_path(conf), conf,
+                            lambda a: a.astype(np.float32, copy=False))
 
 
 def assign_and_partials_numpy(points: np.ndarray, centroids: np.ndarray,
@@ -348,13 +337,13 @@ class KMeansCentroidUpdateReducer(Reducer):
                                         SHIFT_COUNTER,
                                         int(round(shift * 1000)))
         # HBM pre-seed: register the new centroids under the NEXT
-        # round's cache tag so round r+1's maps on this host hit the
+        # round's file identity so round r+1's maps on this host hit the
         # device copy without touching storage (best-effort — a distant
         # tracker's maps just upload once, as before)
         try:
-            from tpumr.ops.devcache import device_cached
-            device_cached(f"kmeans-centroids:{out_path}",
-                          new.astype(np.float32, copy=False), conf)
+            from tpumr.ops.devcache import seed_side_file
+            seed_side_file(CENTROIDS, out_path, conf,
+                           new.astype(np.float32, copy=False))
         except Exception:  # noqa: BLE001 — residency is an
             pass           # optimization, never a dependency
 

@@ -91,6 +91,21 @@ class KernelMapper:
     # scheduler's CPU slots (same job, both backends)
     cpu_mapper_class: type | None = None
 
+    def staged_form(self, values: Any) -> Any:
+        """The host array a split's ``values`` are uploaded as on a TPU
+        slot, once, before the split cache keeps them: the values
+        themselves by default. A kernel may pad them to a shape bucket so
+        that splits of about one size run one compiled program; padding
+        must not change what the kernel gives, and the runners count the
+        split's own records (``DenseBatch.rows``)."""
+        return values
+
+    def task_counters(self, records: int, on_tpu: bool) -> dict:
+        """Task counters the kernel adds for a split of ``records`` records
+        it mapped on a TPU slot (``on_tpu``) or a CPU slot:
+        ``{(group, name): amount}``. The runners add them."""
+        return {}
+
     #: optional vectorized host implementation with the same
     #: ``(batch, conf, task) -> iterable of (key, value)`` contract —
     #: when present, CPU slots run the whole staged split through it

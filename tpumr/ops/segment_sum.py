@@ -52,6 +52,22 @@ def _pulled(x, shift: int, fill):
     return jnp.concatenate([x[..., shift:], pad], axis=-1)
 
 
+def segmented_scan(x, first):
+    """Step 2 of the module's docstring: the segmented inclusive scan of
+    float32 ``x`` along its last axis, a segment starting wherever
+    ``first`` is set (``first[0]`` must be). Each segment's last element
+    ends with the segment's sum, added in the fixed tree order the
+    docstring states; ``log2(n)`` elementwise passes, no loop on the
+    device. The aggregation's kernel and the PageRank map call it."""
+    n = x.shape[-1]
+    f, shift = first, 1
+    while shift < n:
+        x = jnp.where(f, x, _shifted(x, shift, 0.0) + x)
+        f = f | _shifted(f, shift, True)
+        shift *= 2
+    return x
+
+
 @functools.lru_cache(maxsize=8)
 def segment_sum_program(key_cols: int):
     """The jitted device program ``(words [key_cols + 1, n] uint32,
@@ -70,13 +86,8 @@ def segment_sum_program(key_cols: int):
         # padding rows are a group each, so that no sum runs into them
         # and every flag there is set from the start
         first = differs | (idx == 0) | ~valid
-        x = jnp.where(valid, jax.lax.bitcast_convert_type(
-            words[key_cols], jnp.float32), 0.0)
-        f, shift = first, 1
-        while shift < n:
-            x = jnp.where(f, x, _shifted(x, shift, 0.0) + x)
-            f = f | _shifted(f, shift, True)
-            shift *= 2
+        x = segmented_scan(jnp.where(valid, jax.lax.bitcast_convert_type(
+            words[key_cols], jnp.float32), 0.0), first)
         last = valid & _pulled(first, 1, True)
         group = jnp.cumsum((first & valid).astype(jnp.int32)) - 1
         groups = jnp.sum(first & valid, dtype=jnp.int32)
